@@ -39,7 +39,7 @@ from lizardfs_tpu.chunkserver.chunk_store import (
 from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.core import geometry, native_io, plans
 from lizardfs_tpu.core import read_executor
-from lizardfs_tpu.core.encoder import get_encoder
+from lizardfs_tpu.core.encoder import export_backend, get_encoder
 from lizardfs_tpu.proto import framing
 from lizardfs_tpu.proto import messages as m
 from lizardfs_tpu.proto import status as st
@@ -149,12 +149,12 @@ class ChunkServer(Daemon):
         # down; an ack BELOW this fences the command link instead of
         # obeying a zombie. 0 = pre-HA / LZ_HA off, fencing disengaged.
         self.cluster_epoch = 0
+        # one backend for everything this daemon computes (replicator
+        # rebuilds, CRCs): the configured ENCODER. "cpu"/"cpp" never
+        # import jax, so a default chunkserver stays off the chip its
+        # host's client owns; "auto"/"tpu"/"sharded" claim it.
         self.encoder = get_encoder(encoder_name)
-        # replicator recovery backend, resolved lazily on first rebuild:
-        # the auto ladder's mesh-sharded backend when real multichip
-        # silicon is attached (LZ_SHARDED_RECOVERY=0 kills it), else
-        # the configured encoder
-        self._recovery_encoder = None
+        export_backend(self.metrics, self.encoder)
         self.wave_timeout = wave_timeout
         self.heartbeat_interval = heartbeat_interval
         # chunk-tester pacing (hdd_test_chunk analog: the reference
@@ -969,22 +969,6 @@ class ChunkServer(Daemon):
                         except (ConnectionError, OSError, RuntimeError):
                             pass
 
-    def _replicator_encoder(self):
-        """The rebuild compute backend: try the encoder auto-ladder's
-        mesh-sharded wide-stripe decoder (parallel/recovery.py) — it
-        binds only on a real multi-device mesh with the
-        LZ_SHARDED_RECOVERY switch open — and degrade to the configured
-        single-chip encoder everywhere else."""
-        if self._recovery_encoder is None:
-            try:
-                self._recovery_encoder = get_encoder("sharded")
-                self.log.info(
-                    "replicator: mesh-sharded recovery backend active"
-                )
-            except Exception:  # no mesh / no silicon / kill switch
-                self._recovery_encoder = self.encoder
-        return self._recovery_encoder
-
     async def _replicate(self, msg: m.MatocsReplicate) -> None:
         target = geometry.ChunkPartType.from_id(msg.part_id)
         slice_type = target.type
@@ -1009,7 +993,7 @@ class ChunkServer(Daemon):
                 slice_type, list(locations.keys()),
                 scores={p: GLOBAL_STATS.score(a)
                         for p, (a, _) in locations.items()},
-                encoder=self._replicator_encoder(),
+                encoder=self.encoder,
             )
             if not planner.is_readable([target.part]):
                 raise ChunkStoreError(st.NO_CHUNK, "not enough source parts")

@@ -35,7 +35,9 @@ from lizardfs_tpu.constants import (
     env_flag,
 )
 from lizardfs_tpu.core import geometry, plans
-from lizardfs_tpu.core.encoder import ChunkEncoder, get_encoder
+from lizardfs_tpu.core.encoder import (
+    ChunkEncoder, export_backend, get_encoder,
+)
 from lizardfs_tpu.core.read_executor import ReadError, execute_plan
 from lizardfs_tpu.proto import framing
 from lizardfs_tpu.proto import messages as m
@@ -102,10 +104,9 @@ class Client:
         # ex-primary this client lands on learns it was superseded and
         # steps down instead of accepting our writes. 0 = pre-HA.
         self.cluster_epoch = 0
-        # default "auto": tpu on real silicon, else the native C++ SIMD
-        # backend, else numpy — the old hardcoded "cpu" default made any
-        # library user pay the golden path's 3.8x penalty (VERDICT r05
-        # weak #2); LIZARDFS_TPU_ENCODER still overrides
+        # default "auto": the device backend where jax reports an
+        # accelerator (errors building it propagate), else the native
+        # C++ SIMD backend, else numpy; LIZARDFS_TPU_ENCODER overrides
         self.encoder = encoder or get_encoder(None)
         self.wave_timeout = wave_timeout
         self.retries = retries
@@ -258,6 +259,7 @@ class Client:
         from lizardfs_tpu.runtime.metrics import Metrics
 
         self.metrics = metrics if metrics is not None else Metrics()
+        export_backend(self.metrics, self.encoder)
         # adaptive N-deep write window (spends PR 1's phase telemetry):
         # up to LZ_WRITE_WINDOW stripe segments ride unacknowledged per
         # striped chunk write under per-chunkserver credits + a shared

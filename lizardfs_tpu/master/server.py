@@ -4267,6 +4267,9 @@ class MasterServer(Daemon):
                 "chunkservers": [
                     {
                         "cs_id": s.cs_id, "host": s.host, "port": s.port,
+                        # where part locations point when the native
+                        # data plane serves (0 = the control port)
+                        "data_port": s.data_port,
                         "label": s.label, "connected": s.connected,
                         "total_space": s.total_space, "used_space": s.used_space,
                         # mirror=True: a shadow's passive location feed,

@@ -27,28 +27,19 @@ from lizardfs_tpu.parallel import sharded
 
 
 def make_single_chip_step(
-    k: int, m: int, block_size: int = MFSBLOCKSIZE, use_pallas: bool | None = None
+    k: int, m: int, block_size: int = MFSBLOCKSIZE, interpret: bool = False
 ):
-    """Returns a jittable fn(data (k, N) uint8) -> (parity, dcrc, pcrc).
+    """Returns a jittable fn(data (k, N) uint8) -> (parity, dcrc, pcrc):
+    the fused Pallas kernel (bits stay in VMEM). It needs a TPU
+    backend; ``interpret=True`` runs it in the Pallas interpreter."""
+    from lizardfs_tpu.ops import pallas_ec
 
-    On a real TPU backend the Pallas kernels run (bits stay in VMEM); on
-    CPU the XLA bit-plane path is used (same bytes, tested identical).
-    """
     bigm = np.asarray(jax_ec.encoding_bitmatrix(k, m))
-    if use_pallas is None:
-        from lizardfs_tpu.ops import pallas_ec
-
-        use_pallas = pallas_ec.supported()
-    if use_pallas:
-        from lizardfs_tpu.ops import pallas_ec
-
-        def step(data: jnp.ndarray):
-            return pallas_ec.fused_encode_crc(jnp.asarray(bigm), data, block_size)
-
-        return step
 
     def step(data: jnp.ndarray):
-        return jax_ec.fused_encode_crc(jnp.asarray(bigm), data, block_size)
+        return pallas_ec.fused_encode_crc(
+            jnp.asarray(bigm), data, block_size, interpret=interpret
+        )
 
     return step
 

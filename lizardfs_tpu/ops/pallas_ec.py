@@ -19,6 +19,11 @@ Kernels:
     (:mod:`lizardfs_tpu.ops.crc32` machinery).
 
 Numerics are byte-identical to the golden path (tests enforce it).
+
+Every entry point compiles through Mosaic and so needs a TPU backend;
+``interpret=True`` — passed explicitly, by tests on the CPU platform —
+runs the same kernel in the Pallas interpreter. Nothing here infers it
+from the platform: a product call on a device without Mosaic raises.
 """
 
 from __future__ import annotations
@@ -35,15 +40,6 @@ from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.ops import crc32 as crc_host
 
 CRC_SUBBLOCK = 64
-
-
-def supported() -> bool:
-    """Pallas kernels need a real TPU backend (Mosaic); the CPU backend
-    only runs them in interpret mode (tests)."""
-    try:
-        return jax.devices()[0].platform != "cpu"
-    except Exception:
-        return False
 
 
 def _unpack_tile(bytes_tile: jnp.ndarray) -> jnp.ndarray:
@@ -88,8 +84,9 @@ def _encode_kernel(bigm_ref, data_ref, parity_ref, *, m: int, q: int):
     parity_ref[:] = _encode_tile(bigm_ref, data_ref[:], m, q)
 
 
-@functools.partial(jax.jit, static_argnames=("tile",))
-def encode(bigm: jnp.ndarray, data: jnp.ndarray, tile: int = 16384) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def encode(bigm: jnp.ndarray, data: jnp.ndarray, tile: int = 16384,
+           interpret: bool = False) -> jnp.ndarray:
     """Fused bit-plane RS encode: (k, N) uint8 -> (m, N) uint8 parity.
 
     ``bigm`` is the (8m, 8k) expanded generator/recovery matrix.
@@ -115,6 +112,7 @@ def encode(bigm: jnp.ndarray, data: jnp.ndarray, tile: int = 16384) -> jnp.ndarr
             pl.BlockSpec((k, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((m, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
+        interpret=interpret,
     )(bigm_q, data)
 
 
@@ -143,8 +141,9 @@ def _crc_partial_kernel(csub_ref, subs_ref, out_ref):
     out_ref[:] = acc.astype(jnp.int32) & 1  # exact: sums <= 1024
 
 
-@functools.partial(jax.jit, static_argnames=("block_size",))
-def block_crcs(blocks: jnp.ndarray, block_size: int = MFSBLOCKSIZE) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
+def block_crcs(blocks: jnp.ndarray, block_size: int = MFSBLOCKSIZE,
+               interpret: bool = False) -> jnp.ndarray:
     """CRC32 of each row of (B, block_size) uint8 -> (B,) uint32."""
     b = blocks.shape[0]
     sub = 2 * CRC_SUBBLOCK  # 128-byte sub-blocks: full lane width
@@ -172,6 +171,7 @@ def block_crcs(blocks: jnp.ndarray, block_size: int = MFSBLOCKSIZE) -> jnp.ndarr
             pl.BlockSpec((g * nsub, sub), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((g * nsub, 32), lambda i: (i, 0), memory_space=pltpu.VMEM),
+        interpret=interpret,
     )(jnp.asarray(csub_planes, dtype=jnp.bfloat16), subs)
 
     # XLA log-tree fold + finalize (tiny: 32 ints per sub-block)
@@ -407,11 +407,12 @@ def _fused_kernel(bigm_ref, w_ref, shifts_ref, seld_ref, selp_ref,
     )
 
 
-# Silicon-verified default (r01). The bigger-tile/bigger-budget config
-# below halves per-chunk grid steps (benches/ROOFLINE.md #1) but its
-# VMEM model is unverified on hardware, so production callers keep the
-# proven residency; bench.py opts into the staged configs first (most
-# aggressive first) and tags its JSON with whichever actually compiled.
+# Three configurations: the default below, BIG_TILE_CONFIG and
+# ROOFLINE_CONFIG. All three compiled through Mosaic and matched the
+# golden codec at 64 KiB blocks / 64 MiB chunks for ec(8,4) and ec(3,2)
+# on a TPU v5e (jax 0.9.0, libtpu 0.0.34; chip_smoke.py compiles them
+# on every run). Which is fastest has not been measured; production
+# callers use the default until a chip run arbitrates (ROADMAP S4).
 _FUSED_VMEM_BUDGET = 10 * 2**20
 # 11.5 MiB of ~16 MiB physical: ec(8,4) fits tile=32 KiB (10.1 MiB ->
 # 256 steps/chunk, 2x fewer), ec(3,2) a full 64 KiB block
@@ -422,7 +423,7 @@ BIG_TILE_CONFIG = {"tile": 65536, "vmem_budget": 11_534_336}
 # stage from the encode's already-unpacked bit planes via in-VMEM
 # relayout instead of re-extracting (~8 VPU ops/byte over k+m rows).
 # Byte parity of every combination is pinned in interpret mode
-# (tests/test_pallas.py); only the SPEED is a silicon question.
+# (tests/test_pallas.py).
 ROOFLINE_CONFIG = {
     "tile": 65536, "vmem_budget": 11_534_336,
     "wide_crc": True, "reuse_planes": True,
@@ -440,7 +441,7 @@ def fused_encode_crc(
     data: jnp.ndarray,
     block_size: int = MFSBLOCKSIZE,
     tile: int = 16384,
-    interpret: bool | None = None,
+    interpret: bool = False,
     vmem_budget: int = _FUSED_VMEM_BUDGET,
     wide_crc: bool = False,
     reuse_planes: bool = False,
@@ -451,13 +452,10 @@ def fused_encode_crc(
     u32), byte-identical to jax_ec.fused_encode_crc / the golden codec.
 
     ``tile`` shrinks until it fits the VMEM budget, divides the block
-    size, and divides N. Defaults are the silicon-verified residency;
-    pass ``**BIG_TILE_CONFIG`` (ROOFLINE #1) or ``**ROOFLINE_CONFIG``
-    (#1+#2+#3: + wide 128-lane CRC stage-1, + bit-plane reuse) — both
-    numerically pinned, speed pending a live chip.
+    size, and divides N. Pass ``**BIG_TILE_CONFIG`` (ROOFLINE #1) or
+    ``**ROOFLINE_CONFIG`` (#1+#2+#3: + wide 128-lane CRC stage-1,
+    + bit-plane reuse) for the staged alternatives.
     """
-    if interpret is None:
-        interpret = not supported()  # CPU backend: interpret mode
     k, n = data.shape
     m = bigm.shape[0] // 8
     rows = k + m
@@ -592,7 +590,7 @@ def fused_decode_verify(
     survivors: jnp.ndarray,
     expected_crcs: jnp.ndarray,
     block_size: int = MFSBLOCKSIZE,
-    interpret: bool | None = None,
+    interpret: bool = False,
     tile: int = 16384,
     vmem_budget: int = _FUSED_VMEM_BUDGET,
     wide_crc: bool = False,

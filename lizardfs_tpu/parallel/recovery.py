@@ -38,7 +38,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from lizardfs_tpu.ops import gf256, jax_ec
-from lizardfs_tpu.parallel.sharded import shard_map
 
 
 def enabled() -> bool:
@@ -110,7 +109,7 @@ def sharded_reconstruct_with_crcs(
         )
 
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs
         )
     )

@@ -30,14 +30,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from lizardfs_tpu.ops import jax_ec
 
-# jax.shard_map graduated from jax.experimental at ~0.4.40; the call
-# sites pass mesh/in_specs/out_specs as keywords, which both spellings
-# accept — so one shim keeps every jax in the support window working
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map
-
 
 def make_mesh(devices=None, axis: str = "stripe") -> Mesh:
     devices = devices if devices is not None else jax.devices()
@@ -127,7 +119,7 @@ def sharded_encode_with_crcs(mesh: Mesh, k: int, m: int, block_size: int):
         )
 
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs
         )
     )

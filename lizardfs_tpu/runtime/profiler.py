@@ -132,7 +132,12 @@ class SamplingProfiler:
                         mod = code.co_filename.rpartition("/")[2]
                         if mod.endswith(".py"):
                             mod = mod[:-3]
-                        stack.append(f"{mod}.{code.co_name}")
+                        # the collapsed format ends each line with
+                        # " <count>": a frame name may hold no space
+                        # (Python 3.12 names "<frozen runpy>")
+                        stack.append(
+                            f"{mod}.{code.co_name}".replace(" ", "_")
+                        )
                         frame = frame.f_back
                         depth += 1
                     if not stack:

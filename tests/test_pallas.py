@@ -1,32 +1,29 @@
 """Pallas kernels in interpret mode (CPU): byte parity with the golden
-path. The same kernels run compiled on the real chip (validated by
-bench.py / graft entry)."""
+path. Interpret mode is asked for by name in every call; the same
+kernels run compiled on the chip, where chip_smoke.py checks them at
+full geometry."""
 
 import numpy as np
 import pytest
-from jax.experimental import pallas as pl
 
 import lizardfs_tpu.ops.pallas_ec as pe
 from lizardfs_tpu.core.encoder import CpuChunkEncoder
 from lizardfs_tpu.ops import jax_ec
 
-
-@pytest.fixture(autouse=True)
-def interpret_mode(monkeypatch):
-    orig = pl.pallas_call
-
-    def patched(*args, **kwargs):
-        kwargs.setdefault("interpret", True)
-        return orig(*args, **kwargs)
-
-    monkeypatch.setattr(pl, "pallas_call", patched)
-
-
 cpu = CpuChunkEncoder()
 
 
-def test_supported_is_false_on_cpu():
-    assert pe.supported() is False
+def test_interpret_mode_is_never_inferred():
+    """Without interpret=True a call on the CPU platform must raise —
+    not run interpreted, not pick another program."""
+    data = np.zeros((3, 8192), dtype=np.uint8)
+    bigm = jax_ec.encoding_bitmatrix(3, 2)
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        pe.fused_encode_crc(bigm, data, 8192)
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        pe.block_crcs(data.reshape(-1, 4096), 4096)
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        pe.encode(bigm, np.zeros((3, 16384), dtype=np.uint8))
 
 
 @pytest.mark.parametrize("k,m", [(3, 2), (8, 4)])
@@ -34,7 +31,7 @@ def test_pallas_encode_byte_identical(k, m):
     rng = np.random.default_rng(0)
     data = rng.integers(0, 256, size=(k, 2 * 16384), dtype=np.uint8)
     bigm = jax_ec.encoding_bitmatrix(k, m)
-    parity = np.asarray(pe.encode(bigm, data))
+    parity = np.asarray(pe.encode(bigm, data, interpret=True))
     want = np.stack(cpu.encode(k, m, list(data)))
     np.testing.assert_array_equal(parity, want)
 
@@ -43,7 +40,7 @@ def test_pallas_crcs_byte_identical():
     rng = np.random.default_rng(1)
     # 18 blocks: not a multiple of the per-step group (16) -> padding path
     blocks = rng.integers(0, 256, size=(18, 4096), dtype=np.uint8)
-    got = np.asarray(pe.block_crcs(blocks, 4096))
+    got = np.asarray(pe.block_crcs(blocks, 4096, interpret=True))
     from lizardfs_tpu.ops import crc32
 
     np.testing.assert_array_equal(got, crc32.block_crcs_golden(blocks))
@@ -54,7 +51,7 @@ def test_pallas_fused_byte_identical():
     k, m, bs, nb = 8, 4, 8192, 4
     data = rng.integers(0, 256, size=(k, nb * bs), dtype=np.uint8)
     bigm = jax_ec.encoding_bitmatrix(k, m)
-    p, dc, pc = pe.fused_encode_crc(bigm, data, bs)
+    p, dc, pc = pe.fused_encode_crc(bigm, data, bs, interpret=True)
     wp, wd, wpc = cpu.encode_with_checksums(k, m, data, block_size=bs)
     np.testing.assert_array_equal(np.asarray(p), wp)
     np.testing.assert_array_equal(np.asarray(dc), wd)
@@ -68,7 +65,7 @@ def test_pallas_fused_multichunk_blocks():
     k, m, bs, nb = 3, 2, 65536, 3
     data = rng.integers(0, 256, size=(k, nb * bs), dtype=np.uint8)
     bigm = jax_ec.encoding_bitmatrix(k, m)
-    p, dc, pc = pe.fused_encode_crc(bigm, data, bs)  # tile < bs here
+    p, dc, pc = pe.fused_encode_crc(bigm, data, bs, interpret=True)  # tile < bs here
     wp, wd, wpc = cpu.encode_with_checksums(k, m, data, block_size=bs)
     np.testing.assert_array_equal(np.asarray(p), wp)
     np.testing.assert_array_equal(np.asarray(dc), wd)
@@ -83,7 +80,7 @@ def test_pallas_fused_decode_verify():
     k, m, bs, nb = 4, 2, 8192, 2
     data = rng.integers(0, 256, size=(k, nb * bs), dtype=np.uint8)
     bigm = jax_ec.encoding_bitmatrix(k, m)
-    parity, dcrc, _pcrc = pe.fused_encode_crc(bigm, data, bs)
+    parity, dcrc, _pcrc = pe.fused_encode_crc(bigm, data, bs, interpret=True)
     allparts = np.concatenate([data, np.asarray(parity)], axis=0)
     lost = [1, 3]
     have = [i for i in range(k + m) if i not in lost]
@@ -92,7 +89,7 @@ def test_pallas_fused_decode_verify():
     survivors = allparts[list(used)]
     want_crcs = np.asarray(dcrc)[lost]
     rec, crcs, ok = pe.fused_decode_verify(
-        np.asarray(big_rec), survivors, want_crcs, bs
+        np.asarray(big_rec), survivors, want_crcs, bs, interpret=True
     )
     np.testing.assert_array_equal(np.asarray(rec), data[lost])
     assert bool(np.all(np.asarray(ok)))
@@ -100,7 +97,7 @@ def test_pallas_fused_decode_verify():
     bad = want_crcs.copy()
     bad[0, 0] ^= 1
     _, _, ok2 = pe.fused_decode_verify(
-        np.asarray(big_rec), survivors, bad, bs
+        np.asarray(big_rec), survivors, bad, bs, interpret=True
     )
     assert not bool(np.asarray(ok2)[0, 0]) and bool(np.asarray(ok2)[1, 1])
 
@@ -114,7 +111,8 @@ def test_pallas_fused_large_tiles_byte_identical(tile):
     data = rng.integers(0, 256, size=(k, 2 * bs), dtype=np.uint8)
     bigm = jax_ec.encoding_bitmatrix(k, m)
     p, dc, pc = pe.fused_encode_crc(
-        bigm, data, bs, tile=tile, vmem_budget=64 * 2**20
+        bigm, data, bs, tile=tile, vmem_budget=64 * 2**20,
+        interpret=True,
     )
     wp, wd, wpc = cpu.encode_with_checksums(k, m, data, block_size=bs)
     np.testing.assert_array_equal(np.asarray(p), wp)
@@ -130,7 +128,7 @@ def test_pallas_default_tile_shrinks_to_fit():
     for k, m, bs, nb in ((8, 4, 16384, 2), (3, 2, 8192, 3), (8, 2, 65536, 1)):
         data = rng.integers(0, 256, size=(k, nb * bs), dtype=np.uint8)
         bigm = jax_ec.encoding_bitmatrix(k, m)
-        p, dc, pc = pe.fused_encode_crc(bigm, data, bs)
+        p, dc, pc = pe.fused_encode_crc(bigm, data, bs, interpret=True)
         wp, wd, wpc = cpu.encode_with_checksums(k, m, data, block_size=bs)
         np.testing.assert_array_equal(np.asarray(p), wp)
         np.testing.assert_array_equal(np.asarray(dc), wd)
@@ -152,7 +150,7 @@ def test_pallas_roofline_config_byte_identical(k, m, wide, reuse):
     bigm = jax_ec.encoding_bitmatrix(k, m)
     p, dc, pc = pe.fused_encode_crc(
         bigm, data, bs, tile=65536, vmem_budget=64 * 2**20,
-        wide_crc=wide, reuse_planes=reuse,
+        wide_crc=wide, reuse_planes=reuse, interpret=True,
     )
     wp, wd, wpc = cpu.encode_with_checksums(k, m, data, block_size=bs)
     np.testing.assert_array_equal(np.asarray(p), wp)
@@ -170,7 +168,7 @@ def test_pallas_roofline_small_tile_falls_back():
     bigm = jax_ec.encoding_bitmatrix(k, m)
     p, dc, pc = pe.fused_encode_crc(
         bigm, data, bs, tile=512, vmem_budget=64 * 2**20,
-        wide_crc=True, reuse_planes=True,
+        wide_crc=True, reuse_planes=True, interpret=True,
     )
     wp, wd, wpc = cpu.encode_with_checksums(k, m, data, block_size=bs)
     np.testing.assert_array_equal(np.asarray(p), wp)
@@ -189,7 +187,7 @@ def test_pallas_decode_verify_roofline_config_byte_identical():
     k, m, bs, nb = 8, 4, 65536, 2
     data = rng.integers(0, 256, size=(k, nb * bs), dtype=np.uint8)
     bigm = jax_ec.encoding_bitmatrix(k, m)
-    parity, dcrc, _pcrc = pe.fused_encode_crc(bigm, data, bs)
+    parity, dcrc, _pcrc = pe.fused_encode_crc(bigm, data, bs, interpret=True)
     allparts = np.concatenate([data, np.asarray(parity)], axis=0)
     lost = [0]
     have = [i for i in range(k + m) if i not in lost]
@@ -197,7 +195,8 @@ def test_pallas_decode_verify_roofline_config_byte_identical():
     big_rec = jax_ec.recovery_bitmatrix(k, m, tuple(used), tuple(lost))
     rec, _crcs, ok = pe.fused_decode_verify(
         np.asarray(big_rec), allparts[list(used)],
-        np.asarray(dcrc)[lost], bs, **pe.ROOFLINE_CONFIG,
+        np.asarray(dcrc)[lost], bs, interpret=True,
+        **pe.ROOFLINE_CONFIG,
     )
     np.testing.assert_array_equal(np.asarray(rec), data[lost])
     assert np.asarray(ok).all()

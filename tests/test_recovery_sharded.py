@@ -151,4 +151,12 @@ def test_dryrun_multichip_small_mesh():
     small shapes — the same code path the driver captures."""
     import __graft_entry__ as graft
 
-    graft.dryrun_multichip(8, block_size=4096, min_logical_mib=1)
+    got = graft.dryrun_multichip(8, block_size=4096, min_logical_mib=1)
+    # every device of the mesh held a shard of both legs' outputs
+    assert got["shard_devices"] == {
+        "encode": list(range(8)), "reconstruct": list(range(8)),
+    }
+    # asked for more devices than jax reports: an error, not a
+    # substitute mesh
+    with pytest.raises(RuntimeError, match="jax reports 8 cpu"):
+        graft.dryrun_multichip(16, block_size=4096, min_logical_mib=1)
