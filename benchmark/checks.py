@@ -1,0 +1,161 @@
+"""What decides ``correct``: the comparison of what the timed window
+produced with the plain reference, once the window has closed.
+
+Every number compared is an exact count with the limit 0:
+
+  read_wrong_bytes      bytes of the retained answers of the window's
+                        reads (a seeded sample, the longest in it,
+                        degraded ones among them) that differ from the
+                        model's contents
+  getattr_wrong         lengths ``getattr`` reported inside the window
+                        that differ from the model's
+  names_wrong           names the master lists in the run's directory
+                        that the model does not hold, and the reverse
+  length_wrong          live files whose length at the master differs
+  parts_wrong           sampled chunks of live files whose parts are not
+                        k + m on distinct chunkservers (less the killed
+                        server's, where the mix killed one), or whose
+                        part files are not where the master says
+  stored_wrong_bytes    bytes of those chunks' part files on the
+                        chunkservers' disks, parity parts included, that
+                        differ from the reference's striping and
+                        Reed-Solomon parity of the model's contents
+  stored_wrong_crcs     CRC words of those part files that differ from
+                        the CRC32 of the reference's blocks
+  readback_wrong_bytes  bytes of sampled live files read back cold after
+                        the close that differ from the model's
+  unchecked             sampled items the comparison could not read
+
+The reference (``benchmark/reference``) imports nothing of the program
+and takes nothing the program made.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from reference import layout
+
+NAMES = ("read_wrong_bytes", "getattr_wrong", "names_wrong", "length_wrong",
+         "parts_wrong", "stored_wrong_bytes", "stored_wrong_crcs",
+         "readback_wrong_bytes", "unchecked")
+
+
+def wrong_bytes(got, want: np.ndarray) -> int:
+    got = np.frombuffer(got, dtype=np.uint8) if not isinstance(
+        got, np.ndarray) else got
+    n = min(len(got), len(want))
+    return int(np.count_nonzero(got[:n] != want[:n])) + abs(len(got) - len(want))
+
+
+def sample_with_first(items: list, n: int, rng) -> list:
+    """The first item (the longest, as the callers sort) and a seeded
+    draw of n - 1 of the others, in their order."""
+    n = min(n, len(items))
+    if n <= 0:
+        return []
+    rest = sorted(rng.choice(np.arange(1, len(items)), size=n - 1,
+                             replace=False))
+    return items[:1] + [items[i] for i in rest]
+
+
+def check_chunk(data: np.ndarray, k: int, m: int, block: int,
+                part_files: dict[int, str]) -> tuple[int, int]:
+    """(wrong bytes, wrong CRC words) of one chunk's stored parts
+    against the reference; ``part_files`` maps part index -> path."""
+    want_parts = layout.expected_parts(data, k, m, block)
+    live = layout.part_lengths(k, m, len(data), block)
+    bad_bytes = bad_crcs = 0
+    for p, path in part_files.items():
+        body, table = layout.read_part_file(path, block)
+        want = want_parts[p]
+        if len(body) < live[p]:
+            bad_bytes += live[p] - len(body)
+        n = min(len(body), len(want))
+        bad_bytes += int(np.count_nonzero(body[:n] != want[:n]))
+        bad_bytes += int(np.count_nonzero(body[n:]))  # past the end: zeros
+        nblocks = -(-live[p] // block)
+        want_crcs = layout.block_crcs(want[:nblocks * block], block)
+        bad_crcs += sum(1 for a, b in zip(table[:nblocks], want_crcs) if a != b)
+        bad_crcs += max(nblocks - len(table), 0)
+    return bad_bytes, bad_crcs
+
+
+async def compare(traffic, client, config: dict, seed: int) -> dict:
+    """Run the whole comparison; returns name -> {"value", "limit"}."""
+    block, chunk_bytes = int(config["block_bytes"]), int(config["chunk_bytes"])
+    model, chk = traffic.model, traffic.mix["check"]
+    v = dict.fromkeys(NAMES, 0)
+    rng = np.random.default_rng([int(seed), 0x63686B])
+
+    for r in traffic.retained:
+        if r.name in traffic.uncertain:
+            continue
+        # a file unlinked since: a name is written once, so what it
+        # held when read is what the model's record of it last held
+        f = model.files.get(r.name) or traffic.unlinked.get(r.name)
+        if f is None:
+            v["unchecked"] += 1
+            continue
+        v["read_wrong_bytes"] += wrong_bytes(
+            r.data, model.bytes_of(f, r.offset, r.size))
+    v["getattr_wrong"] = sum(1 for _n, seen, want in traffic.getattr_seen
+                             if seen != want)
+
+    live = [f for f in model.live() if f.name not in traffic.uncertain]
+    listed = {e.name for d in traffic.dirs
+              for e in await client.readdir(d.inode)} - traffic.uncertain
+    v["names_wrong"] = len(listed ^ {f.name for f in live})
+    for f in live:
+        if int((await client.getattr(f.inode)).length) != f.length:
+            v["length_wrong"] += 1
+
+    # chunks of live files, a seeded sample with the longest file in it
+    chunks = [(f, ci) for f in sorted(live, key=lambda f: -f.length)
+              for ci in range(-(-f.length // chunk_bytes))]
+    picks = sample_with_first(chunks, int(chk["disk_chunks"]), rng)
+    cs_dirs = traffic.cluster.live_cs_dirs()
+    for f, ci in picks:
+        goal = traffic.dirs[f.dir].goal
+        k, m = int(goal["k"]), int(goal["m"])
+        try:
+            info = await client.chunk_info(f.inode, ci)
+            lost = 1 if (f.name, ci) in traffic.lost_part_chunks else 0
+            ports = {loc.addr.port for loc in info.locations}
+            parts = {loc.part_id for loc in info.locations}
+            want_ids = {layout.ec_part_id(k, m, p) for p in range(k + m)}
+            ok = (len(info.locations) == k + m - lost
+                  and len(ports) == len(info.locations)
+                  and parts <= want_ids and len(parts) == len(info.locations))
+            files, homes = {}, set()
+            for pid in sorted(parts & want_ids):
+                found = layout.find_part_files(cs_dirs, info.chunk_id, pid)
+                if len(found) != 1:
+                    ok = False
+                    continue
+                homes.add(found[0][0])
+                files[pid % 64] = found[0][1]
+            ok = ok and len(homes) == len(files) == k + m - lost
+            if not ok:
+                v["parts_wrong"] += 1
+            span = layout.chunk_spans(f.length, chunk_bytes)[ci]
+            data = model.bytes_of(f, span[0], span[1] - span[0])
+            bad_b, bad_c = check_chunk(data, k, m, block, files)
+            v["stored_wrong_bytes"] += bad_b
+            v["stored_wrong_crcs"] += bad_c
+        except (OSError, ValueError, RuntimeError):
+            v["unchecked"] += 1
+
+    for f in sample_with_first(sorted(live, key=lambda f: -f.length),
+                               int(chk["readback_files"]), rng):
+        try:
+            client.cache.invalidate(f.inode)
+            got = await client.read_file(f.inode, 0, f.length)
+            v["readback_wrong_bytes"] += wrong_bytes(got, model.bytes_of(f))
+        except Exception:  # noqa: BLE001 - an answer that never comes
+            v["unchecked"] += 1
+    return {name: {"value": v[name], "limit": 0} for name in NAMES}
+
+
+def all_within(checks: dict) -> bool:
+    return all(c["value"] <= c["limit"] for c in checks.values())

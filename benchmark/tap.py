@@ -1,0 +1,81 @@
+"""The benchmark's wrappers on the encoder instance: what crosses the
+``ChunkEncoder`` boundary, counted and timed from outside the program
+(the client's write path and its read plans call through the instance,
+so attributes set on it see every call). ``encode_into`` of the device
+encoder lands in ``encode``, so wrapping ``encode`` counts each call
+once. In a traced run each call is also a ``TraceAnnotation``, which
+puts ``bench.encode`` / ``bench.recover`` on the profiler's clock.
+
+``control`` puts a broken guarantee in the encoder's place, to show
+that the comparison fails (never set in a benchmark run):
+  parity-short    the last parity part is stored as zeros: m - 1 valid
+                  parity parts, so not any k of k + m read back
+  recover-approx  the last 64 KiB of every recovered part come back as
+                  zeros: an approximate answer where it was exact
+``fault`` alters one byte where it is produced (tests only):
+  encode-flip, recover-flip
+"""
+
+from __future__ import annotations
+
+import contextlib
+import threading
+import time
+
+CONTROLS = ("parity-short", "recover-approx")
+FAULTS = ("encode-flip", "recover-flip")
+
+
+class EncoderTap:
+    def __init__(self, enc, annotate=None, control=None, fault=None):
+        self.enc = enc
+        self.annotate = annotate or (lambda _name: contextlib.nullcontext())
+        self.control, self.fault = control, fault
+        self.lock = threading.Lock()
+        self.reset()
+        self._encode, self._recover = enc.encode, enc.recover
+        enc.encode, enc.recover = self.encode, self.recover
+
+    def reset(self) -> None:
+        self.encode_calls = []      # (k, m, rows, part bytes, seconds)
+        self.recover_calls = []     # (k, m, rows used, wanted, part bytes, seconds)
+
+    def remove(self) -> None:
+        del self.enc.encode, self.enc.recover
+
+    def encode(self, k, m, data_parts):
+        rows = sum(1 for p in data_parts if p is not None)
+        length = next(len(p) for p in data_parts if p is not None)
+        t0 = time.perf_counter()
+        with self.annotate("bench.encode"):
+            out = self._encode(k, m, data_parts)
+        dt = time.perf_counter() - t0
+        with self.lock:
+            self.encode_calls.append((k, m, rows, length, dt))
+        if self.control == "parity-short":
+            out = list(out)
+            out[-1] = out[-1] * 0
+        if self.fault == "encode-flip":
+            out = list(out)
+            out[0] = out[0].copy()
+            out[0][0] ^= 1
+        return out
+
+    def recover(self, k, m, parts, wanted):
+        rows = sum(1 for p in parts.values() if p is not None)
+        t0 = time.perf_counter()
+        with self.annotate("bench.recover"):
+            out = self._recover(k, m, parts, wanted)
+        dt = time.perf_counter() - t0
+        length = len(next(iter(out.values())))
+        with self.lock:
+            self.recover_calls.append((k, m, min(rows, k), len(wanted),
+                                       length, dt))
+        if self.control == "recover-approx":
+            out = {w: v.copy() for w, v in out.items()}
+            for v in out.values():
+                v[-65536:] = 0
+        if self.fault == "recover-flip":
+            out = {w: v.copy() for w, v in out.items()}
+            next(iter(out.values()))[0] ^= 1
+        return out
