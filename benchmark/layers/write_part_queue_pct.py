@@ -1,0 +1,6 @@
+from _spans import share_pct
+
+
+def read(ctx):
+    return share_pct(ctx, "write", ("hop", "part_dial", "part_init"),
+                     ("part",))

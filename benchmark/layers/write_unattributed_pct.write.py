@@ -1,0 +1,5 @@
+from _spans import share_pct
+
+
+def read(ctx):
+    return share_pct(ctx, "write", ("self",), ("wall",))

@@ -611,7 +611,7 @@ class ChunkServer(Daemon):
             # "queue" sub-interval so native backpressure is visible
             self.trace_ring.record(
                 op["trace_id"], op["name"], op["t0"], op["t1"],
-                role="chunkserver", bytes=op["bytes"],
+                role="chunkserver", bucket="disk", bytes=op["bytes"],
                 disk_us=op["disk_us"], net_us=op["net_us"],
                 queue_us=op.get("queue_us", 0),
                 chunk_id=op["chunk_id"],
@@ -936,7 +936,7 @@ class ChunkServer(Daemon):
             tracing.clear_trace()
         self.trace_ring.record(
             tid, "cs_replicate", tw0, time.time(), role="chunkserver",
-            chunk_id=msg.chunk_id,
+            bucket="net", chunk_id=msg.chunk_id,
         )
         self.slo.observe(
             "replicate", time.perf_counter() - t0, trace_id=tid,
@@ -1100,7 +1100,7 @@ class ChunkServer(Daemon):
                     self.metrics.timing("read").record(dt)
                     self.trace_ring.record(
                         msg.trace_id, "cs_read", tw0, time.time(),
-                        role="chunkserver", bytes=msg.size,
+                        role="chunkserver", bucket="disk", bytes=msg.size,
                     )
                     self.slo.observe(
                         "read", dt, trace_id=msg.trace_id, name="cs_read"
@@ -1119,7 +1119,7 @@ class ChunkServer(Daemon):
                     self.metrics.timing("read_bulk").record(dt)
                     self.trace_ring.record(
                         msg.trace_id, "cs_read_bulk", tw0, time.time(),
-                        role="chunkserver", bytes=msg.size,
+                        role="chunkserver", bucket="disk", bytes=msg.size,
                     )
                     self.slo.observe(
                         "read", dt, trace_id=msg.trace_id,
@@ -1308,7 +1308,7 @@ class ChunkServer(Daemon):
         ).inc()
         self.trace_ring.record(
             session.trace_id, "cs_write_shm", tw0, time.time(),
-            role="chunkserver", bytes=msg.length,
+            role="chunkserver", bucket="disk", bytes=msg.length,
         )
         dt = time.perf_counter() - t0
         self.slo.observe(
@@ -1861,7 +1861,7 @@ class ChunkServer(Daemon):
             session.down_status.pop(msg.write_id, None)
         self.trace_ring.record(
             session.trace_id, "cs_write_bulk", tw0, time.time(),
-            role="chunkserver", bytes=len(msg.data),
+            role="chunkserver", bucket="disk", bytes=len(msg.data),
         )
         dt = time.perf_counter() - t0
         self.slo.observe(

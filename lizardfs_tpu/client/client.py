@@ -48,7 +48,9 @@ from lizardfs_tpu.runtime import faults as _faults
 from lizardfs_tpu.runtime import qos as qosmod
 from lizardfs_tpu.runtime import retry as retrymod
 from lizardfs_tpu.runtime import tracing
-from lizardfs_tpu.runtime.metrics import PhaseBreakdown
+from lizardfs_tpu.runtime.metrics import (
+    READ_PHASES, WRITE_PHASES, PhaseBreakdown,
+)
 from lizardfs_tpu.runtime.rpc import RpcConnection
 from lizardfs_tpu.utils import striping
 
@@ -209,31 +211,25 @@ class Client:
         self._locate_gen = 0
         self.locate_cache_ttl = 3.0
         self.cache.add_invalidate_listener(self._drop_locates)
-        # per-phase busy-time accounting for the write data path
-        # (encode/stage/send/ack/commit); pipelined phases overlap, so
-        # the phase sum may exceed wall time — see runtime.metrics.
-        # "send" is the push cost (socket copy, or descriptor writes on
-        # the shm-ring plane); "ack" is the windowed path's completion
-        # wait (downstream backpressure). Through r06 ack waits were
-        # folded into send_ms — compare r07+ send_ms to older rounds as
-        # send_ms + ack_ms.
-        self.write_phases = PhaseBreakdown(
-            "client_write", ("encode", "stage", "send", "ack", "commit")
-        )
-        # the read-side twin: busy-time per logical read decomposed as
-        # locate (master RPC), dial (pool-miss connects), wait (QoS
-        # throttle + retry backoff + shed waits), net (socket transfer,
-        # incl. the native gather call), decode (plan postprocess /
-        # EC recovery), gather (stripe de-interleave). Deep layers that
-        # can't see the client (conn pool, read executor) charge via
-        # tracing.PHASE_SINK, activated around each logical read.
-        self.read_phases = PhaseBreakdown(
-            "client_read",
-            ("locate", "dial", "wait", "net", "decode", "gather"),
-        )
-        # request-scoped span ring (runtime/tracing.py): phase charges
-        # double as client-role spans when the op runs under a trace;
-        # merge with daemon `trace-dump` output via tracing.merge_timeline
+        # per-phase busy-time accounting of a logical write, as a tree
+        # (runtime.metrics.WRITE_PHASES): getattr, lock, grant, rmw_read,
+        # stage, throttle, encode, send, ack, commit at the top level;
+        # pipelined phases overlap, so the phase sum may exceed wall
+        # time — see runtime.metrics. "send" is the push cost (socket
+        # copy, or descriptor writes on the shm-ring plane); "ack" is
+        # the windowed path's completion wait (downstream
+        # backpressure). Through PR 23 "commit" held the grant too.
+        self.write_phases = PhaseBreakdown("client_write", WRITE_PHASES)
+        # the read-side twin (READ_PHASES): locate (master RPC), wait
+        # (QoS throttle + retry backoff + shed waits), plan, waves (the
+        # plan's parallel part reads: net, socket transfer incl. the
+        # native gather call, and dial, pool-miss connects, inside it),
+        # decode (plan postprocess / EC recovery), gather (stripe
+        # de-interleave), copy
+        self.read_phases = PhaseBreakdown("client_read", READ_PHASES)
+        # request-scoped span ring (runtime/tracing.py): every
+        # tracing.span of an op lands here with its parent; merge with
+        # daemon `trace-dump` output via tracing.merge_timeline
         self.trace_ring = tracing.SpanRing()
         # double-buffered stripe pipeline for striped (xor/ec) chunk
         # writes: encode stripe segment i+1 while segment i's parts are
@@ -260,6 +256,16 @@ class Client:
 
         self.metrics = metrics if metrics is not None else Metrics()
         export_backend(self.metrics, self.encoder)
+        # what a logical write / read installs as ambient for its
+        # spans: layers below the client (the encoder, striping, the
+        # conn pool, native_io's worker threads, the read executor)
+        # charge whichever op is in flight through tracing.span
+        self._write_op = tracing.OpSink(
+            self.write_phases, self.trace_ring, "client", self.metrics
+        )
+        self._read_op = tracing.OpSink(
+            self.read_phases, self.trace_ring, "client", self.metrics
+        )
         # adaptive N-deep write window (spends PR 1's phase telemetry):
         # up to LZ_WRITE_WINDOW stripe segments ride unacknowledged per
         # striped chunk write under per-chunkserver credits + a shared
@@ -353,28 +359,26 @@ class Client:
             pid if pid is not None else os.getpid()
         )
 
-    async def _throttle(self, nbytes: int) -> None:
+    async def _throttle(self, nbytes: int, phase: str = "throttle") -> None:
         """Apply the master-coordinated IO limit to a data transfer,
-        under the calling process's limit group. Traced as its own
-        ``throttle`` span: QoS pacing and the limit-renew RPC are
-        deliberately excluded from the send phase (charging pacing as
-        transfer time would misattribute), so without a span of their
-        own they would be an anonymous hole in every merged timeline."""
-        tw0 = _time.time()
-        try:
-            await self._throttle_inner(nbytes)
-        finally:
-            self.trace_ring.record(
-                tracing.current_trace_id(), "throttle", tw0, _time.time(),
-                role="client",
-            )
-
-    async def _throttle_inner(self, nbytes: int) -> None:
+        under the calling process's limit group. Its own ``throttle``
+        span: QoS pacing and the limit-renew RPC are deliberately
+        excluded from the send phase (charging pacing as transfer time
+        would misattribute), so without a span of their own they would
+        be an anonymous hole in every merged timeline. A read charges
+        it as ``wait``."""
         group = self._io_group_of_caller()
         state = self._io_groups.setdefault(
             group, {"bucket": None, "next_renew": 0.0}
         )
         now = _time.monotonic()
+        if state["bucket"] is None and now < state["next_renew"]:
+            return  # no limit on this group, none to ask for: no wait
+        with tracing.span("throttle", phase=phase, bucket="queue"):
+            await self._throttle_inner(group, state, now, nbytes)
+
+    async def _throttle_inner(self, group: str, state: dict, now: float,
+                              nbytes: int) -> None:
         if now >= state["next_renew"]:
             state["next_renew"] = now + 1.0
             try:
@@ -524,46 +528,6 @@ class Client:
                 last = e
         raise ConnectionError(f"no active master reachable: {last}")
 
-    def _t0(self) -> tuple[float, float]:
-        """(perf_counter, wall) pair opening a phase: the first feeds
-        the PhaseBreakdown, the second anchors the span's timeline."""
-        return (_time.perf_counter(), _time.time())
-
-    def _phase(self, name: str, t0: tuple[float, float]) -> None:
-        """Charge a write phase and, when the op runs under a trace,
-        record the same interval as a client-role span."""
-        self.write_phases.add(name, _time.perf_counter() - t0[0])
-        self.trace_ring.record(
-            tracing.current_trace_id(), name, t0[1], _time.time(),
-            role="client",
-        )
-
-    def _read_phase(self, name: str, t0: tuple[float, float]) -> None:
-        """Charge a read phase (+ client-role span under a trace)."""
-        self.read_phases.add(name, _time.perf_counter() - t0[0])
-        self.trace_ring.record(
-            tracing.current_trace_id(), f"read:{name}", t0[1], _time.time(),
-            role="client",
-        )
-
-    def _read_sink(self, phase: str, t0, t1) -> None:
-        """tracing.PHASE_SINK target: layers below the client (connection
-        pool dials, read-executor socket waits and plan postprocess)
-        charge the ambient logical read's phases here. Pool-miss dials
-        double as the ``dial`` queue-wait gate."""
-        self.read_phases.add(phase, max(t1[0] - t0[0], 0.0))
-        tid = tracing.current_trace_id()
-        if tid:
-            self.trace_ring.record(
-                tid, f"read:{phase}", t0[1], t1[1], role="client"
-            )
-        if phase == "dial":
-            # ring=None: the read:dial span above already lands in the
-            # attribution queue bucket; a twin span would be noise
-            tracing.charge_queue_wait(
-                self.metrics, None, "dial", "default", t0, role="client"
-            )
-
     async def _busy_retry(self, fn, what: str):
         """Honor QoS fair-share sheds: a BUSY status is retried here
         with a jittered backoff seeded by the server's retry-after
@@ -601,12 +565,13 @@ class Client:
                 # shed-retry waits are a queue-wait gate: the op did no
                 # work, it queued behind fair-share admission
                 w0 = tracing.phase_t0()
-                await asyncio.sleep(delay)
+                with tracing.span("backoff", phase="wait", bucket="queue",
+                                  gate="busy_retry"):
+                    await asyncio.sleep(delay)
+                # ring=None: the backoff span is the ring's record
                 tracing.charge_queue_wait(
-                    self.metrics, self.trace_ring, "busy_retry", "default",
-                    w0, role="client",
+                    self.metrics, None, "busy_retry", "default", w0,
                 )
-                tracing.charge_phase("wait", w0)
                 attempt += 1
 
     async def _call(self, msg_cls, **fields):
@@ -617,9 +582,13 @@ class Client:
         # record ONCE, outside the busy-retry loop: a shed-and-retried
         # op is one logical op in op_counters/oplog
         self._record(msg_cls.__name__)
-        return await self._busy_retry(
-            lambda: self._call_once(msg_cls, **fields), msg_cls.__name__
-        )
+        # one span an RPC, named by message class: under a grant /
+        # locate / commit span it is what the wire and the two loops
+        # cost beside the master's own stamped handler time
+        with tracing.span(msg_cls.__name__, layer="rpc", bucket="net"):
+            return await self._busy_retry(
+                lambda: self._call_once(msg_cls, **fields), msg_cls.__name__
+            )
 
     async def _call_once(self, msg_cls, **fields):
         if msg_cls.FIELDS and msg_cls.FIELDS[-1][0] == "trace_id":
@@ -748,7 +717,9 @@ class Client:
             if tid:
                 fields.setdefault("trace_id", tid)
         try:
-            r = await conn.call(msg_cls, timeout=10.0, **fields)
+            with tracing.span(msg_cls.__name__, layer="rpc", bucket="net",
+                              replica=True):
+                r = await conn.call(msg_cls, timeout=10.0, **fields)
         except (OSError, ConnectionError, asyncio.TimeoutError):
             await self._drop_replica()
             self.metrics.counter("shadow_fallbacks").inc()
@@ -1389,21 +1360,20 @@ class Client:
         data = np.frombuffer(bytes(data), dtype=np.uint8)
         total = len(data)
         wall_t0 = _time.perf_counter()
-        # each top-level write is one traced request (unless the caller
-        # already runs under a trace); chunk tasks inherit the context,
-        # and a trace WE started is cleared on the way out so the next
-        # op in this task gets its own id
-        tid, fresh_trace = tracing.begin()
-        tw0 = _time.time()
+        # each top-level write is one span tree under one trace (its
+        # own, unless the caller already runs under one); chunk tasks
+        # inherit the context, and a trace the root started ends with it
+        # so the next op in this task gets its own id
+        root = tracing.span(
+            "write_file", sink=self._write_op, bytes=total
+        ).begin()
         try:
             # every chunk task spawned below copies this context — the
             # native scatter path reads the session from it in-task
             session_ctx = accounting.task_session(self.session_id)
             session_ctx.__enter__()
-            old_length = (await self.getattr(inode)).length
-            self.trace_ring.record(
-                tid, "getattr", tw0, _time.time(), role="client"
-            )
+            with tracing.span("getattr", phase="getattr", bucket="net"):
+                old_length = (await self.getattr(inode)).length
             # a small in-flight window pipelines chunk N+1's grant +
             # transfer behind chunk N's tail (write_cache_window
             # analog); chunks are independent (separate ids/versions)
@@ -1462,16 +1432,12 @@ class Client:
                         )
             if old_length > total:
                 await self.truncate(inode, total)
-            self.write_phases.add_wall(_time.perf_counter() - wall_t0)
-            self.trace_ring.record(
-                tid, "write_file", tw0, _time.time(), role="client",
-                bytes=total,
-            )
             # ONE logical write == ONE accounting record, regardless of
             # how many transient retries the chunks above burned
             self.session_ops.record(
                 self.session_id, "write",
-                _time.perf_counter() - wall_t0, nbytes=total, trace_id=tid,
+                _time.perf_counter() - wall_t0, nbytes=total,
+                trace_id=root.trace_id,
             )
         finally:
             # manual __enter__/__exit__ pair: the session scope must
@@ -1479,7 +1445,9 @@ class Client:
             # second with-block (tokens reset in reverse order, same
             # task, so pairing across the try/finally is sound)
             session_ctx.__exit__(None, None, None)
-            tracing.end(fresh_trace)
+            # the root closes the rep (wall, self time) exactly once,
+            # whatever the chunks above retried
+            root.end()
 
     async def pwrite(self, inode: int, offset: int, data: bytes | np.ndarray) -> None:
         """Positional write at an arbitrary offset (POSIX pwrite).
@@ -1493,14 +1461,16 @@ class Client:
         if len(data) == 0:
             return
         wall_t0 = _time.perf_counter()
-        tid, fresh_trace = tracing.begin()
-        tw0 = _time.time()
+        root = tracing.span(
+            "pwrite", sink=self._write_op, bytes=len(data)
+        ).begin()
         try:
             # session scope for the RMW read-backs + native write path
             # (paired __exit__ in the finally, as in write_file)
             session_ctx = accounting.task_session(self.session_id)
             session_ctx.__enter__()
-            old_length = (await self.getattr(inode)).length
+            with tracing.span("getattr", phase="getattr", bucket="net"):
+                old_length = (await self.getattr(inode)).length
             end = offset + len(data)
             pos = offset
             while pos < end:
@@ -1513,24 +1483,18 @@ class Client:
                     old_length, max(old_length, end),
                 )
                 pos += take
-            # the RMW path charges encode/send phases above — close the
-            # rep so phase sums stay attributable against wall time for
-            # pwrite-heavy workloads too
-            self.write_phases.add_wall(_time.perf_counter() - wall_t0)
-            self.trace_ring.record(
-                tid, "pwrite", tw0, _time.time(), role="client",
-                bytes=len(data),
-            )
             # one logical pwrite counts once — RMW retries inside
             # _pwrite_chunk are implementation detail
             self.session_ops.record(
                 self.session_id, "write",
                 _time.perf_counter() - wall_t0, nbytes=len(data),
-                trace_id=tid,
+                trace_id=root.trace_id,
             )
         finally:
             session_ctx.__exit__(None, None, None)
-            tracing.end(fresh_trace)
+            # closes the rep: the phases charged above stay attributable
+            # against wall time for pwrite-heavy workloads too
+            root.end()
 
     async def _pwrite_chunk(
         self, inode: int, ci: int, coff: int, piece: np.ndarray,
@@ -1545,7 +1509,12 @@ class Client:
             entry = self._chunk_write_locks[key] = [asyncio.Lock(), 0]
         entry[1] += 1
         try:
-            async with entry[0]:
+            if entry[0].locked():
+                with tracing.span("lock", phase="lock", bucket="queue"):
+                    await entry[0].acquire()
+            else:
+                await entry[0].acquire()  # free: nothing to wait for
+            try:
                 # a failed attempt can leave parts torn (some written,
                 # some not, parity stale); each retry takes a FRESH grant
                 # — the version bump drops unreachable holders and the
@@ -1565,6 +1534,8 @@ class Client:
                     )
 
                 await self._retry_transient(f"pwrite chunk {ci}", attempt)
+            finally:
+                entry[0].release()
         finally:
             entry[1] -= 1
             if entry[1] == 0 and self._chunk_write_locks.get(key) is entry:
@@ -1575,10 +1546,7 @@ class Client:
         old_length: int, new_length: int,
         rmw_cache: dict | None = None,
     ) -> None:
-        grant = await self._call(
-            m.CltomaWriteChunk, inode=inode, chunk_index=ci,
-            **self._ident(None, None),
-        )
+        grant = await self._grant(inode, ci)
         self.cache.invalidate(inode, ci)
         status_code = st.EIO
         try:
@@ -1603,15 +1571,41 @@ class Client:
                                         piece, grant.file_length, rmw_cache)
             status_code = st.OK
         finally:
-            await self._call(
-                m.CltomaWriteChunkEnd,
-                chunk_id=grant.chunk_id, inode=inode, chunk_index=ci,
-                file_length=new_length, status=status_code,
-            )
+            with tracing.span("commit", phase="commit", bucket="net"):
+                await self._call(
+                    m.CltomaWriteChunkEnd,
+                    chunk_id=grant.chunk_id, inode=inode, chunk_index=ci,
+                    file_length=new_length, status=status_code,
+                )
             # a locate cached BETWEEN this write's grant and its end
             # carries the pre-write length/identity — drop again now
             # (the master's end-of-write push excludes our own session)
             self._drop_locates(inode)
+
+    async def _grant(self, inode: int, chunk_index: int):
+        """The write grant (CltomaWriteChunk) as a ``grant`` span."""
+        with tracing.span("grant", phase="grant", bucket="net") as sp:
+            grant = await self._call(
+                m.CltomaWriteChunk, inode=inode, chunk_index=chunk_index,
+                **self._ident(None, None),
+            )
+            self._note_srv(sp, "grant_srv", grant)
+        return grant
+
+    @staticmethod
+    def _note_srv(sp, phase: str, reply) -> None:
+        """The master's stamped handler time (``srv_us``, 0 from a
+        master that predates it) as an attribute of the client's span
+        and a phase of its own, laid in the middle of the round trip:
+        what is left of the span is the wire and the two loops."""
+        srv_s = getattr(reply, "srv_us", 0) / 1e6
+        if srv_s <= 0:
+            return
+        sp.attrs["srv_us"] = reply.srv_us
+        now = _time.perf_counter()
+        mid = sp.p0 + max(now - sp.p0 - srv_s, 0.0) / 2
+        tracing.span(phase, layer="master", phase=phase,
+                     bucket="compute").begin(at=mid).end(at=min(mid + srv_s, now))
 
     async def _rmw_striped(
         self, grant, slice_type, copies, ci: int, coff: int,
@@ -1670,10 +1664,11 @@ class Client:
             if not planner.is_readable(wanted):
                 raise ReadError("not enough parts for read-modify-write")
             plan = planner.build_plan(wanted, lo_s, nstripes, part_sizes)
-            buf = await execute_plan(
-                plan, grant.chunk_id, grant.version, by_part,
-                wave_timeout=self.wave_timeout,
-            )
+            with tracing.span("rmw_read", phase="rmw_read", bucket="net"):
+                buf = await execute_plan(
+                    plan, grant.chunk_id, grant.version, by_part,
+                    wave_timeout=self.wave_timeout,
+                )
             bps = nstripes * MFSBLOCKSIZE
             data_parts = {
                 wanted[i]: buf[i * bps : (i + 1) * bps] for i in range(d)
@@ -1694,11 +1689,10 @@ class Client:
         """Encode + rewrite the RMW region's parts (the write half of
         _rmw_striped, shared by first attempts and torn-state
         retries)."""
-        t0 = self._t0()
-        parts = await asyncio.to_thread(
-            striping.split_chunk, region, slice_type, self.encoder
-        )
-        self._phase("encode", t0)
+        with tracing.span("encode", phase="encode", bucket="compute"):
+            parts = await asyncio.to_thread(
+                striping.split_chunk, region, slice_type, self.encoder
+            )
         sends = []
         for part_idx, locs in copies.items():
             stream = parts.get(part_idx)
@@ -1712,20 +1706,14 @@ class Client:
                     part_offset=lo_s * MFSBLOCKSIZE,
                 )
             )
-        t0 = self._t0()
-        await asyncio.gather(*sends)
-        self._phase("send", t0)
+        with tracing.span("send", phase="send", bucket="net"):
+            await asyncio.gather(*sends)
 
     async def _write_chunk(
         self, inode: int, chunk_index: int, chunk_data: np.ndarray,
         file_length: int, defer_end: bool = False,
     ) -> None:
-        t0 = self._t0()
-        grant = await self._call(
-            m.CltomaWriteChunk, inode=inode, chunk_index=chunk_index,
-            **self._ident(None, None),
-        )
-        self._phase("commit", t0)
+        grant = await self._grant(inode, chunk_index)
         self.cache.invalidate(inode, chunk_index)
         status_code = st.EIO
         try:
@@ -1745,16 +1733,15 @@ class Client:
                 ):
                     await self._flush_chunk_ends()
             else:
-                t0 = self._t0()
-                await self._call(
-                    m.CltomaWriteChunkEnd,
-                    chunk_id=grant.chunk_id,
-                    inode=inode,
-                    chunk_index=chunk_index,
-                    file_length=file_length,
-                    status=status_code,
-                )
-                self._phase("commit", t0)
+                with tracing.span("commit", phase="commit", bucket="net"):
+                    await self._call(
+                        m.CltomaWriteChunkEnd,
+                        chunk_id=grant.chunk_id,
+                        inode=inode,
+                        chunk_index=chunk_index,
+                        file_length=file_length,
+                        status=status_code,
+                    )
             # see _write_chunk's twin: locates cached mid-write carry
             # pre-write length/identity and must not outlive the write
             self._drop_locates(inode)
@@ -1767,12 +1754,13 @@ class Client:
         if win is None or not win.pending_ends:
             return
         batch = win.drain_ends()
-        t0 = self._t0()
         try:
-            await self._call(
-                m.CltomaWriteChunkEndBatch,
-                ends=[m.WriteChunkEndEntry(**e) for e in batch],
-            )
+            with tracing.span("commit", phase="commit", bucket="net",
+                              ends=len(batch)):
+                await self._call(
+                    m.CltomaWriteChunkEndBatch,
+                    ends=[m.WriteChunkEndEntry(**e) for e in batch],
+                )
         except st.StatusError:
             # a STATUS reply proves the master consumed the batch (it
             # applies every entry it can and reports the first failure,
@@ -1787,7 +1775,6 @@ class Client:
             # write's length/locks to this one's failure
             win.requeue_ends(batch)
             raise
-        self._phase("commit", t0)
         win.note_coalesced(len(batch))
         self._record("write_commit_batch")
 
@@ -1842,8 +1829,7 @@ class Client:
                 # booked as send_ms, or a throttled client's phase row
                 # misattributes pacing as chunkserver transfer time
                 await self._throttle(sum(lengths))
-            t0 = self._t0()
-            try:
+            with tracing.span("send", phase="send", bucket="net"):
                 if (
                     native_io.parts_scatter_available()
                     and not _faults.ACTIVE
@@ -1880,8 +1866,6 @@ class Client:
                     send_of(p, pay, skip_throttle=True)
                     for p, pay in items
                 ))
-            finally:
-                self._phase("send", t0)
 
         from lizardfs_tpu.core import native_io
 
@@ -1932,17 +1916,15 @@ class Client:
         nblocks = -(-len(chunk_data) // MFSBLOCKSIZE)
         part_len = -(-nblocks // d) * MFSBLOCKSIZE
         stage = self._stage_acquire(d, part_len)
-        t0 = self._t0()
-        stacked, _ = await asyncio.to_thread(
-            striping.padded_data_parts, chunk_data, d, stage
-        )
-        self._phase("stage", t0)
+        with tracing.span("stage", phase="stage", bucket="compute"):
+            stacked, _ = await asyncio.to_thread(
+                striping.padded_data_parts, chunk_data, d, stage
+            )
         first = 1 if slice_type.is_xor else 0
         full_chunk = len(chunk_data) == MFSCHUNKSIZE
 
         async def parity_parts() -> dict[int, np.ndarray]:
-            t0 = self._t0()
-            try:
+            with tracing.span("encode", phase="encode", bucket="compute"):
                 if slice_type.is_xor:
                     par = await asyncio.to_thread(
                         self.encoder.xor_parity, stacked
@@ -1953,8 +1935,6 @@ class Client:
                     list(stacked),
                 )
                 return {d + j: p for j, p in enumerate(par)}
-            finally:
-                self._phase("encode", t0)
 
         try:
             throttled = False
@@ -2200,29 +2180,26 @@ class Client:
             # failure propagates down the chain
             if after is not None:
                 await after
-            t0 = self._t0()
-            await native_io.run(
-                session.send_segment, seg_payloads(a, b),
-                seg_lengths(a, b), a, wid,
-            )
-            self._phase("send", t0)
+            with tracing.span("send", phase="send", bucket="net", seg=wid):
+                await native_io.run(
+                    session.send_segment, seg_payloads(a, b),
+                    seg_lengths(a, b), a, wid,
+                )
 
         send_tasks: list[asyncio.Task] = []
         try:
-            t0 = self._t0()
-            await native_io.run(session.open)
-            self._phase("send", t0)
+            with tracing.span("send", phase="send", bucket="net", seg=0):
+                await native_io.run(session.open)
             for wid, (a, b) in enumerate(bounds, start=1):
-                t0 = self._t0()
-                await asyncio.to_thread(encode_segment, a, b)
-                self._phase("encode", t0)
+                with tracing.span("encode", phase="encode",
+                                  bucket="compute", seg=wid):
+                    await asyncio.to_thread(encode_segment, a, b)
                 send_tasks.append(asyncio.ensure_future(send_segment(
                     a, b, wid, send_tasks[-1] if send_tasks else None
                 )))
             await send_tasks[-1]
-            t0 = self._t0()
-            await native_io.run(session.finish)
-            self._phase("send", t0)
+            with tracing.span("send", phase="send", bucket="net", seg=-1):
+                await native_io.run(session.finish)
         except BaseException:
             for t in send_tasks:
                 t.cancel()
@@ -2278,9 +2255,8 @@ class Client:
         # (write_id, credited bytes, encode seconds, send seconds so far)
         outstanding: deque[list] = deque()
         try:
-            t0 = self._t0()
-            await native_io.run(session.open)
-            self._phase("send", t0)
+            with tracing.span("send", phase="send", bucket="net", seg=0):
+                await native_io.run(session.open)
             for wid, (a, b) in enumerate(bounds, start=1):
                 lengths = seg_lengths(a, b)
                 # shm-ring staging: reserve this segment's arena regions
@@ -2298,14 +2274,15 @@ class Client:
                     while views is None and outstanding:
                         await self._window_collect(session, win, outstanding)
                         views = session.ring_stage(wid, lengths, widths)
-                t0 = self._t0()
+                t0 = _time.perf_counter()
                 try:
-                    await asyncio.to_thread(encode_segment, a, b, views)
+                    with tracing.span("encode", phase="encode",
+                                      bucket="compute", seg=wid):
+                        await asyncio.to_thread(encode_segment, a, b, views)
                 except BaseException:
                     session.ring_unstage(wid)
                     raise
-                enc_dt = _time.perf_counter() - t0[0]
-                self._phase("encode", t0)
+                enc_dt = _time.perf_counter() - t0
                 payloads = seg_payloads(a, b)
                 if views is not None:
                     # parity already lives in its staged arena view —
@@ -2343,13 +2320,14 @@ class Client:
                         "default", w0, role="client",
                     )
                 try:
-                    t0 = self._t0()
-                    await native_io.run(
-                        session.send_segment_window, payloads, lengths,
-                        a, wid,
-                    )
-                    send_dt = _time.perf_counter() - t0[0]
-                    self._phase("send", t0)
+                    t0 = _time.perf_counter()
+                    with tracing.span("send", phase="send", bucket="net",
+                                      seg=wid):
+                        await native_io.run(
+                            session.send_segment_window, payloads, lengths,
+                            a, wid,
+                        )
+                    send_dt = _time.perf_counter() - t0
                 except BaseException:
                     win.release(session.unique_addrs, seg_bytes)
                     raise
@@ -2361,9 +2339,8 @@ class Client:
                     await self._window_collect(session, win, outstanding)
             while outstanding:
                 await self._window_collect(session, win, outstanding)
-            t0 = self._t0()
-            await native_io.run(session.finish)
-            self._phase("send", t0)
+            with tracing.span("send", phase="send", bucket="net", seg=-1):
+                await native_io.run(session.finish)
         except BaseException:
             # the session's executor thread may still be streaming from
             # stacked/par_buf — kill the exchange before those buffers
@@ -2417,15 +2394,15 @@ class Client:
 
         wid, seg_bytes, enc_dt, send_dt = outstanding.popleft()
         try:
-            t0 = self._t0()
-            await native_io.run(session.collect_acks, wid)
+            t0 = _time.perf_counter()
             # ack-reaping is backpressure (downstream disk/CPU), not
-            # push cost — charge it to its own phase so send_ms keeps
-            # measuring the copy the shm ring exists to eliminate; the
-            # depth controller still sees the combined time (ack wait
-            # is exactly the send-bound signal that should deepen it)
-            send_dt += _time.perf_counter() - t0[0]
-            self._phase("ack", t0)
+            # push cost — its own phase, so send_ms keeps measuring the
+            # copy the shm ring exists to eliminate; the depth
+            # controller still sees the combined time (ack wait is
+            # exactly the send-bound signal that should deepen it)
+            with tracing.span("ack", phase="ack", bucket="net", seg=wid):
+                await native_io.run(session.collect_acks, wid)
+            send_dt += _time.perf_counter() - t0
         finally:
             win.release(session.unique_addrs, seg_bytes)
         win.observe(enc_dt, send_dt)
@@ -2452,19 +2429,41 @@ class Client:
         if not skip_throttle:
             await self._throttle(max(length, 0))
         head = locs[0]
-        chain = locs[1:]
 
         # bulk writes stream their pieces in C++ off the event loop
         from lizardfs_tpu.core import native_io
 
-        if (
+        native = (
             native_io.available()
             and length >= native_io.NATIVE_WRITE_THRESHOLD
             # armed faults: the C++ streamer can't be instrumented —
             # the framed asyncio path below serves (LZ_FAULTS unset:
             # byte-identical, the gate is one module-attribute check)
             and not _faults.ACTIVE
+        )
+        # one span a part on either plane; under it the wait for a
+        # worker thread (hop, native) or the dial, then init, data,
+        # ack and end (the native streamer's data span holds its acks)
+        with tracing.span(
+            "part", layer="wire", phase="part", bucket="net",
+            part=head.part_id, bytes=max(length, 0),
+            plane="native" if native else "asyncio",
         ):
+            await self._write_part_on(
+                native, chunk_id, version, locs, payload, length,
+                part_offset, cell,
+            )
+
+    async def _write_part_on(
+        self, native: bool, chunk_id: int, version: int,
+        locs: list[m.PartLocation], payload: np.ndarray, length: int,
+        part_offset: int, cell: dict | None,
+    ) -> None:
+        from lizardfs_tpu.core import native_io
+
+        head = locs[0]
+        chain = locs[1:]
+        if native:
             if cell is not None:
                 # marked BEFORE the executor hand-off: an abort racing
                 # the thread's connect phase must still see a zombie
@@ -2491,29 +2490,33 @@ class Client:
             )
         # bounded dial (unbounded-await audit): honors any ambient
         # RetryPolicy deadline on top of the 5 s cap
-        reader, writer = await retrymod.bounded_wait(
-            asyncio.open_connection(head.addr.host, head.addr.port), 5.0
-        )
+        with tracing.span("part_dial", layer="wire", phase="part_dial",
+                          bucket="queue"):
+            reader, writer = await retrymod.bounded_wait(
+                asyncio.open_connection(head.addr.host, head.addr.port), 5.0
+            )
         try:
-            await framing.send_message(
-                writer,
-                m.CltocsWriteInit(
-                    req_id=1,
-                    chunk_id=chunk_id,
-                    version=version,
-                    part_id=head.part_id,
-                    chain=chain,
-                    create=False,
-                    session_id=self.session_id,
-                ),
-            )
-            # every reply wait is deadline-bounded (unbounded-await
-            # audit): a chunkserver that accepts frames but never acks
-            # fails this part write in bounded time instead of wedging
-            # the session forever
-            init = await retrymod.bounded_wait(
-                framing.read_message(reader), 30.0
-            )
+            with tracing.span("part_init", layer="wire", phase="part_init",
+                              bucket="net"):
+                await framing.send_message(
+                    writer,
+                    m.CltocsWriteInit(
+                        req_id=1,
+                        chunk_id=chunk_id,
+                        version=version,
+                        part_id=head.part_id,
+                        chain=chain,
+                        create=False,
+                        session_id=self.session_id,
+                    ),
+                )
+                # every reply wait is deadline-bounded (unbounded-await
+                # audit): a chunkserver that accepts frames but never
+                # acks fails this part write in bounded time instead of
+                # wedging the session forever
+                init = await retrymod.bounded_wait(
+                    framing.read_message(reader), 30.0
+                )
             if not isinstance(init, m.CstoclWriteStatus) or init.status != st.OK:
                 raise st.StatusError(getattr(init, "status", st.EIO), "write init")
             nbytes = max(length, 0)
@@ -2522,44 +2525,52 @@ class Client:
             from lizardfs_tpu.ops import crc32 as crc_mod
 
             pos = 0
-            while pos < nbytes:
-                abs_off = part_offset + pos
-                block = abs_off // MFSBLOCKSIZE
-                block_off = abs_off % MFSBLOCKSIZE
-                take = min(MFSBLOCKSIZE - block_off, nbytes - pos)
-                piece = payload[pos : pos + take].tobytes()
-                pos += take
-                if not piece:
-                    continue
-                write_id += 1
-                expected.add(write_id)
+            with tracing.span("part_data", layer="wire",
+                              phase="part_data", bucket="net"):
+                while pos < nbytes:
+                    abs_off = part_offset + pos
+                    block = abs_off // MFSBLOCKSIZE
+                    block_off = abs_off % MFSBLOCKSIZE
+                    take = min(MFSBLOCKSIZE - block_off, nbytes - pos)
+                    piece = payload[pos : pos + take].tobytes()
+                    pos += take
+                    if not piece:
+                        continue
+                    write_id += 1
+                    expected.add(write_id)
+                    await framing.send_message(
+                        writer,
+                        m.CltocsWriteData(
+                            req_id=write_id,
+                            chunk_id=chunk_id,
+                            write_id=write_id,
+                            block=block,
+                            offset=block_off,
+                            crc=crc_mod.crc32(piece),
+                            data=piece,
+                        ),
+                    )
+            with tracing.span("part_ack", layer="wire", phase="part_ack",
+                              bucket="net"):
+                while expected:
+                    msg = await retrymod.bounded_wait(
+                        framing.read_message(reader), 30.0
+                    )
+                    if not isinstance(msg, m.CstoclWriteStatus):
+                        raise st.StatusError(
+                            st.EIO, "unexpected write reply")
+                    if msg.status != st.OK:
+                        raise st.StatusError(
+                            msg.status, f"write id {msg.write_id}")
+                    expected.discard(msg.write_id)
+            with tracing.span("part_end", layer="wire", phase="part_end",
+                              bucket="net"):
                 await framing.send_message(
-                    writer,
-                    m.CltocsWriteData(
-                        req_id=write_id,
-                        chunk_id=chunk_id,
-                        write_id=write_id,
-                        block=block,
-                        offset=block_off,
-                        crc=crc_mod.crc32(piece),
-                        data=piece,
-                    ),
+                    writer, m.CltocsWriteEnd(req_id=0, chunk_id=chunk_id)
                 )
-            while expected:
-                msg = await retrymod.bounded_wait(
+                end = await retrymod.bounded_wait(
                     framing.read_message(reader), 30.0
                 )
-                if not isinstance(msg, m.CstoclWriteStatus):
-                    raise st.StatusError(st.EIO, "unexpected write reply")
-                if msg.status != st.OK:
-                    raise st.StatusError(msg.status, f"write id {msg.write_id}")
-                expected.discard(msg.write_id)
-            await framing.send_message(
-                writer, m.CltocsWriteEnd(req_id=0, chunk_id=chunk_id)
-            )
-            end = await retrymod.bounded_wait(
-                framing.read_message(reader), 30.0
-            )
             if not isinstance(end, m.CstoclWriteStatus) or end.status != st.OK:
                 raise st.StatusError(getattr(end, "status", st.EIO), "write end")
         finally:
@@ -2569,31 +2580,22 @@ class Client:
 
     async def read_file(self, inode: int, offset: int = 0, size: int | None = None) -> bytes:
         t0 = _time.perf_counter()
-        tw0 = _time.time()
-        tid, fresh_trace = tracing.begin()
-        # the read-phase sink is scoped to THIS logical read: every
-        # locate/dial/wait/net/decode/gather charge below — including
-        # ones from the conn pool and read executor — lands on this
-        # client's read_phases exactly once (retries/fallbacks re-enter
-        # phases, never the wall/rep accounting)
-        sink_tok = tracing.PHASE_SINK.set(self._read_sink)
-        try:
+        # the root span scopes the read's sink to THIS logical read:
+        # every locate/dial/wait/net/decode/gather span below —
+        # including ones from the conn pool and read executor — lands
+        # on this client's read_phases exactly once (retries/fallbacks
+        # re-enter phases, never the wall/rep accounting the root
+        # closes); it is also the attribution wall anchor
+        # (`trace-dump --attribute`)
+        with tracing.span("read_file", sink=self._read_op) as root:
             with accounting.task_session(self.session_id):
                 data = await self._read_file_inner(inode, offset, size)
-        finally:
-            tracing.PHASE_SINK.reset(sink_tok)
-            tracing.end(fresh_trace)
+            root.attrs["bytes"] = len(data)
         # ONE logical read == ONE accounting record: replica fallbacks
         # and dead-holder retries below this line never double-count
-        dt = _time.perf_counter() - t0
-        self.read_phases.add_wall(dt)
-        # root span: the attribution wall anchor (`trace-dump --attribute`)
-        self.trace_ring.record(
-            tid, "read_file", tw0, _time.time(), role="client",
-            bytes=len(data),
-        )
         self.session_ops.record(
-            self.session_id, "read", dt, nbytes=len(data), trace_id=tid,
+            self.session_id, "read", _time.perf_counter() - t0,
+            nbytes=len(data), trace_id=root.trace_id,
         )
         return data
 
@@ -2636,7 +2638,10 @@ class Client:
                 piece = await self._read_chunk_range(
                     inode, ci, offset - ci * MFSCHUNKSIZE, size, None
                 )
-                return b"" if piece is None else piece.tobytes()
+                if piece is None:
+                    return b""
+                with tracing.span("copy", phase="copy", bucket="compute"):
+                    return piece.tobytes()
         attr = await self.getattr(inode)
         length = attr.length
         if size is None:
@@ -2655,31 +2660,21 @@ class Client:
         at ``offset``; returns bytes read (short at EOF). On the bulk
         path the network recv lands directly in ``out``. ``out`` must be
         C-contiguous uint8."""
-        tid, fresh_trace = tracing.begin()
-        tw0 = _time.time()
         tp0 = _time.perf_counter()
-        sink_tok = tracing.PHASE_SINK.set(self._read_sink)
-        try:
+        with tracing.span("read_file", sink=self._read_op) as root:
             attr = await self.getattr(inode)
             length = attr.length
             end = min(offset + out.size, length)
             if end <= offset:
                 return 0
-            n = end - offset
+            n = root.attrs["bytes"] = end - offset
             with accounting.task_session(self.session_id):
                 await self._read_into(inode, offset, out[:n], length)
-            self.trace_ring.record(
-                tid, "read_file", tw0, _time.time(), role="client", bytes=n
-            )
-            self.read_phases.add_wall(_time.perf_counter() - tp0)
-            self.session_ops.record(
-                self.session_id, "read", _time.time() - tw0, nbytes=n,
-                trace_id=tid,
-            )
-            return n
-        finally:
-            tracing.PHASE_SINK.reset(sink_tok)
-            tracing.end(fresh_trace)
+        self.session_ops.record(
+            self.session_id, "read", _time.perf_counter() - tp0, nbytes=n,
+            trace_id=root.trace_id,
+        )
+        return n
 
     async def _read_into(
         self, inode: int, offset: int, out: np.ndarray, length: int
@@ -2786,16 +2781,14 @@ class Client:
 
         throttled = file_length is not None
         if throttled:
-            t0 = self._t0()
-            await self._throttle(read_size)  # QoS: charge once, not per retry
-            self._read_phase("wait", t0)
+            # QoS: charge once, not per retry
+            await self._throttle(read_size, phase="wait")
         last_error: Exception | None = None
         bad_addrs: set[tuple[str, int]] = set()  # replicas that failed us
         for attempt in range(self.retries):
             if attempt:
-                t0 = self._t0()
-                await asyncio.sleep(min(0.1 * 2 ** attempt, 2.0))  # backoff
-                self._read_phase("wait", t0)
+                with tracing.span("backoff", phase="wait", bucket="queue"):
+                    await asyncio.sleep(min(0.1 * 2 ** attempt, 2.0))
             loc = None
             fresh = False
             if attempt == 0:
@@ -2807,37 +2800,38 @@ class Client:
                         self.op_counters.get("locate_cache_hit", 0) + 1
                     )
             if loc is None:
-                t0 = self._t0()
-                token = self._locate_token(inode)
-                # first attempt may serve the locate from a replica;
-                # RETRY locates go to the primary — a failed read may
-                # mean the replica's mirrored location set lags (e.g.
-                # empty for a chunk just written), and the primary's is
-                # authoritative
-                locate = self._call_read if attempt == 0 else self._call
-                loc = await locate(
-                    m.CltomaReadChunk, inode=inode, chunk_index=chunk_index,
-                    **self._ident(None, None),
-                )
-                fresh = True
-                if (
-                    loc.chunk_id and not loc.locations
-                    and getattr(loc, "_replica_served", False)
-                ):
-                    # a real chunk with no locations FROM A REPLICA: its
-                    # mirrored location set lags (parts registered with
-                    # the primary only so far). Re-locate through the
-                    # primary instead of failing the plan. A primary
-                    # answer with no locations is authoritative — never
-                    # re-ask (that would double locate load during a
-                    # chunkserver outage).
-                    loc = await self._call(
-                        m.CltomaReadChunk, inode=inode,
-                        chunk_index=chunk_index, **self._ident(None, None),
+                with tracing.span("locate", phase="locate",
+                                  bucket="net") as locate_span:
+                    token = self._locate_token(inode)
+                    # first attempt may serve the locate from a replica;
+                    # RETRY locates go to the primary — a failed read may
+                    # mean the replica's mirrored location set lags (e.g.
+                    # empty for a chunk just written), and the primary's is
+                    # authoritative
+                    locate = self._call_read if attempt == 0 else self._call
+                    loc = await locate(
+                        m.CltomaReadChunk, inode=inode, chunk_index=chunk_index,
+                        **self._ident(None, None),
                     )
-                # locate phase: the master round trip(s), replica
-                # fallback included; cache hits charge nothing
-                self._read_phase("locate", t0)
+                    fresh = True
+                    if (
+                        loc.chunk_id and not loc.locations
+                        and getattr(loc, "_replica_served", False)
+                    ):
+                        # a real chunk with no locations FROM A REPLICA: its
+                        # mirrored location set lags (parts registered with
+                        # the primary only so far). Re-locate through the
+                        # primary instead of failing the plan. A primary
+                        # answer with no locations is authoritative — never
+                        # re-ask (that would double locate load during a
+                        # chunkserver outage).
+                        loc = await self._call(
+                            m.CltomaReadChunk, inode=inode,
+                            chunk_index=chunk_index, **self._ident(None, None),
+                        )
+                    # locate phase: the master round trip(s), replica
+                    # fallback included; cache hits charge nothing
+                    self._note_srv(locate_span, "locate_srv", loc)
                 if self._locate_token(inode) == token:
                     # refuse stores that raced an invalidation: the
                     # reply may predate the mutation that bumped epoch
@@ -2878,9 +2872,7 @@ class Client:
                 # provisional geometry would bill EOF reads for bytes
                 # never transferred
                 throttled = True
-                t0 = self._t0()
-                await self._throttle(read_size)
-                self._read_phase("wait", t0)
+                await self._throttle(read_size, phase="wait")
             if loc.chunk_id == 0:
                 if into is not None:
                     into[into_offset : into_offset + size] = 0
@@ -3142,10 +3134,22 @@ class Client:
             and attempt == 0
         ):
             cell: dict = {}
+            # the whole native gather (sockets + C de-interleave) is
+            # charged as net (under waves, as the wave executor's part
+            # reads are), the wait for its worker thread a hop under
+            # it — the chunkserver's queue/disk/net attrs refine it in
+            # the attribution view
+            waves = tracing.span(
+                "waves", layer="wire", phase="waves", bucket="net"
+            ).begin()
+            net = tracing.span(
+                "net", layer="wire", phase="net", bucket="net",
+                plane="gather",
+            ).begin()
             fut = asyncio.get_running_loop().run_in_executor(
                 native_io.EXECUTOR,
                 # partial_with_trace: run_in_executor drops context, so
-                # the request trace id rides the partial instead
+                # the open span and the sink ride the partial instead
                 native_io.partial_with_trace(
                     native_io.read_parts_gather_blocking,
                     [by_part[p][0] for p in wanted],
@@ -3156,14 +3160,12 @@ class Client:
                     cell,
                 ),
             )
-            # run_in_executor does not propagate the phase-sink context;
-            # the whole native gather (sockets + C de-interleave) is
-            # timed at the await and charged as net — the chunkserver's
-            # queue/disk/net attrs refine it in the attribution view
-            t0 = self._t0()
             try:
-                await asyncio.shield(fut)
-                self._read_phase("net", t0)
+                try:
+                    await asyncio.shield(fut)
+                finally:
+                    net.end()
+                    waves.end()
                 for p in wanted:
                     GLOBAL_STATS.record_success(by_part[p][0])
                 # counted so tests/operators can see the fast path is
@@ -3184,15 +3186,16 @@ class Client:
         # per-part scores from the shared chunkserver health registry:
         # an unhealthy holder's part drops in rank, so recovery reads
         # prefer parts on healthy servers (read_plan_executor.cc:95)
-        planner = plans.SliceReadPlanner(
-            slice_type, list(by_part.keys()),
-            scores={p: GLOBAL_STATS.score(a[0])
-                    for p, a in by_part.items()},
-            encoder=self.encoder,
-        )
-        if not planner.is_readable(wanted):
-            raise ReadError("not enough parts available")
-        plan = planner.build_plan(wanted, lo_slot, nslots, part_sizes)
+        with tracing.span("plan", phase="plan", bucket="compute"):
+            planner = plans.SliceReadPlanner(
+                slice_type, list(by_part.keys()),
+                scores={p: GLOBAL_STATS.score(a[0])
+                        for p, a in by_part.items()},
+                encoder=self.encoder,
+            )
+            if not planner.is_readable(wanted):
+                raise ReadError("not enough parts available")
+            plan = planner.build_plan(wanted, lo_slot, nslots, part_sizes)
         # striped plans rotate bad parts internally via waves — no
         # blacklist tagging here, or one dead server would push every
         # healthy part off its topology-preferred copy on retry
@@ -3215,17 +3218,15 @@ class Client:
             and into.flags.c_contiguous and into.dtype == np.uint8
         ):
             # zero-copy: de-interleave straight into the caller's buffer
-            t0 = self._t0()
-            await asyncio.to_thread(
-                striping.assemble_chunk, data_parts, slice_type, size,
-                into[into_offset : into_offset + size],
-            )
-            self._read_phase("gather", t0)
+            with tracing.span("gather", phase="gather", bucket="compute"):
+                await asyncio.to_thread(
+                    striping.assemble_chunk, data_parts, slice_type, size,
+                    into[into_offset : into_offset + size],
+                )
             return None
-        t0 = self._t0()
-        region = await asyncio.to_thread(
-            striping.assemble_chunk, data_parts, slice_type,
-            d * bps,  # bytes covered by these stripes
-        )
-        self._read_phase("gather", t0)
+        with tracing.span("gather", phase="gather", bucket="compute"):
+            region = await asyncio.to_thread(
+                striping.assemble_chunk, data_parts, slice_type,
+                d * bps,  # bytes covered by these stripes
+            )
         return np.asarray(region[rel : rel + size])

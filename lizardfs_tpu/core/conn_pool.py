@@ -62,14 +62,19 @@ class ConnectionPool:
             return conn
         if _faults.ACTIVE:
             await _faults.dial_point("cs", f"{addr[0]}:{addr[1]}")
-        # pool miss: the dial is read-phase "dial" busy-time (and the
-        # `dial` queue-wait gate) on whatever logical read is ambient;
-        # free when no read-phase sink is active
+        # pool miss: the dial is "dial" busy-time, and the `dial`
+        # queue-wait gate, of whatever logical op is ambient
         t0 = _tracing.phase_t0()
-        reader, writer = await _retry.bounded_wait(
-            asyncio.open_connection(*addr), DIAL_TIMEOUT
-        )
-        _tracing.charge_phase("dial", t0)
+        with _tracing.span("dial", layer="wire", phase="dial",
+                           bucket="queue"):
+            reader, writer = await _retry.bounded_wait(
+                asyncio.open_connection(*addr), DIAL_TIMEOUT
+            )
+        sink = _tracing.PHASE_SINK.get()
+        if sink is not None and sink.metrics is not None:
+            # ring=None: the dial span is the ring's record
+            _tracing.charge_queue_wait(
+                sink.metrics, None, "dial", "default", t0)
         return PooledConnection(reader, writer)
 
     def release(self, addr: tuple[str, int], conn: PooledConnection) -> None:

@@ -31,6 +31,7 @@ import numpy as np
 
 from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.ops import crc32, rs
+from lizardfs_tpu.runtime import tracing
 
 log = logging.getLogger("lizardfs.encoder")
 
@@ -171,6 +172,13 @@ class TpuChunkEncoder(ChunkEncoder):
         self._jax = jax
         self._ops = jax_ec
         self._interpret = interpret
+        # this process owns the chip: from now on its spans are also
+        # annotations in the profiler's trace, beside the device's
+        # operations (a daemon never builds this class or imports jax)
+        tracing.register_annotator(
+            jax.profiler.TraceAnnotation,
+            jax.profiler.TraceAnnotation.is_enabled,
+        )
         self._device = device if device is not None else jax.devices()[0]
         if getattr(self._device, "platform", "cpu") != "cpu":
             # before this process's first compile. Not for a CPU-bound
@@ -197,37 +205,59 @@ class TpuChunkEncoder(ChunkEncoder):
     def _put(self, arr: np.ndarray):
         return self._jax.device_put(np.ascontiguousarray(arr), self._device)
 
-    def encode(self, k, m, data_parts):
-        import jax.numpy as jnp
+    def _apply_gf(self, op: str, k: int, m: int, matrix, rows) -> np.ndarray:
+        """One GF(2^8) product across the boundary: ``matrix()`` times
+        ``rows`` (the input parts in the matrix's column order, None =
+        all zeros, whose columns are dropped), as a ``boundary`` span
+        holding four: ``dev_stage`` (the bit matrix and ``np.stack`` on
+        the host), ``dev_put`` (the two ``device_put`` calls, until
+        they return), ``dev_run`` (``apply_gf``, until it returns) and
+        ``dev_fetch`` (``np.asarray``, which blocks until upload,
+        kernel and download are done). No synchronisation is added:
+        the device trace under ``dev_fetch`` gives the kernel, the rest
+        of it is transfer and wake-up."""
+        live = [j for j, r in enumerate(rows) if r is not None]
+        if not live:
+            raise ValueError("at least one input part must be non-None")
 
-        nonzero = [i for i, p in enumerate(data_parts) if p is not None]
-        if not nonzero:
-            raise ValueError("at least one data part must be non-None")
+        def leg(name: str):
+            return tracing.span(name, layer="encoder", phase=name,
+                                bucket="compute")
+
+        with tracing.span("boundary", layer="encoder", phase="boundary",
+                          bucket="compute", op=op, k=k, m=m) as sp:
+            with leg("dev_stage"):
+                bigm = matrix()
+                if len(live) < len(rows):
+                    bigm = bigm[:, np.concatenate(
+                        [np.arange(8 * j, 8 * j + 8) for j in live])]
+                stacked = np.stack([np.asarray(rows[j]) for j in live])
+                sp.attrs["rows"], sp.attrs["bytes"] = stacked.shape
+            with leg("dev_put"):
+                operands = self._put(bigm), self._put(stacked)
+            with leg("dev_run"):
+                out = self._ops.apply_gf(*operands)
+            with leg("dev_fetch"):
+                return np.asarray(out)
+
+    def encode(self, k, m, data_parts):
         if len(data_parts) != k:
             raise ValueError(f"expected {k} data parts, got {len(data_parts)}")
-        bigm = self._ops.encoding_bitmatrix(k, m)
-        if len(nonzero) < k:
-            cols = np.concatenate([np.arange(8 * i, 8 * i + 8) for i in nonzero])
-            bigm = bigm[:, cols]
-        stacked = np.stack([np.asarray(data_parts[i]) for i in nonzero])
-        out = self._ops.apply_gf(self._put(bigm), self._put(stacked))
-        return list(np.asarray(out))
+        return list(self._apply_gf(
+            "encode", k, m, lambda: self._ops.encoding_bitmatrix(k, m),
+            data_parts,
+        ))
 
     def recover(self, k, m, parts, wanted):
         from lizardfs_tpu.ops import gf256
 
         used, _ = gf256.recovery_selection(k, m, list(parts.keys()), wanted)
-        bigm = self._ops.recovery_bitmatrix(k, m, tuple(used), tuple(wanted))
-        nonzero_pos = [j for j, i in enumerate(used) if parts[i] is not None]
-        if not nonzero_pos:
-            raise ValueError("at least one available part must be non-None")
-        if len(nonzero_pos) < len(used):
-            cols = np.concatenate(
-                [np.arange(8 * j, 8 * j + 8) for j in nonzero_pos]
-            )
-            bigm = bigm[:, cols]
-        stacked = np.stack([np.asarray(parts[used[j]]) for j in nonzero_pos])
-        out = np.asarray(self._ops.apply_gf(self._put(bigm), self._put(stacked)))
+        out = self._apply_gf(
+            "recover", k, m,
+            lambda: self._ops.recovery_bitmatrix(
+                k, m, tuple(used), tuple(wanted)),
+            [parts[i] for i in used],
+        )
         return {w: out[i] for i, w in enumerate(wanted)}
 
     def checksum(self, blocks):

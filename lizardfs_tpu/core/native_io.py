@@ -518,28 +518,31 @@ def serve_slot_release() -> None:
 async def run(fn, *args):
     """Run a blocking native-IO function on the dedicated executor.
 
-    The caller's request trace id (runtime/tracing.py contextvar) is
-    captured HERE — run_in_executor does not carry context into the
-    worker thread — and installed as the C side's thread-local
-    (lz_trace_set) for the duration of the call, so the native request
-    builders tag their frames with the trace of the request they serve."""
+    The caller's open span and op sink (runtime/tracing.py
+    contextvars) are captured HERE — run_in_executor does not carry
+    context into the worker thread — and the trace id installed as the
+    C side's thread-local (lz_trace_set) for the duration of the call,
+    so the native request builders tag their frames with the trace of
+    the request they serve."""
     loop = asyncio.get_running_loop()
     return await loop.run_in_executor(EXECUTOR, partial_with_trace(fn, *args))
 
 
 def partial_with_trace(fn, *args):
-    """``functools.partial`` carrying the caller's trace id AND wire
-    session into the executor thread — for call sites that need raw
-    run_in_executor (shield/abort-cell patterns) instead of
-    :func:`run`. Both are captured HERE, in the calling task, because
+    """``functools.partial`` carrying the caller's open span, op sink
+    AND wire session into the executor thread — for call sites that
+    need raw run_in_executor (shield/abort-cell patterns) instead of
+    :func:`run`. All are captured HERE, in the calling task, because
     neither contextvars nor the task's session scope reach an executor
-    thread."""
+    thread; the spans the worker opens (and the ``hop`` span of its
+    wait for the thread) then hang under the caller's and charge the
+    caller's op, traced or not."""
     from lizardfs_tpu.runtime import tracing
 
-    trace_id = tracing.current_trace_id()
-    if trace_id:
+    carried = tracing.carry()
+    if carried is not None:
         return functools.partial(
-            _traced_call, trace_id, accounting.wire_session(), fn, *args
+            _traced_call, carried, accounting.wire_session(), fn, *args
         )
     return functools.partial(fn, *args)
 
@@ -553,7 +556,17 @@ def _thread_trace_id() -> int:
     return getattr(_TRACE_TL, "trace_id", 0)
 
 
-def _traced_call(trace_id, session_id, fn, *args):
+def _traced_call(carried, session_id, fn, *args):
+    from lizardfs_tpu.runtime import tracing
+
+    with tracing.carried(carried):
+        trace_id = tracing.current_trace_id()
+        if not trace_id:
+            return fn(*args)  # an untraced op: only its phases charge
+        return _call_under_trace(trace_id, session_id, fn, *args)
+
+
+def _call_under_trace(trace_id, session_id, fn, *args):
     _TRACE_TL.trace_id = trace_id
     has_c = _lib is not None and hasattr(_lib, "lz_trace_set")
     # the caller's session rides next to the trace (per-session op
@@ -739,7 +752,13 @@ def write_part_blocking(
     (the executor thread is otherwise unkillable while it streams from
     the caller's buffer); ``cell["finished"]`` is set when this thread
     has stopped touching ``payload``."""
-    sock = _blocking_socket(addr, 60.0)
+    from lizardfs_tpu.runtime import tracing
+
+    def leg(name: str, bucket: str = "net"):
+        return tracing.span(name, layer="wire", phase=name, bucket=bucket)
+
+    with leg("part_dial", "queue"):
+        sock = _blocking_socket(addr, 60.0)
     if cell is not None:
         cell["sock"] = sock
         if cell.get("aborted"):
@@ -747,17 +766,18 @@ def write_part_blocking(
             cell["finished"] = True
             raise NativeIOError(-1, "write (aborted)")
     try:
-        sock.sendall(
-            framing.encode(
-                m.CltocsWriteInit(
-                    req_id=1, chunk_id=chunk_id, version=version,
-                    part_id=part_id, chain=chain, create=False,
-                    trace_id=_thread_trace_id(),
-                    session_id=accounting.wire_session(),
+        with leg("part_init"):
+            sock.sendall(
+                framing.encode(
+                    m.CltocsWriteInit(
+                        req_id=1, chunk_id=chunk_id, version=version,
+                        part_id=part_id, chain=chain, create=False,
+                        trace_id=_thread_trace_id(),
+                        session_id=accounting.wire_session(),
+                    )
                 )
             )
-        )
-        init = _recv_message(sock)
+            init = _recv_message(sock)
         if not isinstance(init, m.CstoclWriteStatus) or init.status != st.OK:
             raise st.StatusError(getattr(init, "status", st.EIO), "write init")
         buf = (payload if isinstance(payload, np.ndarray)
@@ -768,15 +788,18 @@ def write_part_blocking(
 
         fn = (_lib.lz_write_part_bulk if part_offset % MFSBLOCKSIZE == 0
               else _lib.lz_write_part)
-        rc = fn(
-            sock.fileno(), chunk_id,
-            buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            len(buf), part_offset, 1,
-        )
+        with leg("part_data"):  # the C streamer: pieces and their acks
+            rc = fn(
+                sock.fileno(), chunk_id,
+                buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+                len(buf), part_offset, 1,
+            )
         if rc != 0:
             raise NativeIOError(rc, "write")
-        sock.sendall(framing.encode(m.CltocsWriteEnd(req_id=0, chunk_id=chunk_id)))
-        end = _recv_message(sock)
+        with leg("part_end"):
+            sock.sendall(framing.encode(
+                m.CltocsWriteEnd(req_id=0, chunk_id=chunk_id)))
+            end = _recv_message(sock)
         if not isinstance(end, m.CstoclWriteStatus) or end.status != st.OK:
             raise st.StatusError(getattr(end, "status", st.EIO), "write end")
     finally:

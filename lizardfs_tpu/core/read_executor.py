@@ -94,24 +94,29 @@ async def read_part_range(
         # retry refills the same region. The cell lets us shut the
         # socket down (killing the thread's recv) and join it.
         cell: dict = {}
+        # the native exchange is charged as the op's net phase at the
+        # await (parallel part reads overlap, so net busy-time may
+        # exceed wall — the PhaseBreakdown pipelining contract); the
+        # wait for its worker thread is a hop span under it
+        net = tracing.span(
+            "net", layer="wire", phase="net", bucket="net", part=part_id,
+            bytes=size, plane="native",
+        ).begin()
         fut = asyncio.get_running_loop().run_in_executor(
             native_io.EXECUTOR,
-            # partial_with_trace: carries the request trace id into the
-            # worker thread (plain run_in_executor drops context)
+            # partial_with_trace: carries the open span and the sink
+            # into the worker thread (run_in_executor drops context)
             native_io.partial_with_trace(
                 native_io.read_part_blocking,
                 addr, chunk_id, version, part_id, offset, size, tmp,
                 cell if scatter_direct else None,
             ),
         )
-        # run_in_executor drops the phase-sink context too: the native
-        # exchange is timed here and charged as read-phase net (parallel
-        # part reads overlap, so net busy-time may exceed wall — the
-        # PhaseBreakdown pipelining contract)
-        t0 = tracing.phase_t0()
         try:
-            await asyncio.shield(fut)
-            tracing.charge_phase("net", t0)
+            try:
+                await asyncio.shield(fut)
+            finally:
+                net.end()
             GLOBAL_STATS.record_success(addr)
             if not scatter_direct:
                 out[into_offset : into_offset + size] = tmp
@@ -135,8 +140,11 @@ async def read_part_range(
     clean = False
     cancelled = False
     # the whole framed exchange (request send + piece recv/CRC loop) is
-    # read-phase net busy-time on the ambient logical read
-    t0 = tracing.phase_t0()
+    # net busy-time of the ambient logical op
+    net = tracing.span(
+        "net", layer="wire", phase="net", bucket="net", part=part_id,
+        bytes=size, plane="asyncio",
+    ).begin()
     try:
         await framing.send_message(
             conn.writer,
@@ -182,7 +190,6 @@ async def read_part_range(
                         f"short read: {received} of {size} bytes"
                     )
                 GLOBAL_STATS.record_success(addr)
-                tracing.charge_phase("net", t0)
                 return out
             else:
                 raise ReadError(f"unexpected message {type(msg).__name__}")
@@ -190,6 +197,7 @@ async def read_part_range(
         cancelled = True
         raise
     finally:
+        net.end()
         if clean:
             GLOBAL_POOL.release(addr, conn)
         else:
@@ -256,6 +264,11 @@ async def execute_plan(
             )
             pending[task] = op.part
 
+    # the waves' part reads run in parallel: one span holds them, so
+    # that the op's top level stays serial (net and dial nest in it)
+    waves = tracing.span(
+        "waves", layer="wire", phase="waves", bucket="net"
+    ).begin()
     current_wave = 0
     start_wave(0)
     wave_start = loop.time()
@@ -315,10 +328,12 @@ async def execute_plan(
             task.cancel()
         if pending:
             await asyncio.gather(*pending.keys(), return_exceptions=True)
+        waves.end()
 
     # postprocess is the decode leg: parity recovery / block CRC checks
-    # for striped plans (a plain pass-through for healthy std reads)
-    t0 = tracing.phase_t0()
-    result = plan.postprocess(buffer, available)
-    tracing.charge_phase("decode", t0)
+    # for striped plans (a plain pass-through for healthy std reads).
+    # It runs on the event loop, so while it holds the boundary every
+    # other session of this loop stands still: the span shows it
+    with tracing.span("decode", phase="decode", bucket="compute"):
+        result = plan.postprocess(buffer, available)
     return result

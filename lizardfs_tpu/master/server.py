@@ -721,6 +721,14 @@ class MasterServer(Daemon):
                 and not target.meta_version:
             target.meta_version = self.changelog.version
 
+    @staticmethod
+    def _stamp_srv(reply, dt: float) -> None:
+        """Stamp the handler's own time on a grant / locate reply
+        (trailing, skew-tolerant ``srv_us``): the client's span round
+        the RPC less this is the wire and the two event loops."""
+        if hasattr(reply, "srv_us"):
+            reply.srv_us = min(max(int(dt * 1e6), 1), 0xFFFFFFFF)
+
     async def _client_loop(self, reader, writer, first: m.CltomaRegister) -> None:
         if not self.is_active:
             if (
@@ -854,7 +862,9 @@ class MasterServer(Daemon):
                 tid = getattr(msg, "trace_id", 0)
                 self.trace_ring.record(
                     tid, type(msg).__name__, tw0, time.time(), role="master",
+                    bucket="compute",
                 )
+                self._stamp_srv(reply, dt)
                 # per-session accounting: the same op charged to its
                 # originating session (the `top` rollup's master leg)
                 self.session_ops.record(
@@ -1307,6 +1317,7 @@ class MasterServer(Daemon):
                         reply = self._error_reply(msg, st.EIO)
                     dt = time.perf_counter() - t0
                     self.metrics.timing(type(msg).__name__).record(dt)
+                    self._stamp_srv(reply, dt)
                     # replica-served reads charge the same session the
                     # primary would (the shadow's own registry; the
                     # client never double-counts — fallbacks re-enter

@@ -315,6 +315,10 @@ class MatoclReadChunk(Message):
     # On locate replies the token pairs with the client's local
     # locate-epoch machinery: the epoch guards against invalidations
     # racing the RPC, the token guards against a lagging replica.
+    # Trailing ``srv_us``: the master's handler time for this RPC in
+    # microseconds (0 from a master that predates it); the client lays
+    # it inside its locate span, so what is left of the span is the
+    # wire and the two event loops.
     MSG_TYPE = 1021
     SKEW_TOLERANT_FROM = 6
     FIELDS = (
@@ -325,6 +329,7 @@ class MatoclReadChunk(Message):
         ("file_length", "u64"),
         ("locations", "list:msg:PartLocation"),
         ("meta_version", "u64"),
+        ("srv_us", "u32"),
     )
 
 
@@ -343,7 +348,10 @@ class CltomaWriteChunk(Message):
 
 
 class MatoclWriteChunk(Message):
+    # trailing ``srv_us``: the master's handler time for the grant,
+    # see MatoclReadChunk
     MSG_TYPE = 1023
+    SKEW_TOLERANT_FROM = 6
     FIELDS = (
         ("req_id", "u32"),
         ("status", "u8"),
@@ -351,6 +359,7 @@ class MatoclWriteChunk(Message):
         ("version", "u32"),
         ("file_length", "u64"),
         ("locations", "list:msg:PartLocation"),
+        ("srv_us", "u32"),
     )
 
 

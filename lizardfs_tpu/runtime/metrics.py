@@ -17,6 +17,7 @@ evaluated elementwise at any resolution, either ad hoc
 
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 
@@ -170,39 +171,104 @@ class Timing:
         return out
 
 
+# The span tree of a client op, as phase name -> the phase it nests
+# in (None: a direct child of the op's root). The top level is what
+# has to sum to wall, with the root's self time, in a serial
+# execution; nested phases split their parent and are never summed
+# with it. ``runtime/tracing.span`` charges them; doc/operations.md
+# ("Write phase accounting", "Read-path microscope") says what each
+# covers.
+_BOUNDARY_PHASES = {
+    "boundary": None,           # one call across the ChunkEncoder boundary
+    "dev_stage": "boundary",    # bit matrix, np.stack
+    "dev_put": "boundary",      # the two device_put calls, until they return
+    "dev_run": "boundary",      # apply_gf, until it returns
+    "dev_fetch": "boundary",    # np.asarray: upload, kernel, download, wake-up
+}
+WRITE_PHASES = {
+    "getattr": None, "lock": None, "grant": None, "grant_srv": "grant",
+    "wait": "grant",            # a BUSY shed's backoff, inside its RPC
+    "rmw_read": None, "stage": None, "throttle": None,
+    "encode": None, "split": "encode",
+    **{k: v or "encode" for k, v in _BOUNDARY_PHASES.items()},
+    "send": None, "part": "send", "hop": "part", "part_dial": "part",
+    "part_init": "part", "part_data": "part", "part_ack": "part",
+    "part_end": "part",
+    "ack": None, "commit": None,
+    # the read-back of a partial-stripe write, under rmw_read
+    "waves": "rmw_read", "dial": "waves", "net": "waves",
+    "decode": "rmw_read",
+}
+READ_PHASES = {
+    "locate": None, "locate_srv": "locate", "wait": None, "plan": None,
+    # the plan's part reads, in parallel: net and dial sum over them
+    "waves": None, "dial": "waves", "net": "waves", "hop": "net",
+    "decode": None,
+    **{k: v or "decode" for k, v in _BOUNDARY_PHASES.items()},
+    "gather": None, "copy": None,
+}
+
+
 class PhaseBreakdown:
     """Per-phase busy-time accounting for a multi-phase operation (the
-    client write pipeline's encode/stage/send/commit split).
+    client write and read paths).
 
-    Each ``add`` charges wall-clock seconds spent *inside* one phase;
-    ``add_wall`` closes one rep (one whole operation) with its end-to-end
-    time. In a serial execution the phase totals sum to ~the wall total;
-    in a pipelined execution phases overlap, so the sum legitimately
-    exceeds wall time — the gap IS the overlap win. ``snapshot`` returns
-    cumulative totals; subtract two snapshots (:func:`phase_delta`) to
-    scope the breakdown to a measured interval (bench reps)."""
+    Each ``add`` charges seconds spent *inside* one phase; ``add_wall``
+    closes one rep (one whole operation) with its end-to-end time and
+    the root's self time, the part of it no child span covered. Phases
+    form a tree (``phases``: name -> parent, None at the top level):
+    in a serial execution the top-level totals plus ``self`` sum to
+    the wall total; in a pipelined execution phases overlap, so the sum
+    legitimately exceeds wall time — the gap IS the overlap win. A
+    phase the tree does not name is kept under its name and counted
+    nowhere else. ``add`` is called from worker threads too and loses
+    no update. ``snapshot`` returns cumulative totals; subtract two
+    snapshots (:func:`phase_delta`) to scope the breakdown to a
+    measured interval (bench reps)."""
 
-    __slots__ = ("name", "phase_names", "totals_s", "wall_s", "reps")
+    __slots__ = ("name", "phases", "totals_s", "wall_s", "self_s", "reps",
+                 "_lock")
 
-    def __init__(self, name: str, phase_names: tuple[str, ...]):
+    def __init__(self, name: str, phases):
         self.name = name
-        self.phase_names = tuple(phase_names)
-        self.totals_s = {p: 0.0 for p in self.phase_names}
+        self.phases = (dict(phases) if isinstance(phases, dict)
+                       else {p: None for p in phases})
+        self.totals_s = {p: 0.0 for p in self.phases}
         self.wall_s = 0.0
+        self.self_s = 0.0
         self.reps = 0
+        self._lock = threading.Lock()
+
+    @property
+    def top_level(self) -> tuple[str, ...]:
+        return tuple(p for p, parent in self.phases.items() if parent is None)
 
     def add(self, phase: str, seconds: float) -> None:
-        self.totals_s[phase] += seconds
+        with self._lock:
+            self.totals_s[phase] = self.totals_s.get(phase, 0.0) + seconds
 
-    def add_wall(self, seconds: float) -> None:
-        self.wall_s += seconds
-        self.reps += 1
+    def add_wall(self, seconds: float, self_seconds: float | None = None) -> None:
+        with self._lock:
+            self.wall_s += seconds
+            self.self_s += self_seconds or 0.0
+            self.reps += 1
 
     def snapshot(self) -> dict:
-        out = {f"{p}_ms": round(v * 1e3, 2) for p, v in self.totals_s.items()}
-        out["wall_ms"] = round(self.wall_s * 1e3, 2)
-        out["reps"] = self.reps
+        with self._lock:
+            out = {f"{p}_ms": round(v * 1e3, 2)
+                   for p, v in self.totals_s.items()}
+            out["self_ms"] = round(self.self_s * 1e3, 2)
+            out["wall_ms"] = round(self.wall_s * 1e3, 2)
+            out["reps"] = self.reps
         return out
+
+
+def top_level_ms(snapshot: dict, phases: dict) -> dict:
+    """The top-level phases of a snapshot (or a delta), name -> ms:
+    what a "dominant phase" is chosen from, so a nested phase is never
+    ranked against its own parent."""
+    return {p: snapshot.get(f"{p}_ms", 0.0)
+            for p, parent in phases.items() if parent is None}
 
 
 def phase_delta(after: dict, before: dict) -> dict:

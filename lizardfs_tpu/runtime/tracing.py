@@ -23,21 +23,32 @@ Each daemon's ring is dumped over the admin link
 write rep decomposes into client encode/stage/send, chunkserver
 recv/disk-commit, and ack segments across processes.
 
-Cost contract: with ``LZ_TRACE=0`` no ids are issued,
-``current_trace_id()`` is 0 everywhere, and every record path is a
-single falsy check — the acceptance bound is <1% on the ec(8,4) write
-row.
+One primitive, :func:`span`, is used at every layer boundary of a
+client op. It charges the ambient op's phase rows
+(``runtime.metrics.PhaseBreakdown``), records the ring span with its
+parent and its self time, and — once the process that owns the chip
+has registered ``jax.profiler.TraceAnnotation`` — opens a profiler
+annotation ``lz.<layer>.<name>``, so a traced run has the program's
+spans in the same ``.xplane.pb`` as the device's operations.
 
-Clocks: spans carry CLOCK_REALTIME epoch seconds (C side: microseconds
-via clock_gettime) so same-host cross-process merges line up; durations
-inside one process stay monotonic-accurate at the span granularity
-(tens of microseconds and up) this subsystem targets.
+Cost contract: with ``LZ_TRACE=0`` no ids are issued,
+``current_trace_id()`` is 0 everywhere, and :func:`span` charges its
+phase row and does nothing else (the phase rows are the always-on
+counters). The measured cost of the default (on) is in
+``doc/operations.md`` ("Request tracing").
+
+Clocks: ring spans carry CLOCK_REALTIME epoch seconds (C side:
+microseconds via clock_gettime) so same-host cross-process merges line
+up; durations are monotonic (``perf_counter``). An op's root
+annotation carries ``t_ns``, its opening on ``time.time_ns()``'s
+clock, so the profile's clock can be laid on the rings' axis.
 """
 
 from __future__ import annotations
 
 import contextvars
-import secrets
+import itertools
+import os
 import time
 from collections import deque
 
@@ -48,10 +59,25 @@ from lizardfs_tpu.constants import env_flag
 
 _ENABLED = env_flag("LZ_TRACE")
 
-# (trace_id, parent_span_id) of the request this task is serving
-CURRENT: contextvars.ContextVar[tuple[int, int] | None] = (
-    contextvars.ContextVar("lz_trace", default=None)
+# what this task is serving: the innermost open Span, or the _Anchor
+# of a trace no span of which is open; either gives (trace_id, span_id)
+CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "lz_trace", default=None
 )
+
+# the logical op in flight: where its spans charge phases and record
+# (an OpSink). A contextvar (not a global) keeps concurrent clients in
+# one process (in-process test clusters, gateways) from cross-charging;
+# tasks and to_thread copy it, run_in_executor does not (carry()).
+PHASE_SINK: contextvars.ContextVar = contextvars.ContextVar(
+    "lz_phase_sink", default=None
+)
+
+# jax.profiler.TraceAnnotation, registered by the process that owns the
+# chip (core/encoder.py) with the check that a profiler session is
+# live; a daemon never imports jax and leaves it None
+_ANNOTATE = None
+_ANNOTATING = None
 
 
 def enabled() -> bool:
@@ -64,14 +90,50 @@ def set_enabled(on: bool) -> None:
     _ENABLED = bool(on)
 
 
+_ID_MASK = (1 << 63) - 1
+
+
+def _seed_ids() -> None:
+    """Trace and span ids count up from one random base per process
+    (a getrandom call per id was a system call on every span)."""
+    global _ids
+    _ids = itertools.count(int.from_bytes(os.urandom(8), "big") & _ID_MASK)
+
+
+_seed_ids()
+os.register_at_fork(after_in_child=_seed_ids)
+
+
 def new_id() -> int:
     # 63-bit nonzero: fits i64/u64 everywhere, 0 stays "untraced"
-    return secrets.randbits(63) | 1
+    return (next(_ids) & _ID_MASK) or 1
+
+
+def register_annotator(factory, active=lambda: True) -> None:
+    """``factory(name, **metadata)`` -> context manager; from now on
+    every span opened while ``active()`` (a profiler session is live:
+    one atomic load) is also an annotation ``lz.<layer>.<name>``."""
+    global _ANNOTATE, _ANNOTATING
+    _ANNOTATE, _ANNOTATING = factory, active
+
+
+class _Anchor:
+    """A trace with no span open (start_trace / adopt_trace): the
+    parent of whatever opens under it."""
+
+    __slots__ = ("trace_id",)
+    span_id = 0
+
+    def __init__(self, trace_id: int):
+        self.trace_id = trace_id
+
+    def cover(self, a: float, b: float) -> None:
+        pass
 
 
 def current_trace_id() -> int:
     cur = CURRENT.get()
-    return cur[0] if cur is not None else 0
+    return cur.trace_id if cur is not None else 0
 
 
 def start_trace() -> int:
@@ -80,7 +142,7 @@ def start_trace() -> int:
     if not _ENABLED:
         return 0
     tid = new_id()
-    CURRENT.set((tid, 0))
+    CURRENT.set(_Anchor(tid))
     return tid
 
 
@@ -114,7 +176,7 @@ def adopt_trace(tid: int) -> None:
     RebuildEngine's per-rebuild id riding MatocsReplicate) so every
     downstream op in this task propagates it."""
     if _ENABLED and tid:
-        CURRENT.set((tid, 0))
+        CURRENT.set(_Anchor(tid))
 
 
 def clear_trace() -> None:
@@ -152,16 +214,16 @@ class SpanRing:
         t1: float,
         role: str = "",
         parent_id: int = 0,
+        *,
+        bucket: str | None = None,
         **attrs,
     ) -> int:
         """Record one finished span; no-op (returns 0) for trace id 0,
-        which is what every call site passes when tracing is off."""
+        which is what every call site passes when tracing is off.
+        ``bucket`` is the attribution bucket the site names for itself
+        (queue, disk, net, compute)."""
         if not trace_id:
             return 0
-        if len(self._ring) == self._ring.maxlen:
-            self.dropped += 1
-            if self._drop_counter is not None:
-                self._drop_counter.inc()
         span_id = new_id()
         rec = {
             "trace_id": trace_id,
@@ -172,19 +234,27 @@ class SpanRing:
             "t0": t0,
             "t1": t1,
         }
+        if bucket is not None:
+            rec["bucket"] = bucket
         if attrs:
             rec["attrs"] = attrs
-        self._ring.append(rec)
+        self.push(rec)
         return span_id
 
-    def span(self, name: str, role: str = "", trace_id: int | None = None):
-        """Context manager timing a block into the ring (sync code)."""
-        return _SpanCtx(self, name, role, trace_id)
+    def push(self, rec) -> None:
+        """Append one finished record, or a closed :class:`Span` (kept
+        as it is: its record is made when the ring is dumped)."""
+        if len(self._ring) == self._ring.maxlen:
+            self.dropped += 1
+            if self._drop_counter is not None:
+                self._drop_counter.inc()
+        self._ring.append(rec)
 
     def dump(self, trace_id: int | None = None) -> list[dict]:
-        if trace_id:
-            return [s for s in self._ring if s["trace_id"] == trace_id]
-        return list(self._ring)
+        return [s if isinstance(s, dict) else s.record()
+                for s in list(self._ring)
+                if not trace_id or trace_id == (
+                    s["trace_id"] if isinstance(s, dict) else s.trace_id)]
 
     def clear(self) -> None:
         self._ring.clear()
@@ -193,25 +263,180 @@ class SpanRing:
         return len(self._ring)
 
 
-class _SpanCtx:
-    __slots__ = ("ring", "name", "role", "trace_id", "t0")
+class OpSink:
+    """Where the spans of one component's logical ops land: its phase
+    rows, its span ring under its role, and (for the ``dial`` gate) its
+    metrics registry. An op's root span installs it as ambient."""
 
-    def __init__(self, ring, name, role, trace_id):
+    __slots__ = ("phases", "ring", "role", "metrics")
+
+    def __init__(self, phases, ring=None, role: str = "", metrics=None):
+        self.phases = phases
         self.ring = ring
-        self.name = name
         self.role = role
-        self.trace_id = (
-            trace_id if trace_id is not None else current_trace_id()
-        )
+        self.metrics = metrics
 
-    def __enter__(self):
-        self.t0 = time.time()
+
+class Span:
+    """The one span primitive: a context manager (or a ``begin()`` /
+    ``end()`` pair) round one layer's share of an op; ``span`` is its
+    name at the call sites.
+
+    On exit it (a) charges ``phase`` on the ambient op's phase rows,
+    (b) records the ring span with its parent, its ``bucket`` and its
+    self time (duration less the union of what its children cover, so
+    parallel children count once), (c) adds its interval to its
+    parent's covered union; while open it is the parent of what runs
+    inside it (tasks and ``to_thread`` copy the context; :func:`carry`
+    takes it into executor threads) and, while a profiler session is
+    live in a process that registered an annotator, a profiler
+    annotation ``lz.<layer>.<name>``.
+
+    ``sink`` makes it an op's root: it installs the sink as ambient,
+    starts a trace unless one is active, and closes one rep of the
+    sink's phase rows with its wall and self time. With ``LZ_TRACE=0``
+    a span charges its phase row (and a root its wall) and does
+    nothing else.
+
+    It runs some fifty times in a small write on a loop that twelve
+    sessions share, so it allocates little: the ring keeps the closed
+    span itself and makes the record when somebody dumps it."""
+
+    __slots__ = ("name", "layer", "phase", "bucket", "sink", "attrs",
+                 "p0", "w0", "dur", "self_s", "role", "trace_id", "span_id",
+                 "parent", "covered", "_prev", "_prev_sink", "_ann")
+
+    def __init__(self, name: str, *, layer: str = "client",
+                 phase: str | None = None, bucket: str | None = None,
+                 sink: OpSink | None = None, **attrs):
+        self.name = name
+        self.layer = layer
+        self.phase = phase
+        self.bucket = bucket
+        self.sink = sink
+        self.attrs = attrs
+        self.trace_id = self.span_id = 0
+        self._ann = None
+
+    def cover(self, a: float, b: float) -> None:
+        # list.append is atomic: children close on other threads too
+        self.covered.append((a, b))
+
+    def begin(self, at: float | None = None) -> "Span":
+        """Open the span; ``at`` (a ``perf_counter`` reading) opens it
+        in the past, for a wait that is known only once it is over."""
+        now = time.perf_counter()
+        self.p0 = now if at is None else at
+        if self.sink is not None:
+            self._prev_sink = PHASE_SINK.get()
+            PHASE_SINK.set(self.sink)
+        if not _ENABLED:
+            return self
+        parent = self._prev = CURRENT.get()
+        if parent is None:
+            if self.sink is None:
+                return self  # no op in flight: the phase row only
+            parent = _Anchor(new_id())  # an op root starts its trace
+        self.parent = parent
+        self.trace_id = parent.trace_id
+        self.span_id = new_id()
+        self.covered = []
+        self.w0 = time.time() - (now - self.p0)
+        CURRENT.set(self)
+        if _ANNOTATE is not None and at is None and _ANNOTATING():
+            meta = self.attrs
+            if self.sink is not None:
+                # an op root says when it opened on time.time_ns()'s
+                # clock: the profile's offset from the rings' axis
+                meta = dict(meta, t_ns=int(self.w0 * 1e9))
+            self._ann = _ANNOTATE(f"lz.{self.layer}.{self.name}", **meta)
+            self._ann.__enter__()
         return self
 
-    def __exit__(self, *exc):
-        self.ring.record(
-            self.trace_id, self.name, self.t0, time.time(), role=self.role
-        )
+    def end(self, at: float | None = None) -> None:
+        dur = (time.perf_counter() if at is None else at) - self.p0
+        if dur < 0.0:
+            dur = 0.0
+        sink = PHASE_SINK.get()
+        if self.phase is not None and sink is not None:
+            sink.phases.add(self.phase, dur)
+        self_s = None
+        if self.span_id:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            CURRENT.set(self._prev)
+            w0 = self.w0
+            self.parent.cover(w0, w0 + dur)
+            self_s = dur
+            if self.covered:  # most spans are leaves
+                w1 = w0 + dur
+                self_s = max(dur - _union_seconds(
+                    [(max(a, w0), min(b, w1)) for a, b in self.covered
+                     if b > w0 and a < w1]), 0.0)
+            if sink is not None and sink.ring is not None:
+                self.dur, self.self_s, self.role = dur, self_s, sink.role
+                sink.ring.push(self)
+        if self.sink is not None:
+            # an op root closes one rep: wall, and the self time no
+            # child span covers ("phases sum to wall" at the top level)
+            self.sink.phases.add_wall(dur, self_s)
+            PHASE_SINK.set(self._prev_sink)
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+    def record(self) -> dict:
+        """The closed span as the ring's plain, JSON-ready record."""
+        rec = {
+            "trace_id": self.trace_id, "span_id": self.span_id,
+            "parent_id": self.parent.span_id, "role": self.role,
+            "name": self.name, "t0": self.w0, "t1": self.w0 + self.dur,
+            "self_ms": round(self.self_s * 1e3, 3),
+        }
+        if self.bucket is not None:
+            rec["bucket"] = self.bucket
+        if self.attrs:
+            rec["attrs"] = self.attrs
+        return rec
+
+
+span = Span
+
+
+def carry():
+    """What an executor hop has to carry by hand (``run_in_executor``
+    copies no context): the open span, the ambient sink and when the
+    hop was submitted. None where there is nothing to carry."""
+    cur, sink = CURRENT.get(), PHASE_SINK.get()
+    if cur is None and sink is None:
+        return None
+    return (cur, sink, time.perf_counter())
+
+
+class carried:
+    """In the worker thread: what :func:`carry` took becomes ambient,
+    and the wait for the thread is a ``hop`` span of its own."""
+
+    __slots__ = ("token",)
+
+    def __init__(self, token):
+        self.token = token
+
+    def __enter__(self):
+        cur, sink, submitted = self.token
+        CURRENT.set(cur)
+        PHASE_SINK.set(sink)
+        span("hop", layer="wire", phase="hop", bucket="queue").begin(
+            at=submitted).end()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        # pooled threads serve many requests: leak nothing into the next
+        CURRENT.set(None)
+        PHASE_SINK.set(None)
         return False
 
 
@@ -269,7 +494,9 @@ def merge_timeline(
             "role": s["role"], "name": s["name"],
             "start_ms": round((s["t0"] - t_lo) * 1e3, 3),
             "dur_ms": round(dur * 1e3, 3),
-            **({"attrs": s["attrs"]} if "attrs" in s else {}),
+            "span_id": s.get("span_id", 0),
+            "parent_id": s.get("parent_id", 0),
+            **({k: s[k] for k in ("bucket", "self_ms", "attrs") if k in s}),
         })
     return {
         "trace_id": spans[0]["trace_id"],
@@ -298,34 +525,14 @@ def format_timeline(timeline: dict) -> str:
     return "\n".join(lines)
 
 
-# --- read-phase sink ---------------------------------------------------------
-#
-# The client activates a sink around each LOGICAL read (read_file /
-# read_file_into); deep layers that have no client reference — the
-# connection pool's dial, the read executor's socket waits and plan
-# postprocess — charge busy-time into whatever sink is ambient. A
-# contextvar (not a global) keeps concurrent clients in one process
-# (in-process test clusters, gateways) from cross-charging; asyncio
-# tasks and to_thread propagate it, run_in_executor does not (native
-# executor hops are therefore timed at the await site instead).
-
-PHASE_SINK: contextvars.ContextVar = contextvars.ContextVar(
-    "lz_read_phase_sink", default=None
-)
+# --- queue-wait gates ---------------------------------------------------------
 
 
 def phase_t0() -> tuple[float, float]:
-    """(perf_counter, wall) anchor for :func:`charge_phase` — durations
-    stay monotonic-accurate while span endpoints stay epoch-aligned."""
+    """(perf_counter, wall) anchor for :func:`charge_queue_wait` —
+    durations stay monotonic-accurate while span endpoints stay
+    epoch-aligned."""
     return (time.perf_counter(), time.time())
-
-
-def charge_phase(phase: str, t0: tuple[float, float]) -> None:
-    """Charge [t0, now] to ``phase`` on the ambient read-phase sink;
-    free (one contextvar get) when no logical read is in flight."""
-    sink = PHASE_SINK.get()
-    if sink is not None:
-        sink(phase, t0, (time.perf_counter(), time.time()))
 
 
 def charge_queue_wait(
@@ -349,9 +556,11 @@ def charge_queue_wait(
                  "connection dials) before doing any work",
         ).record(seconds, trace_id=tid)
     if ring is not None and tid:
+        cur = CURRENT.get()
         ring.record(
             tid, f"queue_wait:{gate}", t0[1], t0[1] + seconds,
-            role=role, gate=gate,
+            role=role, parent_id=cur.span_id if cur is not None else 0,
+            bucket="queue", gate=gate,
         )
     return seconds
 
@@ -359,42 +568,6 @@ def charge_queue_wait(
 # --- latency attribution -----------------------------------------------------
 
 ATTRIBUTION_BUCKETS = ("queue", "disk", "net", "compute", "unattributed")
-
-# substring -> bucket, FIRST match wins (specific names before generic
-# ones: "read:wait" must hit queue before "read" hits net). Unknown
-# names classify to None and their time surfaces as unattributed-gap —
-# honest, and exactly what flags a span this table should learn.
-_BUCKET_RULES = (
-    ("queue_wait", "queue"),
-    ("dial", "queue"),
-    ("throttle", "queue"),
-    ("backoff", "queue"),
-    ("read:wait", "queue"),
-    ("qos", "queue"),
-    ("locate", "net"),
-    ("decode", "compute"),
-    ("gather", "compute"),
-    ("assemble", "compute"),
-    ("encode", "compute"),
-    ("stage", "compute"),
-    ("crc", "compute"),
-    ("disk", "disk"),
-    ("net", "net"),
-    ("send", "net"),
-    ("recv", "net"),
-    ("ack", "net"),
-    ("commit", "net"),
-    ("read", "net"),
-    ("write", "net"),
-)
-
-
-def classify_segment(name: str) -> "str | None":
-    label = str(name).lower()
-    for pat, bucket in _BUCKET_RULES:
-        if pat in label:
-            return bucket
-    return None
 
 
 def _merge_intervals(ivs: list) -> list:
@@ -436,7 +609,10 @@ def attribute_timeline(timeline: dict) -> dict:
     unions are resolved in priority order (queue > disk > net >
     compute), each later bucket only claiming instants no
     higher-priority bucket covered — overlapping spans can never push
-    the sum past 100%. Segments are clamped to the wall window, so a
+    the sum past 100%. A segment's bucket is the one its site named
+    when it recorded the span (``bucket``); one that names none — a
+    span this table has not been told about — surfaces as
+    unattributed, which is honest. Segments are clamped to the wall window, so a
     clock-skewed ring (a chunkserver span leaking past the client
     wall) cannot produce negative gaps; zero/negative-duration
     segments are skipped. Chunkserver spans carrying the native
@@ -482,8 +658,8 @@ def attribute_timeline(timeline: dict) -> dict:
                     )
                     cursor += dur
             continue
-        bucket = classify_segment(seg.get("name", ""))
-        if bucket is not None:
+        bucket = seg.get("bucket")
+        if bucket in ATTRIBUTION_BUCKETS[:-1]:
             per_bucket.setdefault(bucket, []).append((s, e))
     claimed: list = []
     covered = 0.0

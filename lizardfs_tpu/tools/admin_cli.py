@@ -31,6 +31,7 @@ import sys
 from lizardfs_tpu.proto import framing
 from lizardfs_tpu.proto import messages as m
 from lizardfs_tpu.proto import status as st
+from lizardfs_tpu.runtime import metrics as metrics_mod
 
 
 async def _admin(addr: tuple[str, int], command: str, payload: str = "{}",
@@ -374,10 +375,9 @@ def _print_top(doc: dict) -> None:
         )
         phases = entry.get("read_phases")
         if phases and phases.get("reps"):
-            busy = {
-                k[:-3]: v for k, v in phases.items()
-                if k.endswith("_ms") and k != "wall_ms"
-            }
+            # the top level of the read's span tree: a nested phase is
+            # never ranked against its own parent
+            busy = metrics_mod.top_level_ms(phases, metrics_mod.READ_PHASES)
             dom = max(busy, key=lambda k: busy[k]) if busy else "?"
             busy_s = " ".join(
                 f"{k}={v:.0f}ms" for k, v in sorted(

@@ -231,10 +231,11 @@ ANCHORS = (
     # queue-wait gates, and the attribution engine are standing
     # surfaces — losing any leg silently blanks a `top` column, a
     # queue_wait family, or the slowops/incident attribution embed
-    (CLIENT, r"PHASE_SINK\.set\(",
-     "client read-phase sink activation at the read_file boundary"),
-    (CLIENT, r"read_phases\.add_wall\(",
-     "client exactly-once read wall/rep accounting (PhaseBreakdown)"),
+    (CLIENT, r"span\(\"read_file\", sink=self\._read_op\)",
+     "client read root span: sink activation at the read_file boundary "
+     "and exactly-once wall/rep accounting (PhaseBreakdown)"),
+    (TRACING, r"sink\.phases\.add_wall\(",
+     "the span primitive's root close (wall + self time, once an op)"),
     (CLIENT, r"charge_queue_wait\(",
      "client queue-wait gates (dial / busy_retry / write_credit)"),
     (CS, r"charge_queue_wait\(",

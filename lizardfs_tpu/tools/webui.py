@@ -25,6 +25,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from lizardfs_tpu.proto import framing
 from lizardfs_tpu.proto import messages as m
+from lizardfs_tpu.runtime import metrics as metrics_mod
 
 PAGE = """<!doctype html>
 <html><head><title>lizardfs-tpu status</title>
@@ -257,10 +258,8 @@ class Dashboard:
             phases = entry.get("read_phases") or {}
             roofline = ""
             if phases.get("reps"):
-                busy = {
-                    k[:-3]: v for k, v in phases.items()
-                    if k.endswith("_ms") and k != "wall_ms"
-                }
+                busy = metrics_mod.top_level_ms(
+                    phases, metrics_mod.READ_PHASES)
                 if busy:
                     dom = max(busy, key=lambda k: busy[k])
                     roofline = f"{dom} {busy[dom]:.0f}ms"

@@ -22,6 +22,7 @@ import numpy as np
 from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.core import geometry
 from lizardfs_tpu.core.encoder import ChunkEncoder, get_encoder
+from lizardfs_tpu.runtime import tracing
 
 
 def padded_data_parts(
@@ -63,6 +64,15 @@ def split_chunk(
     Returned arrays are zero-padded to whole blocks; callers truncate to
     geometry.chunk_length_to_part_length for the on-wire/on-disk length.
     """
+    # its own span under the caller's encode: the padding, the copies
+    # and the parts dict are this span's self time, the call across
+    # the encoder boundary its child
+    with tracing.span("split", layer="striping", phase="split",
+                      bucket="compute", bytes=int(np.size(data))):
+        return _split_chunk(data, slice_type, encoder)
+
+
+def _split_chunk(data, slice_type, encoder) -> dict[int, np.ndarray]:
     data = np.asarray(data, dtype=np.uint8)
     enc = encoder or get_encoder("cpu")
     if slice_type.is_standard or slice_type.is_tape:

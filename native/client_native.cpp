@@ -38,11 +38,12 @@
 //                          gids:list:u32 trace_id:u64
 //   MatoclReadChunk(1021): req_id:u32 status:u8 chunk_id:u64 version:u32
 //                          file_length:u64 locations:list:msg:PartLocation
-//                          meta_version:u64
+//                          meta_version:u64 srv_us:u32
 //   CltomaWriteChunk(1022): req_id:u32 inode:u32 chunk_index:u32 uid:u32
 //                           gids:list:u32 trace_id:u64
 //   MatoclWriteChunk(1023): req_id:u32 status:u8 chunk_id:u64 version:u32
 //                           file_length:u64 locations:list:msg:PartLocation
+//                           srv_us:u32
 //   CltomaWriteChunkEnd(1024): req_id:u32 chunk_id:u64 inode:u32
 //                              chunk_index:u32 file_length:u64 status:u8
 //                              trace_id:u64

@@ -69,13 +69,13 @@ def test_attribution_overlapping_spans_cannot_exceed_wall():
     priority order (queue > disk > net > compute)."""
     attr = attribute_timeline({
         "trace_id": 0x11, "wall_ms": 100.0, "segments": [
-            {"role": "client", "name": "read:net",
+            {"role": "client", "name": "read:net", "bucket": "net",
              "start_ms": 0.0, "dur_ms": 80.0},
-            {"role": "client", "name": "read:net",
+            {"role": "client", "name": "read:net", "bucket": "net",
              "start_ms": 10.0, "dur_ms": 80.0},   # overlaps the first
-            {"role": "client", "name": "queue_wait:dial",
+            {"role": "client", "name": "queue_wait:dial", "bucket": "queue",
              "start_ms": 0.0, "dur_ms": 50.0},    # overlaps both
-            {"role": "client", "name": "read:decode",
+            {"role": "client", "name": "read:decode", "bucket": "compute",
              "start_ms": 40.0, "dur_ms": 60.0},
         ],
     })
@@ -96,8 +96,14 @@ def test_attribution_missing_legs_surface_as_unattributed():
     smeared over the known buckets."""
     attr = attribute_timeline({
         "trace_id": 0x12, "wall_ms": 50.0, "segments": [
-            {"role": "client", "name": "read:net",
+            {"role": "client", "name": "read:net", "bucket": "net",
              "start_ms": 0.0, "dur_ms": 10.0},
+            # a span whose site named no bucket (or one this table does
+            # not have) is not guessed at from its name: unattributed
+            {"role": "client", "name": "read:net",
+             "start_ms": 20.0, "dur_ms": 10.0},
+            {"role": "client", "name": "send", "bucket": "wire",
+             "start_ms": 30.0, "dur_ms": 10.0},
         ],
     })
     _assert_sane(attr)
@@ -121,16 +127,16 @@ def test_attribution_clock_skewed_rings_clamp_to_wall():
     attr = attribute_timeline({
         "trace_id": 0x14, "wall_ms": 100.0, "segments": [
             # starts 20 ms BEFORE the wall: only [0,10) counts
-            {"role": "chunkserver", "name": "cs_read",
+            {"role": "chunkserver", "name": "cs_read", "bucket": "net",
              "start_ms": -20.0, "dur_ms": 30.0},
             # runs 500 ms past the wall: only [90,100) counts
-            {"role": "chunkserver", "name": "net:send",
+            {"role": "chunkserver", "name": "net:send", "bucket": "net",
              "start_ms": 90.0, "dur_ms": 500.0},
             # entirely outside the wall: contributes nothing
-            {"role": "chunkserver", "name": "disk",
+            {"role": "chunkserver", "name": "disk", "bucket": "disk",
              "start_ms": 200.0, "dur_ms": 50.0},
             # corrupt negative duration: skipped, not subtracted
-            {"role": "client", "name": "read:net",
+            {"role": "client", "name": "read:net", "bucket": "net",
              "start_ms": 40.0, "dur_ms": -5.0},
         ],
     })
@@ -146,7 +152,7 @@ def test_attribution_zero_duration_op():
     must come back all-zero — no division error, no negative gap."""
     attr = attribute_timeline({
         "trace_id": 0x15, "wall_ms": 0.0, "segments": [
-            {"role": "client", "name": "read:net",
+            {"role": "client", "name": "read:net", "bucket": "net",
              "start_ms": 0.0, "dur_ms": 5.0},
         ],
     })
@@ -163,7 +169,7 @@ def test_attribution_native_queue_disk_net_split():
     classifying its envelope — one cs_read feeds three buckets."""
     attr = attribute_timeline({
         "trace_id": 0x16, "wall_ms": 10.0, "segments": [
-            {"role": "chunkserver", "name": "cs_read",
+            {"role": "chunkserver", "name": "cs_read", "bucket": "disk",
              "start_ms": 0.0, "dur_ms": 10.0,
              "attrs": {"queue_us": 2000, "disk_us": 3000,
                        "net_us": 4000}},
@@ -179,7 +185,7 @@ def test_attribution_native_queue_disk_net_split():
     # cannot inflate the split past the span's own duration
     over = attribute_timeline({
         "trace_id": 0x17, "wall_ms": 10.0, "segments": [
-            {"role": "chunkserver", "name": "cs_read",
+            {"role": "chunkserver", "name": "cs_read", "bucket": "disk",
              "start_ms": 0.0, "dur_ms": 4.0,
              "attrs": {"queue_us": 9_000_000, "disk_us": 9_000_000,
                        "net_us": 9_000_000}},
@@ -199,16 +205,16 @@ def test_attribution_composes_with_merge_timeline():
         {"trace_id": tid, "span_id": 1, "parent_id": 0, "role": "client",
          "name": "read_file", "t0": 100.0, "t1": 100.1},
         {"trace_id": tid, "span_id": 2, "parent_id": 0, "role": "client",
-         "name": "read:locate", "t0": 100.0, "t1": 100.01},
+         "name": "read:locate", "bucket": "net", "t0": 100.0, "t1": 100.01},
         {"trace_id": tid, "span_id": 3, "parent_id": 0, "role": "client",
-         "name": "queue_wait:dial", "t0": 100.01, "t1": 100.02},
+         "name": "queue_wait:dial", "bucket": "queue", "t0": 100.01, "t1": 100.02},
         {"trace_id": tid, "span_id": 4, "parent_id": 0,
-         "role": "chunkserver", "name": "cs_read",
+         "role": "chunkserver", "name": "cs_read", "bucket": "disk",
          "t0": 100.02, "t1": 100.07,
          "attrs": {"queue_us": 10_000, "disk_us": 20_000,
                    "net_us": 15_000}},
         {"trace_id": tid, "span_id": 5, "parent_id": 0, "role": "client",
-         "name": "read:decode", "t0": 100.07, "t1": 100.09},
+         "name": "read:decode", "bucket": "compute", "t0": 100.07, "t1": 100.09},
     ]
     timeline = merge_timeline(spans, tid, wall_name="read_file")
     attr = attribute_timeline(timeline)
@@ -385,6 +391,66 @@ def test_read_phases_count_once_across_replica_fallback(tmp_path, seed):
     assert fallbacks >= 1, f"seed {seed}: replica fallback never engaged"
     assert d["reps"] == 1, f"seed {seed}: wall/reps charged {d['reps']}x"
     assert d["locate_ms"] > 0.0, "fallback locate left no locate time"
+
+
+# --- one span tree per logical read ------------------------------------------
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("backend", ["cpu", "tpu_interpret"])
+async def test_degraded_read_yields_one_span_tree_that_sums_to_wall(
+    tmp_path, backend
+):
+    """A 2 MiB read of an ec(8,4) chunk that has lost a data part:
+    root, locate, the throttle's wait, plan, waves (the parallel part
+    reads, net a part), decode (holding the recover call's four
+    boundary spans), gather and copy, as one tree whose top level plus
+    self time is the wall."""
+    from tests.test_cluster import WIDE_EC_GOAL, Cluster
+    from tests.test_write_phases import check_one_tree, encoder_for
+
+    cluster = Cluster(tmp_path, n_cs=12)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        c.encoder = encoder_for(backend)
+        f = await c.create(1, f"tree_{backend}.bin")
+        await c.setgoal(f.inode, WIDE_EC_GOAL)
+        payload = data_generator.generate(13, 2 * 2**20).tobytes()
+        await c.write_file(f.inode, payload)
+        chunk_id = (await c.chunk_info(f.inode, 0)).chunk_id
+        victim = next(
+            cs for cs in cluster.chunkservers
+            if any(cf.chunk_id == chunk_id and cf.part_id % 64 == 0
+                   for cf in cs.store.all_parts()))
+        await victim.stop()
+        c.cache.invalidate(f.inode)
+        c._locate_cache.clear()
+        # the first read learns the holder is gone (and warms the
+        # recover shape); the one under test plans round it
+        assert await c.read_file(f.inode, 0, len(payload)) == payload
+        c.cache.invalidate(f.inode)
+        c.trace_ring.clear()
+        before = c.read_phases.snapshot()
+        assert await c.read_file(f.inode, 0, len(payload)) == payload
+        d = phase_delta(c.read_phases.snapshot(), before)
+        spans = c.trace_ring.dump()
+        check_one_tree(spans, "read_file", c.read_phases, d, "decode",
+                       backend)
+        names = {s["name"] for s in spans}
+        assert {"plan", "waves", "net", "decode", "gather", "copy"} <= names
+        waves = [s for s in spans if s["name"] == "waves"]
+        assert len(waves) == 1
+        nets = [s for s in spans if s["name"] == "net"]
+        assert len(nets) >= 8 and all(
+            s["parent_id"] == waves[0]["span_id"] for s in nets)
+        assert d["net_ms"] > d["waves_ms"], "net sums the parallel parts"
+        if backend != "cpu":
+            rec = [s for s in spans if s["name"] == "boundary"]
+            assert rec[0]["attrs"]["op"] == "recover"
+            assert rec[0]["attrs"]["rows"] == 8
+    finally:
+        await cluster.stop()
 
 
 # --- end-to-end smoke (`make read-smoke`) -----------------------------------

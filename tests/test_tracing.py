@@ -205,6 +205,385 @@ async def test_skewed_peer_is_served(tmp_path):
     finally:
         await cluster.stop()
 
+# --- the span primitive: parents, self time, sink, annotator ---------------
+
+
+def _sink():
+    from lizardfs_tpu.runtime.metrics import PhaseBreakdown
+
+    ph = PhaseBreakdown("t", {"a": None, "b": None, "c": "a"})
+    ring = tracing.SpanRing()
+    return tracing.OpSink(ph, ring, "client"), ph, ring
+
+
+def _by_name(ring):
+    out = {}
+    for s in ring.dump():
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def _busy(seconds: float) -> None:
+    import time
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_span_parents_and_self_time_serial_and_nested():
+    tracing.clear_trace()
+    sink, ph, ring = _sink()
+    with tracing.span("op", sink=sink, bytes=7) as root:
+        with tracing.span("a", phase="a", bucket="net"):
+            _busy(0.004)
+            with tracing.span("c", phase="c", bucket="compute"):
+                _busy(0.003)
+        with tracing.span("b", phase="b", bucket="queue"):
+            _busy(0.002)
+        _busy(0.002)  # nobody's but the root's
+    assert tracing.current_trace_id() == 0, "a root's own trace ends with it"
+    spans = _by_name(ring)
+    op, a, b, c = (spans[n][0] for n in ("op", "a", "b", "c"))
+    assert op["parent_id"] == 0 and op["span_id"] == root.span_id
+    assert a["parent_id"] == b["parent_id"] == op["span_id"]
+    assert c["parent_id"] == a["span_id"]
+    assert len({s["trace_id"] for s in ring.dump()}) == 1
+    assert (a["bucket"], b["bucket"], c["bucket"]) == (
+        "net", "queue", "compute")
+    assert op["attrs"] == {"bytes": 7}
+    dur = lambda s: (s["t1"] - s["t0"]) * 1e3  # noqa: E731
+    # self time: duration less what the children cover
+    assert a["self_ms"] == pytest.approx(dur(a) - dur(c), abs=0.05)
+    assert c["self_ms"] == pytest.approx(dur(c), abs=0.01)
+    assert op["self_ms"] == pytest.approx(dur(op) - dur(a) - dur(b), abs=0.05)
+    assert op["self_ms"] >= 1.9
+    # phase rows: each span charged once; the root closed one rep with
+    # its wall and self time, and the top level sums to wall with it
+    snap = ph.snapshot()
+    assert snap["reps"] == 1
+    assert snap["a_ms"] == pytest.approx(dur(a), abs=0.05)
+    assert snap["c_ms"] == pytest.approx(dur(c), abs=0.05)
+    assert snap["wall_ms"] == pytest.approx(dur(op), abs=0.05)
+    assert snap["self_ms"] == pytest.approx(op["self_ms"], abs=0.02)
+    top = sum(snap[f"{p}_ms"] for p in ph.top_level)
+    assert ph.top_level == ("a", "b")
+    assert top + snap["self_ms"] == pytest.approx(snap["wall_ms"], rel=0.02)
+
+
+@pytest.mark.asyncio
+async def test_span_parallel_children_count_once():
+    """Children that overlap (a gather) cover their union of the
+    parent once: its self time never goes negative, and its phase row
+    holds its own duration, not the children's sum."""
+    tracing.clear_trace()
+    sink, ph, ring = _sink()
+
+    async def child(delay):
+        with tracing.span("c", phase="c"):
+            await asyncio.sleep(delay)
+
+    with tracing.span("op", sink=sink):
+        with tracing.span("a", phase="a"):
+            await asyncio.gather(child(0.02), child(0.03), child(0.03))
+    spans = _by_name(ring)
+    a = spans["a"][0]
+    assert len(spans["c"]) == 3
+    assert all(c["parent_id"] == a["span_id"] for c in spans["c"])
+    dur_a = (a["t1"] - a["t0"]) * 1e3
+    summed = sum((c["t1"] - c["t0"]) * 1e3 for c in spans["c"])
+    assert summed > dur_a * 2  # busy time legitimately passes wall
+    assert 0.0 <= a["self_ms"] < dur_a * 0.5
+    snap = ph.snapshot()
+    assert snap["c_ms"] == pytest.approx(summed, abs=0.1)
+    assert snap["a_ms"] == pytest.approx(dur_a, abs=0.1)
+
+
+@pytest.mark.asyncio
+async def test_span_children_in_to_thread_and_executor_thread():
+    """to_thread copies the context; an executor hop carries the open
+    span and the sink by hand (native_io.partial_with_trace) and turns
+    the wait for the thread into a ``hop`` span of its own."""
+    from lizardfs_tpu.core import native_io
+
+    tracing.clear_trace()
+    sink, ph, ring = _sink()
+
+    def work(name):
+        with tracing.span(name, phase="c"):
+            _busy(0.002)
+        return tracing.current_trace_id()
+
+    with tracing.span("op", sink=sink) as root:
+        with tracing.span("a", phase="a"):
+            tid_thread = await asyncio.to_thread(work, "in_to_thread")
+        with tracing.span("b", phase="b"):
+            tid_exec = await asyncio.get_running_loop().run_in_executor(
+                native_io.EXECUTOR,
+                native_io.partial_with_trace(work, "in_executor"),
+            )
+            # what the pool thread was given it gave back
+            assert await asyncio.get_running_loop().run_in_executor(
+                native_io.EXECUTOR, tracing.current_trace_id) == 0
+    assert tid_thread == tid_exec == root.trace_id != 0
+    spans = _by_name(ring)
+    assert spans["in_to_thread"][0]["parent_id"] == spans["a"][0]["span_id"]
+    assert spans["in_executor"][0]["parent_id"] == spans["b"][0]["span_id"]
+    hop = spans["hop"][0]
+    assert hop["parent_id"] == spans["b"][0]["span_id"]
+    assert hop["bucket"] == "queue"
+    assert hop["t1"] <= spans["in_executor"][0]["t0"] + 1e-3
+    snap = ph.snapshot()
+    assert snap["c_ms"] >= 3.9 and snap["hop_ms"] >= 0.0  # both charged
+
+
+def test_span_without_an_op_charges_nothing_and_records_nothing():
+    tracing.clear_trace()
+    with tracing.span("dial", phase="dial") as sp:
+        pass
+    assert sp.span_id == 0 and tracing.current_trace_id() == 0
+
+
+def test_span_begin_end_pair_and_retroactive_open():
+    import time
+
+    tracing.clear_trace()
+    sink, ph, ring = _sink()
+    root = tracing.span("op", sink=sink).begin()
+    t_wait = time.perf_counter()
+    _busy(0.003)
+    # a wait known only once it is over opens in the past
+    tracing.span("b", phase="b", bucket="queue").begin(at=t_wait).end()
+    root.end()
+    b = _by_name(ring)["b"][0]
+    assert (b["t1"] - b["t0"]) * 1e3 >= 2.9
+    assert ph.snapshot()["b_ms"] >= 2.9
+    assert _by_name(ring)["op"][0]["self_ms"] < 1.0
+
+
+def test_annotator_called_only_when_registered(monkeypatch):
+    """No profiler name is built while no process has registered the
+    annotation; once one has, every span opens ``lz.<layer>.<name>``
+    with its attributes, an op root adding ``t_ns`` (its opening on
+    time.time_ns()'s clock), and closes it."""
+    import time
+
+    tracing.clear_trace()
+    calls = []
+
+    class Ann:
+        def __init__(self, name, **meta):
+            calls.append(["new", name, meta])
+
+        def __enter__(self):
+            calls.append(["enter"])
+
+        def __exit__(self, *exc):
+            calls.append(["exit"])
+
+    sink, _ph, _ring = _sink()
+    monkeypatch.setattr(tracing, "_ANNOTATE", None)
+    with tracing.span("op", sink=sink):
+        with tracing.span("a", layer="encoder", phase="a", k=3):
+            pass
+    assert calls == []
+    tracing.register_annotator(Ann)
+    t_before = time.time_ns()
+    with tracing.span("op", sink=sink, bytes=1):
+        with tracing.span("a", layer="encoder", phase="a", k=3):
+            pass
+    news = [c for c in calls if c[0] == "new"]
+    assert [c[1] for c in news] == ["lz.client.op", "lz.encoder.a"]
+    assert news[1][2] == {"k": 3}
+    assert news[0][2]["bytes"] == 1
+    assert abs(news[0][2]["t_ns"] - t_before) < 50_000_000
+    assert [c[0] for c in calls].count("enter") == 2
+    assert [c[0] for c in calls].count("exit") == 2
+    # a span outside any op opens none (it has no trace to belong to)
+    del calls[:]
+    with tracing.span("dial", phase="dial"):
+        pass
+    assert calls == []
+    # registered, but no profiler session is live: one check, no name
+    # built, nothing opened (what an untraced run pays)
+    live = []
+    tracing.register_annotator(Ann, lambda: bool(live))
+    with tracing.span("op", sink=sink):
+        pass
+    assert calls == []
+    live.append(1)
+    with tracing.span("op", sink=sink):
+        pass
+    assert [c[0] for c in calls] == ["new", "enter", "exit"]
+
+
+def test_lz_trace_off_leaves_ring_empty_and_phase_rows_charged():
+    tracing.clear_trace()
+    sink, ph, ring = _sink()
+    tracing.set_enabled(False)
+    try:
+        with tracing.span("op", sink=sink) as root:
+            with tracing.span("a", phase="a"):
+                _busy(0.002)
+            assert tracing.current_trace_id() == 0
+            assert tracing.PHASE_SINK.get() is sink
+    finally:
+        tracing.set_enabled(True)
+    assert tracing.PHASE_SINK.get() is None
+    assert len(ring) == 0 and root.span_id == 0 and root.trace_id == 0
+    snap = ph.snapshot()
+    assert snap["reps"] == 1 and snap["a_ms"] >= 1.9
+    assert snap["wall_ms"] >= snap["a_ms"] and snap["self_ms"] == 0.0
+
+
+def test_ids_come_from_one_seeded_counter():
+    import lizardfs_tpu.runtime.tracing as mod
+
+    assert not hasattr(mod, "secrets")
+    ids = [tracing.new_id() for _ in range(1000)]
+    assert len(set(ids)) == 1000 and all(0 < i < 2**63 for i in ids)
+    assert ids == sorted(ids) or ids[0] > ids[-1]  # a counter (may wrap)
+
+
+def test_phase_charges_from_worker_threads_lose_no_update():
+    """PhaseBreakdown.add is a read-modify-write reached from the loop,
+    to_thread workers and the native-io pool at once."""
+    import sys
+    import threading
+
+    from lizardfs_tpu.runtime.metrics import PhaseBreakdown
+
+    ph = PhaseBreakdown("t", ("a",))
+    n_threads, n_adds = 16, 4000
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(n_adds):
+                ph.add("a", 1.0)
+                ph.add("late", 1.0)  # a phase the tree does not name
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert ph.totals_s["a"] == n_threads * n_adds
+    assert ph.totals_s["late"] == n_threads * n_adds
+
+
+def test_srv_us_version_skew():
+    """``srv_us`` trails the grant and locate replies: a master that
+    predates it is decoded (0: the client charges nothing), a reply
+    that carries none is byte-identical to the old encoding, and a cut
+    inside a required field still fails."""
+    for cls, extra in ((m.MatoclWriteChunk, {}),
+                       (m.MatoclReadChunk, {"meta_version": 9})):
+        fields = dict(req_id=1, status=0, chunk_id=5, version=2,
+                      file_length=10, locations=[], **extra)
+        body = cls(srv_us=1234, **fields).pack_body()
+        assert cls.parse(body).srv_us == 1234
+        old = body[:-4]  # exactly the encoding before the field
+        decoded = cls.parse(old)
+        assert decoded.srv_us == 0 and decoded.chunk_id == 5
+        assert decoded.file_length == 10
+        assert cls(**fields).pack_body() == old
+        with pytest.raises(Exception):
+            cls.parse(body[:20])  # cut inside file_length: no zero-fill
+
+
+@pytest.mark.asyncio
+async def test_master_without_srv_us_is_served(tmp_path, monkeypatch):
+    """E2E skew: against a master that stamps no ``srv_us`` (one that
+    predates it) a traced pwrite and read work, charge no server
+    phase, and still have their grant / locate spans; against this
+    master the same ops carry the stamp inside those spans."""
+    from lizardfs_tpu.master.server import MasterServer
+
+    cluster = Cluster(tmp_path, n_cs=3)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "srv.bin")
+        monkeypatch.setattr(
+            MasterServer, "_stamp_srv", staticmethod(lambda reply, dt: None))
+        await c.pwrite(f.inode, 0, b"x" * 1000)
+        assert await c.read_file(f.inode, 0, 1000) == b"x" * 1000
+        w, r = c.write_phases.snapshot(), c.read_phases.snapshot()
+        assert w["grant_ms"] > 0 and w["grant_srv_ms"] == 0
+        assert r["locate_ms"] > 0 and r["locate_srv_ms"] == 0
+        names = {s["name"] for s in c.trace_ring.dump()}
+        assert {"grant", "locate"} <= names
+        assert not {"grant_srv", "locate_srv"} & names
+        monkeypatch.undo()
+        c.trace_ring.clear()
+        await c.pwrite(f.inode, 0, b"y" * 1000)
+        c._locate_cache.clear()
+        assert await c.read_file(f.inode, 0, 1000) == b"y" * 1000
+        w, r = c.write_phases.snapshot(), c.read_phases.snapshot()
+        assert 0 < w["grant_srv_ms"] <= w["grant_ms"]
+        assert 0 < r["locate_srv_ms"] <= r["locate_ms"]
+        spans = {s["name"]: s for s in c.trace_ring.dump()}
+        assert spans["grant"]["attrs"]["srv_us"] >= 1
+        assert spans["grant_srv"]["parent_id"] == spans["grant"]["span_id"]
+        assert spans["grant"]["t0"] <= spans["grant_srv"]["t0"]
+        assert spans["grant_srv"]["t1"] <= spans["grant"]["t1"] + 1e-4
+        assert spans["locate_srv"]["parent_id"] == spans["locate"]["span_id"]
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.asyncio
+async def test_traced_pwrite_is_one_tree_that_attributes(tmp_path):
+    """A merged timeline of one traced pwrite (the client's ring and
+    every daemon's) has every span but the root carrying a parent that
+    is in the timeline, and its attribution leaves little of the wall
+    to no bucket."""
+    cluster = Cluster(tmp_path, n_cs=6)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "tree.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        payload = b"p" * (3 * 2**20)
+        await c.pwrite(f.inode, 0, payload)  # chunk made, pools warm
+        c.trace_ring.clear()
+        tid = tracing.start_trace()
+        try:
+            await c.pwrite(f.inode, len(payload), payload)
+        finally:
+            tracing.clear_trace()
+        client_spans = c.trace_ring.dump(tid)
+        ids = {s["span_id"] for s in client_spans}
+        roots = [s for s in client_spans if s["parent_id"] not in ids]
+        assert [s["name"] for s in roots] == ["pwrite"]
+        assert roots[0]["parent_id"] == 0
+        names = {s["name"] for s in client_spans}
+        assert {"getattr", "grant", "encode", "split", "send",
+                "part", "commit", "CltomaWriteChunk"} <= names
+        spans = list(client_spans) + cluster.master.trace_spans(tid)
+        for cs in cluster.chunkservers:
+            spans += cs.trace_spans(tid)
+        tl = tracing.merge_timeline(spans, tid, wall_name="pwrite")
+        seg_ids = {s["span_id"] for s in tl["segments"]} | {
+            roots[0]["span_id"]}
+        client_segs = [s for s in tl["segments"] if s["role"] == "client"]
+        assert client_segs and all(
+            s["parent_id"] in seg_ids for s in client_segs)
+        assert {s["role"] for s in tl["segments"]} >= {
+            "client", "master", "chunkserver"}
+        attr = tracing.attribute_timeline(tl)
+        # under 10 % on a quiet box and on the chip (PERF.md); a 20 ms
+        # op beside five other test workers gets room for the loop's
+        # scheduling delays, which are the root's self time
+        assert attr["pct"]["unattributed"] < 25.0, attr
+        assert sum(attr["buckets_ms"].values()) == pytest.approx(
+            attr["wall_ms"], abs=0.01)
+    finally:
+        await cluster.stop()
+
+
 
 # --- e2e: one write yields a merged cross-role trace -----------------------
 

@@ -157,6 +157,141 @@ async def test_phase_breakdown_sums_to_wall_clock(tmp_path):
             assert 0.4 * d["wall_ms"] <= total <= 2.0 * d["wall_ms"], (
                 f"phase sum {total} vs wall {d['wall_ms']} out of range"
             )
+            # the span tree's own statement of the same: the grant is a
+            # phase of its own now, and on this strictly serial path
+            # the top level plus the root's self time IS the wall
+            assert d["getattr_ms"] > 0.0 and d["grant_ms"] > 0.0
+            assert 0.0 < d["grant_srv_ms"] <= d["grant_ms"]
+            top = sum(d[f"{p}_ms"] for p in client.write_phases.top_level)
+            assert top + d["self_ms"] == pytest.approx(
+                d["wall_ms"], rel=0.02
+            ), (top, d)
+    finally:
+        await cluster.stop()
+
+
+def encoder_for(backend: str):
+    """The two backends a span tree is pinned under: the numpy golden
+    encoder, and the device encoder on the CPU platform (the Pallas
+    interpreter), which opens the four boundary spans."""
+    from lizardfs_tpu.core.encoder import CpuChunkEncoder, TpuChunkEncoder
+
+    if backend == "cpu":
+        return CpuChunkEncoder()
+    return TpuChunkEncoder(force_cpu=True, interpret=True)
+
+
+def check_one_tree(spans, root_name, phases, delta, holder, backend):
+    """One op's ring spans form ONE tree under ``root_name``; its
+    top-level phases plus the root's self time equal the wall within
+    2 %; under ``holder`` sits the call across the encoder boundary
+    with its four spans, in order (the device backend only)."""
+    ids = {s["span_id"]: s for s in spans}
+    roots = [s for s in spans if s["parent_id"] not in ids]
+    assert [s["name"] for s in roots] == [root_name], roots
+    assert len({s["trace_id"] for s in spans}) == 1
+    root = roots[0]
+    wall = (root["t1"] - root["t0"]) * 1e3
+    assert delta["reps"] == 1
+    assert delta["wall_ms"] == pytest.approx(wall, abs=0.05)
+    assert delta["self_ms"] == pytest.approx(root["self_ms"], abs=0.05)
+    top = sum(delta[f"{p}_ms"] for p in phases.top_level)
+    assert top + delta["self_ms"] == pytest.approx(wall, rel=0.02), (
+        top, delta)
+    # what the top level holds are the root's direct children
+    direct = {s["name"] for s in spans if s["parent_id"] == root["span_id"]}
+    assert direct <= set(phases.top_level) | {"throttle"}, direct
+
+    def ancestors(s):
+        while s["parent_id"] in ids:
+            s = ids[s["parent_id"]]
+            yield s["name"]
+
+    boundary = [s for s in spans if s["name"] == "boundary"]
+    if backend == "cpu":
+        assert not boundary
+        return
+    assert boundary, "no call crossed the encoder boundary"
+    for b in boundary:
+        assert holder in list(ancestors(b)), list(ancestors(b))
+        legs = sorted((s for s in spans if s["parent_id"] == b["span_id"]),
+                      key=lambda s: s["t0"])
+        assert [s["name"] for s in legs] == [
+            "dev_stage", "dev_put", "dev_run", "dev_fetch"]
+        assert {"op", "k", "m", "rows", "bytes"} <= set(b["attrs"])
+        assert sum(s["t1"] - s["t0"] for s in legs) <= (
+            b["t1"] - b["t0"]) + 1e-6
+    for leg in ("dev_stage", "dev_put", "dev_run", "dev_fetch"):
+        assert delta[f"{leg}_ms"] > 0.0
+    assert delta["boundary_ms"] == pytest.approx(
+        sum((b["t1"] - b["t0"]) * 1e3 for b in boundary), abs=0.05)
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("backend", ["cpu", "tpu_interpret"])
+async def test_pwrite_yields_one_span_tree_that_sums_to_wall(
+    tmp_path, backend
+):
+    """A 2 MiB pwrite of four full ec(8,4) stripes (the shape of the
+    benchmark's streaming cell): root, getattr, lock, grant, encode
+    (split, and under it the boundary's four spans), send (a part span
+    a part, its legs under it) and commit, as one tree."""
+    payload = _payload(2 * 2**20)
+    cluster = Cluster(tmp_path, n_cs=12)
+    await cluster.start(health_interval=5.0)
+    try:
+        client = await cluster.client()
+        client.encoder = encoder_for(backend)
+        f = await client.create(1, f"tree_{backend}.bin")
+        await client.setgoal(f.inode, EC84_GOAL)
+        await client.pwrite(f.inode, 0, payload)  # chunk made, shape warm
+        client.trace_ring.clear()
+        before = client.write_phases.snapshot()
+        await client.pwrite(f.inode, len(payload), payload)
+        d = phase_delta(client.write_phases.snapshot(), before)
+        spans = client.trace_ring.dump()
+        check_one_tree(spans, "pwrite", client.write_phases, d, "encode",
+                       backend)
+        by_name: dict = {}
+        for s in spans:
+            by_name.setdefault(s["name"], []).append(s)
+        for name in ("getattr", "grant", "encode", "split", "send",
+                     "commit"):
+            assert len(by_name[name]) == 1, name
+        # the chunk's write lock was free: no wait, so no lock span
+        assert "lock" not in by_name and d["lock_ms"] == 0.0
+        assert by_name["split"][0]["parent_id"] == \
+            by_name["encode"][0]["span_id"]
+        parts = by_name["part"]
+        assert len(parts) == 12
+        assert all(p["parent_id"] == by_name["send"][0]["span_id"]
+                   for p in parts)
+        assert {p["attrs"]["bytes"] for p in parts} == {262144}
+        assert {p["attrs"]["plane"] for p in parts} <= {"native", "asyncio"}
+        for p in parts:
+            legs = {s["name"] for s in spans
+                    if s["parent_id"] == p["span_id"]}
+            want = ({"hop", "part_dial", "part_init", "part_data",
+                     "part_end"} if p["attrs"]["plane"] == "native" else
+                    {"part_dial", "part_init", "part_data", "part_ack",
+                     "part_end"})
+            assert want <= legs, (p["attrs"], legs)
+        # nested phases split their parents: never more than them
+        assert d["split_ms"] <= d["encode_ms"]
+        assert d["grant_srv_ms"] <= d["grant_ms"]
+        # two writers on one chunk: the second waits for the lock, and
+        # the wait is a span of its own under its root
+        client.trace_ring.clear()
+        await asyncio.gather(
+            client.pwrite(f.inode, 0, payload),
+            client.pwrite(f.inode, len(payload), payload),
+        )
+        waits = [s for s in client.trace_ring.dump() if s["name"] == "lock"]
+        assert len(waits) == 1 and waits[0]["bucket"] == "queue"
+        assert (waits[0]["t1"] - waits[0]["t0"]) > 0.001
+        client.cache.invalidate(f.inode)
+        assert await client.read_file(f.inode, 0, 2 * len(payload)) == \
+            payload * 2
     finally:
         await cluster.stop()
 
