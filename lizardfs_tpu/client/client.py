@@ -84,6 +84,22 @@ def _is_transient(e: Exception) -> bool:
     )
 
 
+def _abort_zombie_sends(send_cells: list[dict]) -> list[dict]:
+    """Kill executor threads of cancelled/failed native sends:
+    run_in_executor threads are unkillable, so a cancelled send would
+    otherwise keep streaming from its buffer for up to 120 s while
+    pinning a native-IO worker."""
+    from lizardfs_tpu.core import native_io
+
+    zombies = [
+        c for c in send_cells
+        if c.get("submitted") and not c.get("finished")
+    ]
+    for c in zombies:
+        native_io.abort_write(c)
+    return zombies
+
+
 class Client:
     def __init__(
         self,
@@ -1693,21 +1709,91 @@ class Client:
             parts = await asyncio.to_thread(
                 striping.split_chunk, region, slice_type, self.encoder
             )
-        sends = []
-        for part_idx, locs in copies.items():
-            stream = parts.get(part_idx)
-            if stream is None:
-                continue
-            sends.append(
-                self._write_part(
-                    grant.chunk_id, grant.version, locs,
-                    stream[: nstripes * MFSBLOCKSIZE],
-                    nstripes * MFSBLOCKSIZE,
-                    part_offset=lo_s * MFSBLOCKSIZE,
-                )
+        nbytes = nstripes * MFSBLOCKSIZE
+        send_cells: list[dict] = []
+        try:
+            await self._send_parts(
+                grant.chunk_id, grant.version,
+                [(locs, parts[p][:nbytes], nbytes)
+                 for p, locs in copies.items() if p in parts],
+                lo_s * MFSBLOCKSIZE, send_cells,
             )
+        finally:
+            # a cancelled pwrite must not leave a worker streaming the
+            # region for up to 120 s (as _push_chunk_parts)
+            _abort_zombie_sends(send_cells)
+
+    async def _send_parts(
+        self, chunk_id: int, version: int,
+        parts: list[tuple[list[m.PartLocation], np.ndarray, int]],
+        part_offset: int, send_cells: list[dict],
+        skip_throttle: bool = False,
+    ) -> None:
+        """Write ``payload[:length]`` at the block-aligned
+        ``part_offset`` of several parts of one chunk, each given as
+        ``(holders, payload, length)``: ONE pooled scatter exchange
+        (native_io.write_parts_scatter_blocking: one worker thread,
+        pooled sockets, every init sent before an ack is read, one
+        poll-driven C call for the frames and their acks, every end
+        sent before one is read) when every part has a single holder
+        (no relay chain), per-part sends otherwise or when the
+        exchange fails. Shared by whole-chunk writes and the RMW
+        region of a striped pwrite. ``send_cells`` receives the abort
+        handle of every native send issued (_abort_zombie_sends).
+        ``skip_throttle``: the caller already charged these bytes
+        (QoS rule: charge once, not per retry/fallback)."""
+        from lizardfs_tpu.core import native_io
+
+        if not parts:
+            return
+        lengths = [length for _, _, length in parts]
+        if not skip_throttle:
+            # charged BEFORE the send timer starts: QoS queueing
+            # (token-bucket waits, the limit-renew RPC) must not be
+            # booked as send_ms, or a throttled client's phase row
+            # misattributes pacing as chunkserver transfer time
+            await self._throttle(sum(lengths))
         with tracing.span("send", phase="send", bucket="net"):
-            await asyncio.gather(*sends)
+            if (
+                native_io.parts_scatter_available()
+                and not _faults.ACTIVE
+                and len(parts) > 1
+                and all(len(locs) == 1 for locs, _, _ in parts)
+            ):
+                cell: dict = {"submitted": True}
+                send_cells.append(cell)
+                try:
+                    # one part span an exchange; the worker's hop and
+                    # its legs (part_dial: the pool acquire) under it
+                    with tracing.span(
+                        "part", layer="wire", phase="part", bucket="net",
+                        plane="scatter", parts=len(parts), bytes=sum(lengths),
+                    ):
+                        await native_io.run(
+                            native_io.write_parts_scatter_blocking,
+                            [(locs[0].addr.host, locs[0].addr.port)
+                             for locs, _, _ in parts],
+                            chunk_id, version,
+                            [locs[0].part_id for locs, _, _ in parts],
+                            [pay for _, pay, _ in parts], lengths,
+                            part_offset, cell,
+                        )
+                    self._record("parts_scatter_write")
+                    return
+                except (native_io.NativeIOError, OSError,
+                        ConnectionError, st.StatusError):
+                    self._record("parts_scatter_fallback")
+            # bytes already charged above — per-part sends must not
+            # pay again (and their throttle would pollute the timer)
+            cells: list[dict] = [{} for _ in parts]
+            send_cells.extend(cells)
+            await asyncio.gather(*(
+                self._write_part(
+                    chunk_id, version, locs, pay, length,
+                    part_offset=part_offset, skip_throttle=True, cell=c,
+                )
+                for (locs, pay, length), c in zip(parts, cells)
+            ))
 
     async def _write_chunk(
         self, inode: int, chunk_index: int, chunk_data: np.ndarray,
@@ -1809,78 +1895,16 @@ class Client:
         async def send_batch(
             items: list[tuple[int, np.ndarray]], skip_throttle: bool = False
         ) -> None:
-            """Write several whole parts: ONE native poll-driven call
-            when every part has a single holder (no relay chain),
-            per-part sends otherwise or on native failure.
-            ``skip_throttle``: the caller already charged these bytes
-            (QoS rule: charge once, not per retry/fallback)."""
-            from lizardfs_tpu.core import native_io
-
-            items = [(p, pay) for p, pay in items if p in by_part]
-            if not items:
-                return
-            lengths = [
-                striping.part_length(slice_type, p, len(chunk_data))
-                for p, _ in items
-            ]
-            if not skip_throttle:
-                # charged BEFORE the send timer starts: QoS queueing
-                # (token-bucket waits, the limit-renew RPC) must not be
-                # booked as send_ms, or a throttled client's phase row
-                # misattributes pacing as chunkserver transfer time
-                await self._throttle(sum(lengths))
-            with tracing.span("send", phase="send", bucket="net"):
-                if (
-                    native_io.parts_scatter_available()
-                    and not _faults.ACTIVE
-                    and len(items) > 1
-                    and all(len(by_part[p]) == 1 for p, _ in items)
-                ):
-                    cell: dict = {"submitted": True}
-                    send_cells.append(cell)
-                    try:
-                        await native_io.run(
-                            native_io.write_parts_scatter_blocking,
-                            [(by_part[p][0].addr.host,
-                              by_part[p][0].addr.port)
-                             for p, _ in items],
-                            grant.chunk_id, grant.version,
-                            [by_part[p][0].part_id for p, _ in items],
-                            [pay for _, pay in items], lengths, 0, cell,
-                        )
-                        self._record("parts_scatter_write")
-                        return
-                    except (native_io.NativeIOError, OSError,
-                            ConnectionError, st.StatusError):
-                        self._record("parts_scatter_fallback")
-                        # fall through per-part — bytes were already
-                        # charged to the throttle above, don't pay twice
-                        await asyncio.gather(*(
-                            send_of(p, pay, skip_throttle=True)
-                            for p, pay in items
-                        ))
-                        return
-                # bytes already charged above — per-part sends must not
-                # pay again (and their throttle would pollute the timer)
-                await asyncio.gather(*(
-                    send_of(p, pay, skip_throttle=True)
-                    for p, pay in items
-                ))
+            """Write several whole parts (those the grant placed)."""
+            await self._send_parts(
+                grant.chunk_id, grant.version,
+                [(by_part[p], pay,
+                  striping.part_length(slice_type, p, len(chunk_data)))
+                 for p, pay in items if p in by_part],
+                0, send_cells, skip_throttle,
+            )
 
         from lizardfs_tpu.core import native_io
-
-        def _abort_zombie_sends() -> list[dict]:
-            """Kill executor threads of cancelled/failed native sends:
-            run_in_executor threads are unkillable, so a cancelled send
-            would otherwise keep streaming from its buffer for up to
-            120 s while pinning a native-IO worker."""
-            zombies = [
-                c for c in send_cells
-                if c.get("submitted") and not c.get("finished")
-            ]
-            for c in zombies:
-                native_io.abort_write(c)
-            return zombies
 
         if slice_type.is_standard or slice_type.is_tape:
             # whole-chunk copies: stream the caller's buffer directly
@@ -1896,7 +1920,7 @@ class Client:
                 for t in copy_tasks:
                     t.cancel()
                 await asyncio.gather(*copy_tasks, return_exceptions=True)
-                _abort_zombie_sends()
+                _abort_zombie_sends(send_cells)
             return
         # striped slices: scatter into contiguous part streams first
         # (one memcpy, the `stage` phase), then hand off to one of:
@@ -2004,7 +2028,7 @@ class Client:
             # executor thread may still be streaming from the staging
             # buffer: kill it now, and never pool a buffer a zombie
             # thread might still read
-            zombies = _abort_zombie_sends()
+            zombies = _abort_zombie_sends(send_cells)
             self._stage_release(
                 stage, poolable=full_chunk and not zombies
             )

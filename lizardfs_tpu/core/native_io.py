@@ -139,40 +139,87 @@ class NativeIOError(Exception):
 
 
 class _SocketPool:
-    """Thread-safe pool of blocking sockets keyed by address."""
+    """Thread-safe pool of blocking sockets keyed by address.
 
-    def __init__(self, max_idle: int = 4):
-        self.max_idle = max_idle
-        self._lock = threading.Lock()
+    An address keeps as many idle sockets as it has had out at once:
+    12 sessions writing 5 parts each to 6 servers hold 10 a server, and
+    a fixed bound of 4 closed 6 in 10 on release, to be dialled again
+    by the next call. Never more than ``MAX_IDLE``: an idle socket
+    pins a connection thread on the chunkserver. ``share`` makes this
+    pool count its leases with another's: a socket taken from one may
+    go back to the other (a plain connection that negotiated a ring).
+
+    ``hits`` and ``dials`` count what :meth:`acquire` handed out, so
+    the reuse share can be read (tests pin it; beside UDS_CONNECTS)."""
+
+    MAX_IDLE = 32
+
+    def __init__(self, share: "_SocketPool | None" = None):
         self._idle: dict[tuple[str, int], list[socket.socket]] = {}
+        self.hits = self.dials = 0
+        if share is None:
+            self._lock = threading.Lock()
+            # addr -> sockets out now, and the most out at once
+            self._out: dict[tuple[str, int], int] = {}
+            self._peak: dict[tuple[str, int], int] = {}
+        else:
+            self._lock, self._out, self._peak = (
+                share._lock, share._out, share._peak)
 
-    def acquire(self, addr: tuple[str, int]) -> socket.socket:
+    def _lease(self, addr: tuple[str, int], n: int) -> None:
+        # under self._lock
+        out = self._out[addr] = self._out.get(addr, 0) + n
+        if out > self._peak.get(addr, 0):
+            self._peak[addr] = out
+
+    def acquire(self, addr: tuple[str, int],
+                fresh: bool = False) -> socket.socket:
+        """An idle socket, or a dial. ``fresh`` dials whatever is idle:
+        the second attempt after a failure, since one server restart
+        stales every socket the pool holds for it."""
         with self._lock:
-            bucket = self._idle.get(addr)
-            if bucket:
-                return bucket.pop()
-        return _blocking_socket(addr, 30.0)
+            bucket = None if fresh else self._idle.get(addr)
+            sock = bucket.pop() if bucket else None
+            self._lease(addr, 1)
+            if sock is not None:
+                self.hits += 1
+                return sock
+            self.dials += 1
+        try:
+            return _blocking_socket(addr, 30.0)
+        except BaseException:
+            with self._lock:
+                self._lease(addr, -1)
+            raise
 
     def try_acquire(self, addr: tuple[str, int]):
         """Pop an idle socket or return None — never dials."""
         with self._lock:
             bucket = self._idle.get(addr)
             if bucket:
+                self._lease(addr, 1)
+                self.hits += 1
                 return bucket.pop()
         return None
 
     def release(self, addr: tuple[str, int], sock: socket.socket) -> None:
         with self._lock:
+            self._lease(addr, -1)
             bucket = self._idle.setdefault(addr, [])
-            if len(bucket) < self.max_idle:
+            if len(bucket) < min(self._peak.get(addr, 0), self.MAX_IDLE):
                 bucket.append(sock)
                 return
         shm_ring_drop(sock)
         sock.close()
 
-    def discard(self, sock: socket.socket) -> None:
+    def discard(self, addr: tuple[str, int], sock: socket.socket) -> None:
+        with self._lock:
+            self._lease(addr, -1)
         shm_ring_drop(sock)
-        sock.close()
+        try:
+            sock.close()
+        except OSError:
+            pass
 
 
 # --- same-host shared-memory part rings (native/shm_ring.h) ----------------
@@ -415,7 +462,7 @@ POOL = _SocketPool()
 # session protocol (descriptors + bulk frames + init/end) but not the
 # read plane — reads and legacy per-part writes must keep drawing from
 # the plain POOL so they never land on a proactor-owned connection.
-RING_POOL = _SocketPool()
+RING_POOL = _SocketPool(share=POOL)
 
 # observability + contract pin: how many data-plane connections took the
 # same-host unix-socket fast path (tests assert this moves, so a silent
@@ -716,11 +763,11 @@ def read_part_blocking(
     for attempt in (0, 1):
         # second attempt dials fresh: the pool may hold several sockets
         # staled by the same server restart
-        sock = POOL.acquire(addr) if attempt == 0 else _blocking_socket(addr, 30.0)
+        sock = POOL.acquire(addr, fresh=attempt == 1)
         if cell is not None:
             cell["sock"] = sock
             if cell.get("aborted"):
-                POOL.discard(sock)
+                POOL.discard(addr, sock)
                 raise NativeIOError(-1, "read (aborted)")
         rc = fn(
             sock.fileno(), chunk_id, version, part_id, offset, size, ptr
@@ -730,10 +777,18 @@ def read_part_blocking(
         if rc == 0:
             POOL.release(addr, sock)
             return
-        POOL.discard(sock)
+        POOL.discard(addr, sock)
         if rc == -1 and attempt == 0 and not (cell or {}).get("aborted"):
             continue  # stale pooled socket: retry on a fresh connection
         raise NativeIOError(rc, "read")
+
+
+def _leg(name: str, bucket: str = "net"):
+    """One leg of a part write as a span under the caller's ``part``:
+    the names are the same on every plane."""
+    from lizardfs_tpu.runtime import tracing
+
+    return tracing.span(name, layer="wire", phase=name, bucket=bucket)
 
 
 def write_part_blocking(
@@ -752,12 +807,7 @@ def write_part_blocking(
     (the executor thread is otherwise unkillable while it streams from
     the caller's buffer); ``cell["finished"]`` is set when this thread
     has stopped touching ``payload``."""
-    from lizardfs_tpu.runtime import tracing
-
-    def leg(name: str, bucket: str = "net"):
-        return tracing.span(name, layer="wire", phase=name, bucket=bucket)
-
-    with leg("part_dial", "queue"):
+    with _leg("part_dial", "queue"):
         sock = _blocking_socket(addr, 60.0)
     if cell is not None:
         cell["sock"] = sock
@@ -766,7 +816,7 @@ def write_part_blocking(
             cell["finished"] = True
             raise NativeIOError(-1, "write (aborted)")
     try:
-        with leg("part_init"):
+        with _leg("part_init"):
             sock.sendall(
                 framing.encode(
                     m.CltocsWriteInit(
@@ -788,7 +838,7 @@ def write_part_blocking(
 
         fn = (_lib.lz_write_part_bulk if part_offset % MFSBLOCKSIZE == 0
               else _lib.lz_write_part)
-        with leg("part_data"):  # the C streamer: pieces and their acks
+        with _leg("part_data"):  # the C streamer: pieces and their acks
             rc = fn(
                 sock.fileno(), chunk_id,
                 buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
@@ -796,7 +846,7 @@ def write_part_blocking(
             )
         if rc != 0:
             raise NativeIOError(rc, "write")
-        with leg("part_end"):
+        with _leg("part_end"):
             sock.sendall(framing.encode(
                 m.CltocsWriteEnd(req_id=0, chunk_id=chunk_id)))
             end = _recv_message(sock)
@@ -913,8 +963,7 @@ def read_parts_gather_blocking(
         socks = []
         try:
             for i, addr in enumerate(addrs):
-                s = (POOL.acquire(addr) if attempt == 0
-                     else _blocking_socket(addr, 30.0))
+                s = POOL.acquire(addr, fresh=attempt == 1)
                 socks.append((addr, s))
                 reqs[i].fd = s.fileno()
                 reqs[i].chunk_id = chunk_id
@@ -946,8 +995,8 @@ def read_parts_gather_blocking(
                 continue  # stale pooled socket: redial everything once
             raise NativeIOError(bad, "parts gather")
         finally:
-            for _, s in socks:
-                POOL.discard(s)
+            for addr, s in socks:
+                POOL.discard(addr, s)
 
 
 def abort_parts_gather(cell: dict) -> None:
@@ -1144,8 +1193,7 @@ class PartsScatterSession:
                     if attempt == 0 and ring_mode:
                         s = RING_POOL.try_acquire(addr)
                     if s is None:
-                        s = (POOL.acquire(addr) if attempt == 0
-                             else _blocking_socket(addr, 60.0))
+                        s = POOL.acquire(addr, fresh=attempt == 1)
                     self._socks.append(s)
                 for i in range(len(self.part_ids)):
                     _send_write_init(
@@ -1164,8 +1212,8 @@ class PartsScatterSession:
                 self._setup_rings()
                 return
             except (ConnectionError, OSError, st.StatusError):
-                for s in self._socks:
-                    POOL.discard(s)
+                for addr, s in zip(self.unique_addrs, self._socks):
+                    POOL.discard(addr, s)
                 self._socks.clear()
                 self.cell.pop("socks", None)
                 if attempt == 1 or self.cell.get("aborted"):
@@ -1459,12 +1507,8 @@ class PartsScatterSession:
         self.cell["finished"] = True
 
     def close(self) -> None:
-        for s in self._socks:
-            shm_ring_drop(s)  # dead socket: its segment dies with it
-            try:
-                s.close()
-            except OSError:
-                pass
+        for addr, s in zip(self.unique_addrs, self._socks):
+            POOL.discard(addr, s)  # dead socket: its segment dies with it
         self._socks.clear()
         self._rings = []
         self._ring_staged.clear()
@@ -1513,26 +1557,31 @@ def _write_parts_scatter(
     for attempt in (0, 1):
         socks: list[tuple[tuple[str, int], socket.socket]] = []
         try:
-            for i, addr in enumerate(addrs):
-                s = (POOL.acquire(addr) if attempt == 0
-                     else _blocking_socket(addr, 60.0))
-                socks.append((addr, s))
-                _send_write_init(s, chunk_id, version, part_ids[i])
+            # the pool acquire: near zero on a hit, a dial on a miss
+            with _leg("part_dial", "queue"):
+                for addr in addrs:
+                    socks.append(
+                        (addr, POOL.acquire(addr, fresh=attempt == 1)))
             if cell is not None:
                 cell["socks"] = [s for _, s in socks]
                 if cell.get("aborted"):
                     raise NativeIOError(-1, "parts scatter (aborted)")
-            _recv_write_init_acks([s for _, s in socks])
+            with _leg("part_init"):
+                for i, (_, s) in enumerate(socks):
+                    _send_write_init(s, chunk_id, version, part_ids[i])
+                _recv_write_init_acks([s for _, s in socks])
             reqs, ptrs, lens = _marshal_part_reqs(
                 [s.fileno() for _, s in socks], chunk_id, 1, part_ids,
                 payloads, lengths,
             )
-            rc = _lib.lz_write_parts_scatter(
-                ctypes.cast(reqs, ctypes.c_void_p), n, ptrs, lens,
-                part_offset, 120_000,
-            )
+            with _leg("part_data"):  # every part's frame and its ack
+                rc = _lib.lz_write_parts_scatter(
+                    ctypes.cast(reqs, ctypes.c_void_p), n, ptrs, lens,
+                    part_offset, 120_000,
+                )
             if rc == 0:
-                _write_end_handshake([s for _, s in socks], chunk_id)
+                with _leg("part_end"):
+                    _write_end_handshake([s for _, s in socks], chunk_id)
                 for addr, s in socks:
                     POOL.release(addr, s)
                 socks.clear()
@@ -1550,5 +1599,5 @@ def _write_parts_scatter(
                 continue  # redial once (pool may hold staled sockets)
             raise
         finally:
-            for _, s in socks:
-                POOL.discard(s)
+            for addr, s in socks:
+                POOL.discard(addr, s)

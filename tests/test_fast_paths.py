@@ -322,6 +322,182 @@ async def test_parts_scatter_skips_chained_copies(tmp_path):
         await cluster.stop()
 
 
+async def test_send_parts_stands_aside_for_a_chained_part(
+    tmp_path, monkeypatch
+):
+    """One part with a second holder (a relay chain) and the whole
+    batch takes the per-part sends: the exchange's frames carry no
+    chain. The decision alone is driven: the per-part sender is a
+    recorder, and the exchange must not be called at all."""
+    if not native_io.parts_scatter_available():
+        pytest.skip("native parts scatter not built")
+    cluster = Cluster(tmp_path)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "chain.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        await c.pwrite(f.inode, 0, b"x" * (3 * B))
+        locs = (await c.chunk_info(f.inode, 0)).locations
+        assert len(locs) == 5
+
+        def never(*a, **k):
+            raise AssertionError("exchange tried with a chained part")
+
+        sent = []
+
+        async def record(chunk_id, version, holders, payload, length, **kw):
+            sent.append((len(holders), length, kw["part_offset"],
+                         kw["skip_throttle"], kw["cell"]))
+
+        monkeypatch.setattr(native_io, "write_parts_scatter_blocking", never)
+        monkeypatch.setattr(c, "_write_part", record)
+        pay = np.zeros(B, dtype=np.uint8)
+        parts = [([loc], pay, B) for loc in locs[:4]]
+        parts.append(([locs[4], locs[0]], pay, B))
+        cells: list[dict] = []
+        before = dict(c.op_counters)
+        await c._send_parts(7, 1, parts, 2 * B, cells)
+        assert [s[:4] for s in sent] == [(1, B, 2 * B, True)] * 4 + [
+            (2, B, 2 * B, True)]
+        assert [s[4] for s in sent] == cells  # an abort handle a send
+        assert c.op_counters == before
+    finally:
+        await cluster.stop()
+
+
+async def test_pwrite_reuses_pooled_sockets_through_a_restart(tmp_path):
+    """Two pwrites to one chunk dial each chunkserver once; a data
+    plane restarted between two more costs one redial of the exchange
+    and no error, and the call after it dials nothing again."""
+    if not native_io.parts_scatter_available():
+        pytest.skip("native parts scatter not built")
+    from lizardfs_tpu.chunkserver import native_serve
+
+    cluster = Cluster(tmp_path)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "pool.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        stripe = 3 * B
+        rows = [data_generator.generate(40 + i, stripe).tobytes()
+                for i in range(4)]
+        pool = native_io.POOL
+
+        async def write(i):
+            """Full stripe i (no read-back): (dials, hits) it cost."""
+            d0, h0 = pool.dials, pool.hits
+            await c.pwrite(f.inode, i * stripe, rows[i])
+            return pool.dials - d0, pool.hits - h0
+
+        assert await write(0) == (5, 0)
+        assert await write(1) == (0, 5)
+        for cs in cluster.chunkservers:
+            port = cs.data_server.port
+            await asyncio.to_thread(cs.data_server.stop)
+            cs.data_server = native_serve.DataPlaneServer(
+                [s.folder for s in cs.store.stores], cs.host, port)
+        # five stale hits, then the one redial of all five
+        assert await write(2) == (5, 5)
+        assert await write(3) == (0, 5)
+        assert c.op_counters.get("parts_scatter_write", 0) == 4
+        assert c.op_counters.get("parts_scatter_fallback", 0) == 0
+        c.cache.invalidate(f.inode)
+        assert await c.read_file(f.inode) == b"".join(rows)
+    finally:
+        await cluster.stop()
+
+
+def test_socket_pool_keeps_as_many_idle_as_were_out():
+    """The idle bound of an address is derived: what has been out at
+    once, never over ``MAX_IDLE``."""
+    listener = socket_mod.socket()
+    listener.bind(("127.0.0.1", 0))
+    listener.listen(64)
+    addr = listener.getsockname()
+    pool = native_io._SocketPool()
+    try:
+        for out_at_once, kept in ((2, 2), (6, 6), (10, 10), (3, 10),
+                                  (pool.MAX_IDLE + 3, pool.MAX_IDLE)):
+            socks = [pool.acquire(addr) for _ in range(out_at_once)]
+            for s in socks:
+                pool.release(addr, s)
+            assert len(pool._idle[addr]) == kept, out_at_once
+        # 2 dialled, 4 more for 6, 4 for 10, none for 3, the rest for 35
+        assert pool.dials == pool.MAX_IDLE + 3
+        assert pool.hits == 2 + 6 + 3 + 10
+        one = pool.acquire(addr, fresh=True)  # dials whatever is idle
+        assert pool.dials == pool.MAX_IDLE + 4
+        pool.discard(addr, one)
+        assert pool._out[addr] == 0
+    finally:
+        for s in pool._idle.pop(addr, []):
+            s.close()
+        listener.close()
+
+
+async def test_cancelled_pwrite_aborts_its_exchange(tmp_path, monkeypatch):
+    """A cancelled striped pwrite kills the worker of its exchange:
+    the cell is aborted and the thread stops reading the region."""
+    if not native_io.parts_scatter_available():
+        pytest.skip("native parts scatter not built")
+    import threading
+    import time as time_mod
+
+    cluster = Cluster(tmp_path)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "cancel.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        started = threading.Event()
+        seen: list[dict] = []
+        real = native_io._lib.lz_write_parts_scatter
+
+        def stall(reqs, n, ptrs, lens, part_offset, max_ms):
+            """The C streamer, held until the abort shuts its sockets
+            down: then the real call fails on them at once."""
+            started.set()
+            deadline = time_mod.monotonic() + 15.0
+            while time_mod.monotonic() < deadline and not any(
+                    cl.get("aborted") for cl in seen):
+                time_mod.sleep(0.01)
+            return real(reqs, n, ptrs, lens, part_offset, max_ms)
+
+        real_blocking = native_io.write_parts_scatter_blocking
+
+        def spy(addrs, cid, ver, pids, payloads, lengths, off=0, cell=None):
+            seen.append(cell)
+            return real_blocking(addrs, cid, ver, pids, payloads, lengths,
+                                 off, cell)
+
+        monkeypatch.setattr(native_io, "write_parts_scatter_blocking", spy)
+        monkeypatch.setattr(native_io._lib, "lz_write_parts_scatter", stall)
+        task = asyncio.ensure_future(c.pwrite(f.inode, 0, b"y" * (3 * B)))
+        await asyncio.wait_for(
+            asyncio.get_running_loop().run_in_executor(None, started.wait, 10),
+            15.0,
+        )
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+        assert len(seen) == 1 and seen[0].get("aborted")
+        deadline = time_mod.monotonic() + 10.0
+        while not seen[0].get("finished") and time_mod.monotonic() < deadline:
+            await asyncio.sleep(0.01)
+        assert seen[0].get("finished") is True, \
+            "the worker still streams from the cancelled pwrite's region"
+        assert "socks" not in seen[0]
+        monkeypatch.undo()
+        # the torn chunk is rewritten whole by the next call
+        await c.pwrite(f.inode, 0, b"z" * (3 * B))
+        c.cache.invalidate(f.inode)
+        assert await c.read_file(f.inode) == b"z" * (3 * B)
+    finally:
+        await cluster.stop()
+
+
 # --- write-abort path: zombie sender threads must die promptly --------------
 
 async def test_abort_write_scatter_unblocks_thread():

@@ -7,9 +7,12 @@ import pytest
 
 from lizardfs_tpu.client.cache import BlockCache, ReadaheadAdviser
 from lizardfs_tpu.constants import MFSBLOCKSIZE
-from lizardfs_tpu.utils import data_generator
+from lizardfs_tpu.core import geometry, native_io
+from lizardfs_tpu.runtime import faults
+from lizardfs_tpu.utils import data_generator, striping
 
-from tests.test_cluster import Cluster, EC_GOAL, XOR_GOAL
+from tests.test_cluster import Cluster, EC_GOAL, WIDE_EC_GOAL, XOR_GOAL
+from tests.test_write_phases import _find_part_files, _read_part
 
 
 def test_block_cache_lru_and_invalidate():
@@ -62,6 +65,69 @@ async def test_pwrite_random_offsets(tmp_path, goal):
             back = await c.read_file(f.inode)
             assert back == bytes(model), f"mismatch after patch {i} at {off}+{ln}"
     finally:
+        await cluster.stop()
+
+
+@pytest.mark.parametrize("mode", ["scatter", "fallback", "faults"])
+@pytest.mark.parametrize("goal", [WIDE_EC_GOAL, EC_GOAL, XOR_GOAL])
+@pytest.mark.asyncio
+async def test_striped_pwrite_fan_out(tmp_path, monkeypatch, goal, mode):
+    """A striped pwrite at a non-zero, stripe-unaligned offset (so the
+    RMW read-back runs) sends its region's parts as ONE pooled scatter
+    exchange; with the exchange made to fail the per-part sends serve;
+    with fault rules armed the exchange is not tried. In every case
+    the file and the part files on disk, parity included, are the
+    golden codec's."""
+    if not native_io.parts_scatter_available():
+        pytest.skip("native parts scatter not built")
+    cluster = Cluster(tmp_path, n_cs=12 if goal == WIDE_EC_GOAL else 6)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "fan.bin")
+        await c.setgoal(f.inode, goal)
+        d = {WIDE_EC_GOAL: 8, EC_GOAL: 3, XOR_GOAL: 3}[goal]
+        stripe = d * MFSBLOCKSIZE
+        size = 3 * stripe + 4321
+        model = bytearray(data_generator.generate(5, size).tobytes())
+        await c.write_file(f.inode, bytes(model))
+        if mode == "fallback":
+            def boom(*a, **k):
+                raise native_io.NativeIOError(5, "injected scatter failure")
+
+            monkeypatch.setattr(
+                native_io, "write_parts_scatter_blocking", boom)
+        elif mode == "faults":
+            # a rule that never fires: armed is what stands the
+            # uninstrumentable native exchange down
+            faults.arm("chunkserver:disk_pwrite error,after=1000000")
+        before = dict(c.op_counters)
+        off = stripe // 2 + 777  # inside stripe 0, ends inside stripe 1
+        patch = data_generator.generate(6, stripe).tobytes()
+        await c.pwrite(f.inode, off, patch)
+        model[off : off + len(patch)] = patch
+
+        def moved(name):
+            return c.op_counters.get(name, 0) - before.get(name, 0)
+
+        assert moved("parts_scatter_write") == (mode == "scatter")
+        assert moved("parts_scatter_fallback") == (mode == "fallback")
+        c.cache.invalidate(f.inode)
+        assert await c.read_file(f.inode) == bytes(model)
+        info = await c.chunk_info(f.inode, 0)
+        slice_type = geometry.ChunkPartType.from_id(
+            info.locations[0].part_id).type
+        golden = striping.split_chunk(
+            np.frombuffer(bytes(model), dtype=np.uint8), slice_type)
+        stored = _find_part_files(cluster, info.chunk_id)
+        assert len(stored) == slice_type.expected_parts
+        for part_id, path in stored.items():
+            part = geometry.ChunkPartType.from_id(part_id).part
+            data = np.frombuffer(_read_part(path)[0], dtype=np.uint8)
+            assert len(data) >= striping.part_length(slice_type, part, size)
+            assert (data == golden[part][: len(data)]).all(), part
+    finally:
+        faults.clear()
         await cluster.stop()
 
 

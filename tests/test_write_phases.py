@@ -228,20 +228,34 @@ def check_one_tree(spans, root_name, phases, delta, holder, backend):
 
 
 @pytest.mark.asyncio
-@pytest.mark.parametrize("backend", ["cpu", "tpu_interpret"])
+@pytest.mark.parametrize("backend,plane", [
+    ("cpu", "scatter"), ("tpu_interpret", "scatter"), ("cpu", "per_part"),
+])
 async def test_pwrite_yields_one_span_tree_that_sums_to_wall(
-    tmp_path, backend
+    tmp_path, monkeypatch, backend, plane
 ):
     """A 2 MiB pwrite of four full ec(8,4) stripes (the shape of the
     benchmark's streaming cell): root, getattr, lock, grant, encode
-    (split, and under it the boundary's four spans), send (a part span
-    a part, its legs under it) and commit, as one tree."""
+    (split, and under it the boundary's four spans), send (one part
+    span for the pooled scatter exchange, its legs under it; with the
+    exchange made to fail, a part span a part beside it) and commit,
+    as one tree."""
+    from lizardfs_tpu.core import native_io
+
+    if not native_io.parts_scatter_available():
+        pytest.skip("native parts scatter not built")
     payload = _payload(2 * 2**20)
     cluster = Cluster(tmp_path, n_cs=12)
     await cluster.start(health_interval=5.0)
     try:
         client = await cluster.client()
         client.encoder = encoder_for(backend)
+        if plane == "per_part":
+            def boom(*a, **k):
+                raise native_io.NativeIOError(5, "injected scatter failure")
+
+            monkeypatch.setattr(
+                native_io, "write_parts_scatter_blocking", boom)
         f = await client.create(1, f"tree_{backend}.bin")
         await client.setgoal(f.inode, EC84_GOAL)
         await client.pwrite(f.inode, 0, payload)  # chunk made, shape warm
@@ -263,19 +277,37 @@ async def test_pwrite_yields_one_span_tree_that_sums_to_wall(
         assert by_name["split"][0]["parent_id"] == \
             by_name["encode"][0]["span_id"]
         parts = by_name["part"]
-        assert len(parts) == 12
         assert all(p["parent_id"] == by_name["send"][0]["span_id"]
                    for p in parts)
-        assert {p["attrs"]["bytes"] for p in parts} == {262144}
-        assert {p["attrs"]["plane"] for p in parts} <= {"native", "asyncio"}
-        for p in parts:
-            legs = {s["name"] for s in spans
-                    if s["parent_id"] == p["span_id"]}
-            want = ({"hop", "part_dial", "part_init", "part_data",
-                     "part_end"} if p["attrs"]["plane"] == "native" else
-                    {"part_dial", "part_init", "part_data", "part_ack",
-                     "part_end"})
-            assert want <= legs, (p["attrs"], legs)
+        exchange = [p for p in parts if p["attrs"]["plane"] == "scatter"]
+        assert len(exchange) == 1
+        assert exchange[0]["attrs"]["parts"] == 12
+        assert exchange[0]["attrs"]["bytes"] == 12 * 262144
+        if plane == "scatter":
+            # ONE exchange: one worker, one hop, every leg once
+            assert len(parts) == 1
+            legs = [s["name"] for s in spans
+                    if s["parent_id"] == parts[0]["span_id"]]
+            assert sorted(legs) == ["hop", "part_data", "part_dial",
+                                    "part_end", "part_init"], legs
+            for leg in ("part", "hop", "part_init", "part_data",
+                        "part_end"):
+                assert d[f"{leg}_ms"] > 0.0, leg
+            assert d["part_ack_ms"] == 0.0
+        else:
+            parts = [p for p in parts if p is not exchange[0]]
+            assert len(parts) == 12
+            assert {p["attrs"]["bytes"] for p in parts} == {262144}
+            assert {p["attrs"]["plane"] for p in parts} <= {
+                "native", "asyncio"}
+            for p in parts:
+                legs = {s["name"] for s in spans
+                        if s["parent_id"] == p["span_id"]}
+                want = ({"hop", "part_dial", "part_init", "part_data",
+                         "part_end"} if p["attrs"]["plane"] == "native" else
+                        {"part_dial", "part_init", "part_data", "part_ack",
+                         "part_end"})
+                assert want <= legs, (p["attrs"], legs)
         # nested phases split their parents: never more than them
         assert d["split_ms"] <= d["encode_ms"]
         assert d["grant_srv_ms"] <= d["grant_ms"]
