@@ -49,7 +49,7 @@ from lizardfs_tpu.runtime import qos as qosmod
 from lizardfs_tpu.runtime import retry as retrymod
 from lizardfs_tpu.runtime import tracing
 from lizardfs_tpu.runtime.metrics import (
-    READ_PHASES, WRITE_PHASES, PhaseBreakdown,
+    READ_PHASES, WRITE_COUNTS, WRITE_PHASES, PhaseBreakdown,
 )
 from lizardfs_tpu.runtime.rpc import RpcConnection
 from lizardfs_tpu.utils import striping
@@ -229,13 +229,17 @@ class Client:
         self.cache.add_invalidate_listener(self._drop_locates)
         # per-phase busy-time accounting of a logical write, as a tree
         # (runtime.metrics.WRITE_PHASES): getattr, lock, grant, rmw_read,
-        # stage, throttle, encode, send, ack, commit at the top level;
-        # pipelined phases overlap, so the phase sum may exceed wall
-        # time — see runtime.metrics. "send" is the push cost (socket
-        # copy, or descriptor writes on the shm-ring plane); "ack" is
-        # the windowed path's completion wait (downstream
-        # backpressure). Through PR 23 "commit" held the grant too.
-        self.write_phases = PhaseBreakdown("client_write", WRITE_PHASES)
+        # rmw_patch, stage, throttle, encode, send, ack, commit at the
+        # top level; pipelined phases overlap, so the phase sum may
+        # exceed wall time — see runtime.metrics. "send" is the push
+        # cost (socket copy, or descriptor writes on the shm-ring
+        # plane); "ack" is the windowed path's completion wait
+        # (downstream backpressure). Through PR 23 "commit" held the
+        # grant too.
+        # Beside the times it counts the read-modify-write branch
+        # (runtime.metrics.WRITE_COUNTS; _count_write).
+        self.write_phases = PhaseBreakdown(
+            "client_write", WRITE_PHASES, WRITE_COUNTS)
         # the read-side twin (READ_PHASES): locate (master RPC), wait
         # (QoS throttle + retry backoff + shed waits), plan, waves (the
         # plan's parallel part reads: net, socket transfer incl. the
@@ -442,6 +446,13 @@ class Client:
     def _record(self, op: str, **kw) -> None:
         self.oplog.append((_time.time(), op, kw))
         self.op_counters[op] = self.op_counters.get(op, 0) + 1
+
+    def _count_write(self, name: str, n: int = 1) -> None:
+        """One of the write path's counts (WRITE_COUNTS): beside the
+        phase rows, for the interval a snapshot delta scopes, and in
+        ``op_counters``, for the mount's ``.stats``."""
+        self.write_phases.count(name, n)
+        self.op_counters[name] = self.op_counters.get(name, 0) + n
 
     async def _retry_transient(self, what: str, attempt_fn) -> None:
         """Run ``attempt_fn`` under the unified RetryPolicy
@@ -1478,7 +1489,7 @@ class Client:
             return
         wall_t0 = _time.perf_counter()
         root = tracing.span(
-            "pwrite", sink=self._write_op, bytes=len(data)
+            "pwrite", sink=self._write_op, bytes=len(data), offset=offset
         ).begin()
         try:
             # session scope for the RMW read-backs + native write path
@@ -1510,6 +1521,7 @@ class Client:
             session_ctx.__exit__(None, None, None)
             # closes the rep: the phases charged above stay attributable
             # against wall time for pwrite-heavy workloads too
+            self._count_write("payload_bytes", len(data))
             root.end()
 
     async def _pwrite_chunk(
@@ -1640,17 +1652,21 @@ class Client:
             # now would decode a mix of already-rewritten and stale
             # parts — reuse the region assembled BEFORE any of our
             # writes touched the wire, making retries write-only
-            region = rmw_cache["region"]
             await self._rmw_send(grant, slice_type, copies, lo_s,
-                                 nstripes, region)
+                                 rmw_cache["region"])
             return
-        region = np.zeros(nstripes * stripe_bytes, dtype=np.uint8)
+        # whole stripes, but for the chunk's last: where 1,024 blocks
+        # are no multiple of d it holds fewer than d, and no part has
+        # room for a block past them
+        region_len = min(nstripes * stripe_bytes,
+                         MFSCHUNKSIZE - region_start)
 
         chunk_len_old = min(max(old_length - ci * MFSCHUNKSIZE, 0), MFSCHUNKSIZE)
-        overlap_end = min(chunk_len_old, region_start + len(region))
+        overlap_end = min(chunk_len_old, region_start + region_len)
         fully_covered = (
             coff == region_start and coff + len(piece) >= overlap_end
         )
+        buf = None
         if overlap_end > region_start and not fully_covered:
             # read back the stripes being partially overwritten,
             # preferring healthy copies (same scoring as the read path)
@@ -1679,44 +1695,63 @@ class Client:
             )
             if not planner.is_readable(wanted):
                 raise ReadError("not enough parts for read-modify-write")
+            # the plan spans the whole region and part_sizes clips it to
+            # what is live: a part with nothing there gets a request of
+            # no bytes, which read_part_range answers without the wire
             plan = planner.build_plan(wanted, lo_s, nstripes, part_sizes)
-            with tracing.span("rmw_read", phase="rmw_read", bucket="net"):
+            asked = sum(op.request_size for op in plan.read_operations
+                        if op.wave == 0)
+            with tracing.span(
+                "rmw_read", phase="rmw_read", bucket="net", bytes=asked,
+                stripes=-(-(overlap_end - region_start) // stripe_bytes),
+            ):
                 buf = await execute_plan(
                     plan, grant.chunk_id, grant.version, by_part,
                     wave_timeout=self.wave_timeout,
                 )
-            bps = nstripes * MFSBLOCKSIZE
-            data_parts = {
-                wanted[i]: buf[i * bps : (i + 1) * bps] for i in range(d)
-            }
-            region[:] = striping.assemble_chunk(
-                data_parts, slice_type, len(region)
-            )
-        region[coff - region_start : coff - region_start + len(piece)] = piece
+            self._count_write("rmw_reads")
+            self._count_write("rmw_read_bytes", asked)
+        with tracing.span("rmw_patch", phase="rmw_patch", bucket="compute",
+                          bytes=region_len):
+            region = np.zeros(region_len, dtype=np.uint8)
+            if buf is not None:
+                bps = nstripes * MFSBLOCKSIZE
+                striping.assemble_chunk(
+                    {wanted[i]: buf[i * bps : (i + 1) * bps]
+                     for i in range(d)},
+                    slice_type, region_len, out=region,
+                )
+            region[coff - region_start : coff - region_start + len(piece)] = piece
         if rmw_cache is not None:
             # stash the patched region BEFORE any write hits the wire:
             # this is the one pre-torn snapshot a retry may trust
             rmw_cache["region"] = region
-        await self._rmw_send(grant, slice_type, copies, lo_s, nstripes,
-                             region)
+        await self._rmw_send(grant, slice_type, copies, lo_s, region)
 
     async def _rmw_send(self, grant, slice_type, copies, lo_s: int,
-                        nstripes: int, region: np.ndarray) -> None:
+                        region: np.ndarray) -> None:
         """Encode + rewrite the RMW region's parts (the write half of
         _rmw_striped, shared by first attempts and torn-state
-        retries)."""
+        retries). ``region`` starts at stripe ``lo_s`` and is whole
+        blocks long; each part is sent as far as the region reaches
+        into it."""
+        self._count_write("rmw_region_bytes", len(region))
         with tracing.span("encode", phase="encode", bucket="compute"):
             parts = await asyncio.to_thread(
                 striping.split_chunk, region, slice_type, self.encoder
             )
-        nbytes = nstripes * MFSBLOCKSIZE
+        part_offset = lo_s * MFSBLOCKSIZE
+        region_end = lo_s * slice_type.data_parts * MFSBLOCKSIZE + len(region)
+        lengths = {
+            p: striping.part_length(slice_type, p, region_end) - part_offset
+            for p in copies if p in parts
+        }
         send_cells: list[dict] = []
         try:
             await self._send_parts(
                 grant.chunk_id, grant.version,
-                [(locs, parts[p][:nbytes], nbytes)
-                 for p, locs in copies.items() if p in parts],
-                lo_s * MFSBLOCKSIZE, send_cells,
+                [(copies[p], parts[p][:n], n) for p, n in lengths.items()],
+                part_offset, send_cells,
             )
         finally:
             # a cancelled pwrite must not leave a worker streaming the

@@ -188,7 +188,11 @@ _BOUNDARY_PHASES = {
 WRITE_PHASES = {
     "getattr": None, "lock": None, "grant": None, "grant_srv": "grant",
     "wait": "grant",            # a BUSY shed's backoff, inside its RPC
-    "rmw_read": None, "stage": None, "throttle": None,
+    "rmw_read": None,
+    # a partial-stripe write's region: allocated, the read-back
+    # assembled into it, the caller's bytes laid over it
+    "rmw_patch": None,
+    "stage": None, "throttle": None,
     "encode": None, "split": "encode",
     **{k: v or "encode" for k, v in _BOUNDARY_PHASES.items()},
     "send": None, "part": "send", "hop": "part", "part_dial": "part",
@@ -199,6 +203,12 @@ WRITE_PHASES = {
     "waves": "rmw_read", "dial": "waves", "net": "waves",
     "decode": "rmw_read",
 }
+# What the write path counts beside its times (PhaseBreakdown.count):
+# pwrite calls that read stripes back, the live bytes they asked of the
+# chunkservers, the data bytes of the regions encoded and sent, and the
+# bytes the callers handed pwrite (charged where the rep closes).
+WRITE_COUNTS = ("rmw_reads", "rmw_read_bytes", "rmw_region_bytes",
+                "payload_bytes")
 READ_PHASES = {
     "locate": None, "locate_srv": "locate", "wait": None, "plan": None,
     # the plan's part reads, in parallel: net and dial sum over them
@@ -222,18 +232,22 @@ class PhaseBreakdown:
     legitimately exceeds wall time — the gap IS the overlap win. A
     phase the tree does not name is kept under its name and counted
     nowhere else. ``add`` is called from worker threads too and loses
-    no update. ``snapshot`` returns cumulative totals; subtract two
-    snapshots (:func:`phase_delta`) to scope the breakdown to a
+    no update. ``count`` counts what the operation did beside what it
+    took (calls of a branch, bytes); the names in ``counts`` read 0
+    until they are charged. ``snapshot`` returns cumulative totals,
+    times as ``<phase>_ms`` and counts under their own names; subtract
+    two snapshots (:func:`phase_delta`) to scope the breakdown to a
     measured interval (bench reps)."""
 
-    __slots__ = ("name", "phases", "totals_s", "wall_s", "self_s", "reps",
-                 "_lock")
+    __slots__ = ("name", "phases", "totals_s", "counts", "wall_s", "self_s",
+                 "reps", "_lock")
 
-    def __init__(self, name: str, phases):
+    def __init__(self, name: str, phases, counts=()):
         self.name = name
         self.phases = (dict(phases) if isinstance(phases, dict)
                        else {p: None for p in phases})
         self.totals_s = {p: 0.0 for p in self.phases}
+        self.counts = dict.fromkeys(counts, 0)
         self.wall_s = 0.0
         self.self_s = 0.0
         self.reps = 0
@@ -246,6 +260,10 @@ class PhaseBreakdown:
     def add(self, phase: str, seconds: float) -> None:
         with self._lock:
             self.totals_s[phase] = self.totals_s.get(phase, 0.0) + seconds
+
+    def count(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self.counts[name] = self.counts.get(name, 0) + n
 
     def add_wall(self, seconds: float, self_seconds: float | None = None) -> None:
         with self._lock:
@@ -260,6 +278,7 @@ class PhaseBreakdown:
             out["self_ms"] = round(self.self_s * 1e3, 2)
             out["wall_ms"] = round(self.wall_s * 1e3, 2)
             out["reps"] = self.reps
+            out.update(self.counts)
         return out
 
 
@@ -273,9 +292,10 @@ def top_level_ms(snapshot: dict, phases: dict) -> dict:
 
 def phase_delta(after: dict, before: dict) -> dict:
     """Elementwise ``after - before`` of two :meth:`PhaseBreakdown.snapshot`
-    dicts (same keys), rounded back to centi-ms."""
+    dicts (same keys): times rounded back to centi-ms, ``reps`` and the
+    counts exact."""
     return {
-        k: round(after[k] - before.get(k, 0), 2) if k != "reps"
+        k: round(after[k] - before.get(k, 0), 2) if k.endswith("_ms")
         else after[k] - before.get(k, 0)
         for k in after
     }

@@ -373,11 +373,16 @@ def _print_top(doc: dict) -> None:
             f"{mrow.get('p99_ms', 0.0):>8.1f}  "
             f"{hot_s}{('  trace ' + exemplar) if exemplar else ''}"
         )
-        phases = entry.get("read_phases")
-        if phases and phases.get("reps"):
-            # the top level of the read's span tree: a nested phase is
+        for key, tree, noun in (
+            ("read_phases", metrics_mod.READ_PHASES, "read"),
+            ("write_phases", metrics_mod.WRITE_PHASES, "write"),
+        ):
+            phases = entry.get(key)
+            if not (phases and phases.get("reps")):
+                continue
+            # the top level of the op's span tree: a nested phase is
             # never ranked against its own parent
-            busy = metrics_mod.top_level_ms(phases, metrics_mod.READ_PHASES)
+            busy = metrics_mod.top_level_ms(phases, tree)
             dom = max(busy, key=lambda k: busy[k]) if busy else "?"
             busy_s = " ".join(
                 f"{k}={v:.0f}ms" for k, v in sorted(
@@ -385,10 +390,22 @@ def _print_top(doc: dict) -> None:
                 ) if v > 0
             )
             print(
-                f"             `- read phases ({phases.get('reps', 0)} "
-                f"reads, wall {phases.get('wall_ms', 0.0):.0f}ms) "
+                f"             `- {noun} phases ({phases['reps']} "
+                f"{noun}s, wall {phases.get('wall_ms', 0.0):.0f}ms) "
                 f"dominant {dom}  {busy_s}"
             )
+            if phases.get("rmw_reads"):
+                # pwrite calls that read stripes back first, and what
+                # the branch moved beyond what it was handed
+                payload = phases.get("payload_bytes", 0)
+                extra = (phases.get("rmw_read_bytes", 0)
+                         + phases.get("rmw_region_bytes", 0) - payload)
+                print(
+                    f"                read-modify-write: {phases['rmw_reads']}"
+                    f" of {phases['reps']} writes read back, "
+                    f"{100.0 * extra / max(payload, 1):+.1f}% bytes beyond "
+                    "the payload"
+                )
         gw = entry.get("gateway")
         if gw and gw.get("protocol"):
             proto = gw.get("protocol") or []

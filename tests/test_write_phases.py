@@ -25,7 +25,9 @@ from lizardfs_tpu.client.client import Client
 from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.core import geometry
 from lizardfs_tpu.proto import messages as m
-from lizardfs_tpu.runtime.metrics import PhaseBreakdown, phase_delta
+from lizardfs_tpu.runtime.metrics import (
+    WRITE_COUNTS, WRITE_PHASES, PhaseBreakdown, phase_delta,
+)
 from lizardfs_tpu.utils import striping
 
 from tests.test_cluster import Cluster
@@ -326,6 +328,107 @@ async def test_pwrite_yields_one_span_tree_that_sums_to_wall(
             payload * 2
     finally:
         await cluster.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("call,live_blocks,stripes", [(1, 2, 12), (2, 1, 11)])
+async def test_partial_stripe_pwrite_reads_back_then_patches(
+    tmp_path, call, live_blocks, stripes
+):
+    """The second and the third of sequential 2 MiB pwrites at ec(3,2)
+    start 128 KiB and 64 KiB into a 192 KiB stripe: one tree whose
+    top level runs getattr, grant, rmw_read (the head stripe's live
+    blocks, the plan's waves under it), rmw_patch (the region), encode,
+    send, commit; it sums to the wall, and the branch counts itself."""
+    payload = _payload(2 * 2**20)
+    cluster = Cluster(tmp_path, n_cs=6)
+    await cluster.start(health_interval=5.0)
+    try:
+        client = await cluster.client()
+        f = await client.create(1, f"rmw_{call}.bin")
+        await client.setgoal(f.inode, EC32_GOAL)
+        for j in range(call):
+            await client.pwrite(f.inode, j * len(payload), payload)
+        client.trace_ring.clear()
+        before = client.write_phases.snapshot()
+        await client.pwrite(f.inode, call * len(payload), payload)
+        d = phase_delta(client.write_phases.snapshot(), before)
+        spans = client.trace_ring.dump()
+        check_one_tree(spans, "pwrite", client.write_phases, d, "encode",
+                       "cpu")
+        root = next(s for s in spans if s["name"] == "pwrite")
+        top = sorted((s for s in spans if s["parent_id"] == root["span_id"]),
+                     key=lambda s: s["t0"])
+        assert [s["name"] for s in top] == [
+            "getattr", "grant", "rmw_read", "rmw_patch", "encode", "send",
+            "commit"]
+        by_name = {s["name"]: s for s in top}
+        region = stripes * 3 * MFSBLOCKSIZE
+        assert by_name["rmw_read"]["attrs"] == {
+            "bytes": live_blocks * MFSBLOCKSIZE, "stripes": 1}
+        assert by_name["rmw_read"]["bucket"] == "net"
+        assert by_name["rmw_patch"]["attrs"] == {"bytes": region}
+        assert by_name["rmw_patch"]["bucket"] == "compute"
+        waves = [s for s in spans if s["name"] == "waves"]
+        assert [w["parent_id"] for w in waves] == [
+            by_name["rmw_read"]["span_id"]]
+        assert d["rmw_read_ms"] > 0.0 and d["rmw_patch_ms"] > 0.0
+        assert d["waves_ms"] <= d["rmw_read_ms"]
+        assert {n: d[n] for n in client.write_phases.counts} == {
+            "rmw_reads": 1, "rmw_read_bytes": live_blocks * MFSBLOCKSIZE,
+            "rmw_region_bytes": region, "payload_bytes": len(payload)}
+        client.cache.invalidate(f.inode)
+        assert await client.read_file(
+            f.inode, 0, (call + 1) * len(payload)) == payload * (call + 1)
+    finally:
+        await cluster.stop()
+
+
+def test_phase_breakdown_counts_beside_its_times():
+    """Counts ride the snapshot under their own names, read 0 until
+    charged, and a delta of two snapshots keeps them exact integers."""
+    pb = PhaseBreakdown("t", {"a": None, "b": "a"}, counts=("calls", "bytes"))
+    before = pb.snapshot()
+    assert before == {"a_ms": 0.0, "b_ms": 0.0, "self_ms": 0.0,
+                      "wall_ms": 0.0, "reps": 0, "calls": 0, "bytes": 0}
+    pb.add("a", 0.0015)
+    pb.count("calls")
+    pb.count("bytes", 3 * 2**31)
+    pb.add_wall(0.002, 0.0005)
+    d = phase_delta(pb.snapshot(), before)
+    assert d == {"a_ms": 1.5, "b_ms": 0.0, "self_ms": 0.5, "wall_ms": 2.0,
+                 "reps": 1, "calls": 1, "bytes": 3 * 2**31}
+    assert all(isinstance(d[k], int) for k in ("reps", "calls", "bytes"))
+    # a snapshot taken before the program had the count (an older
+    # client's pushed stats) subtracts as 0
+    assert phase_delta(pb.snapshot(), {"a_ms": 1.0})["calls"] == 1
+
+
+def test_top_renders_the_write_phases_and_the_rmw_counts(capsys):
+    """`lizardfs-admin top` names a session's dominant write phase and,
+    where its writers are not stripe-aligned, how many calls read back
+    and what the branch moved beyond the payload; a session whose
+    client predates the counts gets the phase line alone."""
+    from lizardfs_tpu.tools.admin_cli import _print_top
+
+    pb = PhaseBreakdown("client_write", WRITE_PHASES, WRITE_COUNTS)
+    pb.add("send", 0.6)
+    pb.add("rmw_read", 0.2)
+    pb.add("part", 5.0)  # nested: never ranked against send
+    for name, n in (("rmw_reads", 21), ("rmw_read_bytes", 2 * 2**20),
+                    ("rmw_region_bytes", 69568 * 1024),
+                    ("payload_bytes", 64 * 2**20)):
+        pb.count(name, n)
+    for _ in range(32):
+        pb.add_wall(0.03)
+    old = {k: v for k, v in pb.snapshot().items() if k not in WRITE_COUNTS}
+    _print_top({"sessions": {
+        "s1": {"info": "new", "write_phases": pb.snapshot()},
+        "s2": {"info": "old", "write_phases": old}}})
+    out = capsys.readouterr().out
+    assert out.count("write phases (32 writes, wall 960ms) dominant send") == 2
+    assert out.count("read-modify-write") == 1
+    assert "21 of 32 writes read back, +9.3% bytes beyond the payload" in out
 
 
 @pytest.mark.asyncio
