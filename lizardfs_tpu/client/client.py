@@ -1768,9 +1768,9 @@ class Client:
         ``part_offset`` of several parts of one chunk, each given as
         ``(holders, payload, length)``: ONE pooled scatter exchange
         (native_io.write_parts_scatter_blocking: one worker thread,
-        pooled sockets, every init sent before an ack is read, one
-        poll-driven C call for the frames and their acks, every end
-        sent before one is read) when every part has a single holder
+        pooled sockets, ONE poll-driven C call for the three legs:
+        every init and its status, every bulk frame and its ack, every
+        end and its status) when every part has a single holder
         (no relay chain), per-part sends otherwise or when the
         exchange fails. Shared by whole-chunk writes and the RMW
         region of a striped pwrite. ``send_cells`` receives the abort
@@ -1818,6 +1818,13 @@ class Client:
                 except (native_io.NativeIOError, OSError,
                         ConnectionError, st.StatusError):
                     self._record("parts_scatter_fallback")
+                finally:
+                    # what the worker observed: the three legs ran in
+                    # the one C call; pooled sockets had died under it
+                    if cell.get("native"):
+                        self._record("parts_scatter_native")
+                    if cell.get("redialled"):
+                        self._record("parts_scatter_redial")
             # bytes already charged above — per-part sends must not
             # pay again (and their throttle would pollute the timer)
             cells: list[dict] = [{} for _ in parts]

@@ -18,6 +18,7 @@ import os
 import socket
 import struct
 import threading
+import time
 import weakref
 from concurrent.futures import ThreadPoolExecutor
 
@@ -86,6 +87,20 @@ if _lib is not None:
             _lib.lz_write_parts_scatter.restype = ctypes.c_int
         except AttributeError:
             pass  # stale .so: multi-part write fast path stays off
+        try:
+            _lib.lz_write_parts_exchange.argtypes = [
+                ctypes.c_void_p, ctypes.c_uint32,
+                ctypes.POINTER(ctypes.c_char_p),
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.POINTER(ctypes.c_void_p),
+                ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint64,
+                ctypes.POINTER(ctypes.c_char_p),
+                ctypes.POINTER(ctypes.c_uint32), ctypes.c_uint32,
+                ctypes.POINTER(ctypes.c_uint64),
+            ]
+            _lib.lz_write_parts_exchange.restype = ctypes.c_int
+        except AttributeError:
+            pass  # stale .so: striped writes take the per-part sends
         try:
             _lib.lz_trace_set.argtypes = [ctypes.c_uint64]
             _lib.lz_trace_set.restype = None
@@ -1024,7 +1039,9 @@ abort_parts_scatter = abort_parts_gather
 
 
 def parts_scatter_available() -> bool:
-    return _lib is not None and hasattr(_lib, "lz_write_parts_scatter")
+    """The one-shot part exchange (lz_write_parts_exchange: WriteInit,
+    bulk and WriteEnd legs in one native call) is built."""
+    return _lib is not None and hasattr(_lib, "lz_write_parts_exchange")
 
 
 def parts_scatterv_available() -> bool:
@@ -1041,19 +1058,34 @@ def parts_scatterv_available() -> bool:
 SCATTER_NO_ACK = 1
 
 
-# shared building blocks of the two scatter-write paths (the one-shot
-# write_parts_scatter_blocking and the multi-segment PartsScatterSession):
-# a protocol change lands in exactly one place
+# building blocks of the two scatter-write paths. _write_init_frame,
+# _write_end_frame and _marshal_part_reqs serve both: a protocol change
+# lands in one place. _send_write_init, _recv_write_init_acks and
+# _write_end_handshake (the handshakes in Python framing) serve
+# PartsScatterSession.open() / finish() (connections shared by several
+# parts, one End a connection, once a chunk) and nothing else: the
+# one-shot write_parts_scatter_blocking hands its frames to
+# lz_write_parts_exchange, which runs both rounds in C.
 
 
-def _send_write_init(sock: socket.socket, chunk_id: int, version: int,
-                     part_id: int) -> None:
-    sock.sendall(framing.encode(m.CltocsWriteInit(
+def _write_init_frame(chunk_id: int, version: int, part_id: int) -> bytes:
+    """The WriteInit of a scatter write (no chain, the part exists),
+    the calling thread's trace id and the session id riding it."""
+    return framing.encode(m.CltocsWriteInit(
         req_id=1, chunk_id=chunk_id, version=version,
         part_id=part_id, chain=[], create=False,
         trace_id=_thread_trace_id(),
         session_id=accounting.wire_session(),
-    )))
+    ))
+
+
+def _write_end_frame(chunk_id: int) -> bytes:
+    return framing.encode(m.CltocsWriteEnd(req_id=0, chunk_id=chunk_id))
+
+
+def _send_write_init(sock: socket.socket, chunk_id: int, version: int,
+                     part_id: int) -> None:
+    sock.sendall(_write_init_frame(chunk_id, version, part_id))
 
 
 def _recv_write_init_acks(socks: list[socket.socket]) -> None:
@@ -1091,9 +1123,7 @@ def _marshal_part_reqs(
 
 def _write_end_handshake(socks: list[socket.socket], chunk_id: int) -> None:
     for s in socks:
-        s.sendall(framing.encode(
-            m.CltocsWriteEnd(req_id=0, chunk_id=chunk_id)
-        ))
+        s.sendall(_write_end_frame(chunk_id))
     for s in socks:
         end = _recv_message(s)
         if not isinstance(end, m.CstoclWriteStatus) or end.status != st.OK:
@@ -1516,6 +1546,16 @@ class PartsScatterSession:
         self.cell["finished"] = True
 
 
+# one deadline for the three legs of a one-shot exchange
+_EXCHANGE_MAX_MS = 120_000
+# lz_write_parts_exchange's failing leg -> (phase row, what the error says)
+_EXCHANGE_LEGS = (
+    ("part_init", "write init"),
+    ("part_data", "parts scatter write"),
+    ("part_end", "write end"),
+)
+
+
 def write_parts_scatter_blocking(
     addrs: list[tuple[str, int]],
     chunk_id: int,
@@ -1526,27 +1566,47 @@ def write_parts_scatter_blocking(
     part_offset: int = 0,
     cell: dict | None = None,
 ) -> None:
-    """Write n whole parts (one bulk frame + ack each) in ONE
-    poll-driven native exchange — the write-path mirror of
+    """Write n whole parts (WriteInit, one bulk frame + ack, WriteEnd
+    each) in ONE poll-driven native exchange — the write-path mirror of
     read_parts_gather_blocking: one executor thread and one C call
-    (which also runs the per-block CRC pass) replace n of each. The
-    WriteInit/WriteEnd handshakes stay in Python framing (they carry
-    the variable-length chain list). Raises NativeIOError on the first
-    failing part; the caller falls back to per-part writes. ``cell``
-    publishes the live sockets so abort_parts_scatter() can kill the
-    exchange from another thread; ``cell["finished"]`` marks when this
-    thread has stopped reading from ``payloads``."""
+    (lz_write_parts_exchange: three status rounds and the per-block CRC
+    pass, the GIL given up once) replace n of each. The init and end
+    frames are encoded here (proto/messages.py stays the wire format's
+    one source) and handed over as bytes; C parses only the statuses
+    and times the legs, which are laid under the caller's ``part`` span
+    as ``part_init`` / ``part_data`` / ``part_end`` after the call.
+    Raises NativeIOError on the first failing part, naming the leg; the
+    caller falls back to per-part writes. ``cell`` publishes the live
+    sockets so abort_parts_scatter() can kill the exchange from another
+    thread; ``cell["finished"]`` marks when this thread has stopped
+    reading from ``payloads``; ``cell["native"]`` says the three legs
+    ran in the one call and ``cell["redialled"]`` that fresh sockets
+    were dialled after pooled ones died."""
     n = len(addrs)
     assert n == len(part_ids) == len(payloads) == len(lengths)
+    if cell is None:
+        cell = {}
     try:
         _write_parts_scatter(
             addrs, chunk_id, version, part_ids, payloads, lengths,
             part_offset, cell,
         )
     finally:
-        if cell is not None:
-            cell.pop("socks", None)
-            cell["finished"] = True
+        cell.pop("socks", None)
+        cell["finished"] = True
+
+
+def _lay_exchange_legs(t0: float, leg_us) -> None:
+    """The legs the C call timed, as spans (and phase rows) under the
+    open ``part`` span, end to end from ``t0`` (a ``perf_counter``
+    reading taken before the call; both clocks are the steady one)."""
+    now = time.perf_counter()
+    for (name, _), us in zip(_EXCHANGE_LEGS, leg_us):
+        if not us:
+            break  # the exchange ended before this leg
+        t1 = min(t0 + us / 1e6, now)
+        _leg(name).begin(at=t0).end(at=t1)
+        t0 = t1
 
 
 def _write_parts_scatter(
@@ -1562,41 +1622,46 @@ def _write_parts_scatter(
                 for addr in addrs:
                     socks.append(
                         (addr, POOL.acquire(addr, fresh=attempt == 1)))
-            if cell is not None:
-                cell["socks"] = [s for _, s in socks]
-                if cell.get("aborted"):
-                    raise NativeIOError(-1, "parts scatter (aborted)")
-            with _leg("part_init"):
-                for i, (_, s) in enumerate(socks):
-                    _send_write_init(s, chunk_id, version, part_ids[i])
-                _recv_write_init_acks([s for _, s in socks])
+            cell["socks"] = [s for _, s in socks]
+            if attempt == 1:
+                cell["redialled"] = True
+            if cell.get("aborted"):
+                raise NativeIOError(-1, "parts scatter (aborted)")
+            inits = [_write_init_frame(chunk_id, version, part_id)
+                     for part_id in part_ids]
+            ends = [_write_end_frame(chunk_id)] * n
             reqs, ptrs, lens = _marshal_part_reqs(
                 [s.fileno() for _, s in socks], chunk_id, 1, part_ids,
                 payloads, lengths,
             )
-            with _leg("part_data"):  # every part's frame and its ack
-                rc = _lib.lz_write_parts_scatter(
-                    ctypes.cast(reqs, ctypes.c_void_p), n, ptrs, lens,
-                    part_offset, 120_000,
-                )
-            if rc == 0:
-                with _leg("part_end"):
-                    _write_end_handshake([s for _, s in socks], chunk_id)
+            leg_us = (ctypes.c_uint64 * 3)()
+            t0 = time.perf_counter()
+            leg = _lib.lz_write_parts_exchange(
+                ctypes.cast(reqs, ctypes.c_void_p), n,
+                (ctypes.c_char_p * n)(*inits),
+                (ctypes.c_uint32 * n)(*map(len, inits)),
+                ptrs, lens, part_offset,
+                (ctypes.c_char_p * n)(*ends),
+                (ctypes.c_uint32 * n)(*map(len, ends)),
+                _EXCHANGE_MAX_MS, leg_us,
+            )
+            _lay_exchange_legs(t0, leg_us)
+            if leg == 0:  # every init, bulk and End status read and OK
                 for addr, s in socks:
                     POOL.release(addr, s)
                 socks.clear()
+                cell["native"] = True
                 return
-            bad = next((int(r.rc) for r in reqs if r.rc != 0), -1)
-            if attempt == 0 and bad == -1 and not (
-                cell is not None and cell.get("aborted")
-            ):
+            # a server's refusal ends the round for parts still in
+            # flight (they read -1): the status is what failed it
+            rcs = [int(r.rc) for r in reqs if r.rc != 0] or [-1]
+            bad = max(rcs) if max(rcs) > 0 else min(rcs)
+            if attempt == 0 and bad == -1 and not cell.get("aborted"):
                 continue  # stale pooled sockets: redial everything once
-            raise NativeIOError(bad, "parts scatter write")
+            raise NativeIOError(bad, _EXCHANGE_LEGS[leg - 1][1])
         except (ConnectionError, OSError, st.StatusError):
-            if attempt == 0 and not (
-                cell is not None and cell.get("aborted")
-            ):
-                continue  # redial once (pool may hold staled sockets)
+            if attempt == 0 and not cell.get("aborted"):
+                continue  # the dial failed: once more, all fresh
             raise
         finally:
             for addr, s in socks:

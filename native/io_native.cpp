@@ -91,11 +91,13 @@ bool send_all(int fd, const uint8_t* buf, size_t len) {
     return true;
 }
 
-int64_t steady_ms() {
+int64_t steady_us() {
     struct timespec ts;
     clock_gettime(CLOCK_MONOTONIC, &ts);
-    return int64_t(ts.tv_sec) * 1000 + ts.tv_nsec / 1000000;
+    return int64_t(ts.tv_sec) * 1000000 + ts.tv_nsec / 1000;
 }
+
+int64_t steady_ms() { return steady_us() / 1000; }
 
 constexpr uint32_t kTypeWriteBulk = 1214;
 constexpr uint32_t kTypeWriteBulkPart = 1215;
@@ -157,13 +159,18 @@ void build_bulk_write_part_header(std::vector<uint8_t>& head,
     put32(head.data() + 37 + 4 * ncrcs, static_cast<uint32_t>(len));
 }
 
-// Validate a CstoclWriteStatus ack payload for a bulk write: returns
-// the peer status (0 = OK) or -2 on a protocol violation.
+// Validate a CstoclWriteStatus payload: returns the peer status
+// (0 = OK) or -2 on a protocol violation.
+int parse_write_status(const uint8_t* pay, uint32_t len) {
+    if (len < 18 || pay[0] != kProtoVersion) return -2;
+    return pay[17];
+}
+
+// The same for a bulk write's ack, which must echo its write_id.
 int parse_bulk_write_ack(const uint8_t* pay, uint32_t len,
                          uint32_t write_id) {
-    if (len < 18 || pay[0] != kProtoVersion) return -2;
-    if (get32(pay + 13) != write_id) return -2;
-    return pay[17];
+    if (len >= 18 && get32(pay + 13) != write_id) return -2;
+    return parse_write_status(pay, len);
 }
 
 bool recv_all(int fd, uint8_t* buf, size_t len) {
@@ -664,58 +671,54 @@ int lz_read_parts_gather(lz_part_req* parts, uint32_t d, uint32_t offset,
     return ret;
 }
 
-// Whole-stripe fan-out: stream n part payloads as bulk writes (one
-// 1214 frame + one ack each) over n already-initialized sockets in ONE
-// poll-driven loop. The mirror of lz_read_parts_gather for the write
-// path: one native call replaces n thread dispatches, and the
-// per-block CRC pass over every payload runs here, GIL-free. The
-// caller has already exchanged WriteInit on each socket and sends
-// WriteEnd afterwards.
+namespace {
+
+// What one socket sends in a status round: a frame head and the payload
+// that follows it on the wire (none for a handshake frame).
+struct RoundSend {
+    const uint8_t* head = nullptr;
+    uint64_t head_len = 0;
+    const uint8_t* pay = nullptr;
+    uint64_t pay_len = 0;
+};
+
+// One poll-driven round over n connected sockets: socket i sends
+// out[i] (head, then payload) and reads ONE CstoclWriteStatus back.
+// The shared body of the three legs of a part exchange (WriteInit,
+// bulk data, WriteEnd) and of lz_write_parts_scatter. Entries whose rc
+// the caller has already set nonzero are left alone and fail the round
+// before a byte is sent. match_write_id: the status must echo
+// parts[i].version (a bulk ack); a handshake's status carries no id.
 //
-// parts[i].version carries the bulk write_id for part i (reusing the
-// request struct; the chunk version is already bound by WriteInit).
-// parts[i].rc: 0 ok; >0 peer status; -1 socket; -2 protocol. Returns
-// 0 iff every part succeeded (caller falls back to per-part writes).
-int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
-                           const uint8_t* const* payloads,
-                           const uint64_t* lens, uint64_t part_offset,
-                           uint32_t max_ms) {
-    if (n == 0 || part_offset % kBlockSize != 0) return -1;
+// parts[i].rc: 0 ok; >0 peer status; -1 socket (or deadline); -2
+// protocol. Returns 0 iff every part's status arrived and is OK; the
+// round ends on the first failure (the others read -1).
+int status_round(lz_part_req* parts, uint32_t n, const RoundSend* out,
+                 bool match_write_id, int64_t deadline) {
+    constexpr int32_t kInFlight = 1 << 30;
     struct St {
         enum Phase { kSendHdr, kSendPay, kAckHdr, kAckPay, kDone };
         Phase phase = kSendHdr;
-        std::vector<uint8_t> head;
         uint64_t sent = 0;   // bytes sent in the current phase
         uint32_t got = 0;    // bytes received in the current phase
         uint32_t ack_len = 0;
         uint8_t small[32];
     };
     std::vector<St> st(n);
-    for (uint32_t i = 0; i < n; ++i) {
-        if (lens[i] > (64u << 20)) { parts[i].rc = -2; continue; }
-        build_bulk_write_header(st[i].head, parts[i].chunk_id,
-                                parts[i].version, part_offset,
-                                payloads[i], lens[i]);
-        parts[i].rc = 1 << 30;  // in flight
-    }
-    const int64_t deadline = steady_ms() + max_ms;
     uint32_t live = 0;
     bool failed = false;
     for (uint32_t i = 0; i < n; ++i) {
-        if (parts[i].rc == (1 << 30)) ++live;
-        else failed = true;
+        if (parts[i].rc != 0) { failed = true; continue; }
+        parts[i].rc = kInFlight;
+        ++live;
     }
     std::vector<pollfd> pfds(n);
     while (live && !failed) {
         const int64_t now = steady_ms();
-        if (now >= deadline) {
-            for (uint32_t i = 0; i < n; ++i)
-                if (parts[i].rc == (1 << 30)) parts[i].rc = -1;
-            break;
-        }
+        if (now >= deadline) break;
         int nfds = 0;
         for (uint32_t i = 0; i < n; ++i) {
-            if (parts[i].rc != (1 << 30)) continue;
+            if (parts[i].rc != kInFlight) continue;
             pfds[nfds].fd = parts[i].fd;
             pfds[nfds].events =
                 (st[i].phase <= St::kSendPay) ? POLLOUT : POLLIN;
@@ -738,23 +741,18 @@ int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
             if (i == n) continue;
             St& s = st[i];
             bool progress = true;
-            while (progress && parts[i].rc == (1 << 30)) {
+            while (progress && parts[i].rc == kInFlight) {
                 progress = false;
                 if (s.phase == St::kSendHdr || s.phase == St::kSendPay) {
-                    const uint8_t* src;
-                    uint64_t total;
-                    if (s.phase == St::kSendHdr) {
-                        src = s.head.data();
-                        total = s.head.size();
-                    } else {
-                        src = payloads[i];
-                        total = lens[i];
-                    }
+                    const bool hdr = s.phase == St::kSendHdr;
+                    const uint8_t* src = hdr ? out[i].head : out[i].pay;
+                    const uint64_t total =
+                        hdr ? out[i].head_len : out[i].pay_len;
                     while (s.sent < total) {
                         ssize_t w = ::send(parts[i].fd, src + s.sent,
                                            static_cast<size_t>(
                                                total - s.sent),
-                                           MSG_DONTWAIT);
+                                           MSG_DONTWAIT | MSG_NOSIGNAL);
                         if (w < 0) {
                             if (errno == EAGAIN || errno == EWOULDBLOCK)
                                 break;
@@ -764,27 +762,19 @@ int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
                         }
                         s.sent += static_cast<uint64_t>(w);
                     }
-                    if (parts[i].rc != (1 << 30)) break;
+                    if (parts[i].rc != kInFlight) break;
                     if (s.sent >= total) {
                         s.sent = 0;
-                        s.phase = (s.phase == St::kSendHdr)
-                                      ? St::kSendPay : St::kAckHdr;
+                        s.phase = hdr ? St::kSendPay : St::kAckHdr;
                         progress = true;
                     }
                     continue;
                 }
-                // ack phases
-                uint8_t* dst;
-                uint32_t want;
-                if (s.phase == St::kAckHdr) {
-                    dst = s.small;
-                    want = 8;
-                } else {
-                    dst = s.small;
-                    want = s.ack_len;
-                }
-                ssize_t r = ::recv(parts[i].fd, dst + s.got, want - s.got,
-                                   MSG_DONTWAIT);
+                // status phases: the 8-byte frame header, then its body
+                const uint32_t want =
+                    (s.phase == St::kAckHdr) ? 8 : s.ack_len;
+                ssize_t r = ::recv(parts[i].fd, s.small + s.got,
+                                   want - s.got, MSG_DONTWAIT);
                 if (r == 0) { parts[i].rc = -1; --live; break; }
                 if (r < 0) {
                     if (errno == EAGAIN || errno == EWOULDBLOCK) break;
@@ -806,15 +796,17 @@ int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
                     s.phase = St::kAckPay;
                     progress = true;
                 } else {
-                    parts[i].rc = parse_bulk_write_ack(
-                        s.small, s.ack_len, parts[i].version);
+                    parts[i].rc = match_write_id
+                        ? parse_bulk_write_ack(s.small, s.ack_len,
+                                               parts[i].version)
+                        : parse_write_status(s.small, s.ack_len);
                     s.phase = St::kDone;
                     --live;
                 }
             }
         }
         for (uint32_t i = 0; i < n; ++i) {
-            if (parts[i].rc != 0 && parts[i].rc != (1 << 30)) {
+            if (parts[i].rc != 0 && parts[i].rc != kInFlight) {
                 failed = true;
                 break;
             }
@@ -822,10 +814,119 @@ int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
     }
     int ret = 0;
     for (uint32_t i = 0; i < n; ++i) {
-        if (parts[i].rc == (1 << 30)) parts[i].rc = -1;
+        if (parts[i].rc == kInFlight) parts[i].rc = -1;
         if (parts[i].rc != 0) ret = -1;
     }
     return ret;
+}
+
+// The bulk leg: one 1214 frame (per-block CRCs computed here, GIL-free)
+// + one ack per part. parts[i].version carries the bulk write_id for
+// part i (reusing the request struct; the chunk version is already
+// bound by WriteInit).
+int scatter_bulk(lz_part_req* parts, uint32_t n,
+                 const uint8_t* const* payloads, const uint64_t* lens,
+                 uint64_t part_offset, int64_t deadline) {
+    if (n == 0 || part_offset % kBlockSize != 0) return -1;
+    std::vector<std::vector<uint8_t>> heads(n);
+    std::vector<RoundSend> out(n);
+    for (uint32_t i = 0; i < n; ++i) {
+        parts[i].rc = 0;
+        if (lens[i] > (64u << 20)) { parts[i].rc = -2; continue; }
+        build_bulk_write_header(heads[i], parts[i].chunk_id,
+                                parts[i].version, part_offset,
+                                payloads[i], lens[i]);
+        out[i].head = heads[i].data();
+        out[i].head_len = heads[i].size();
+        out[i].pay = payloads[i];
+        out[i].pay_len = lens[i];
+    }
+    return status_round(parts, n, out.data(), true, deadline);
+}
+
+// A handshake leg: one caller-built frame per socket, one status each.
+int handshake_round(lz_part_req* parts, uint32_t n,
+                    const uint8_t* const* frames, const uint32_t* lens,
+                    int64_t deadline) {
+    std::vector<RoundSend> out(n);
+    for (uint32_t i = 0; i < n; ++i) {
+        parts[i].rc = 0;
+        out[i].head = frames[i];
+        out[i].head_len = lens[i];
+    }
+    return status_round(parts, n, out.data(), false, deadline);
+}
+
+}  // namespace
+
+// Whole-stripe fan-out: stream n part payloads as bulk writes (one
+// 1214 frame + one ack each) over n already-initialized sockets in ONE
+// poll-driven loop. The mirror of lz_read_parts_gather for the write
+// path: one native call replaces n thread dispatches, and the
+// per-block CRC pass over every payload runs here, GIL-free. The
+// caller has already exchanged WriteInit on each socket and sends
+// WriteEnd afterwards (PartsScatterSession: one handshake pair a
+// chunk, many segments); a one-shot write runs all three legs in
+// lz_write_parts_exchange.
+//
+// parts[i].version carries the bulk write_id for part i.
+// parts[i].rc: 0 ok; >0 peer status; -1 socket; -2 protocol. Returns
+// 0 iff every part succeeded (caller falls back to per-part writes).
+int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
+                           const uint8_t* const* payloads,
+                           const uint64_t* lens, uint64_t part_offset,
+                           uint32_t max_ms) {
+    return scatter_bulk(parts, n, payloads, lens, part_offset,
+                        steady_ms() + max_ms);
+}
+
+// The whole one-shot part exchange in one call: over n connected
+// sockets, three status rounds with no caller code between them:
+//   leg 1  one caller-built WriteInit frame per socket, one status each;
+//   leg 2  the bulk leg of lz_write_parts_scatter;
+//   leg 3  one caller-built WriteEnd frame per socket, one status each.
+// A leg starts only when every status of the one before is in and OK:
+// no data goes to a server that has not accepted the init, and no End
+// is left unread for the socket's next user. The frames are the
+// caller's bytes (proto/messages.py stays the one source of the wire
+// format; the trace and session ids ride the init as the caller
+// encoded them); only the status replies are parsed here.
+//
+// One deadline (max_ms) covers the call. leg_us[0..2] receive each
+// leg's duration in microseconds on the steady clock (0 for a leg that
+// never started). Returns 0 iff every part passed every leg, else the
+// leg that failed (1, 2, 3) with parts[i].rc as lz_write_parts_scatter
+// sets it; bad arguments fail leg 2 (whose precondition they break)
+// with every rc -2, before a byte is sent.
+int lz_write_parts_exchange(lz_part_req* parts, uint32_t n,
+                            const uint8_t* const* init_frames,
+                            const uint32_t* init_lens,
+                            const uint8_t* const* payloads,
+                            const uint64_t* lens, uint64_t part_offset,
+                            const uint8_t* const* end_frames,
+                            const uint32_t* end_lens, uint32_t max_ms,
+                            uint64_t* leg_us) {
+    leg_us[0] = leg_us[1] = leg_us[2] = 0;
+    if (n == 0 || part_offset % kBlockSize != 0) {
+        for (uint32_t i = 0; i < n; ++i) parts[i].rc = -2;
+        return 2;
+    }
+    const int64_t deadline = steady_ms() + max_ms;
+    int64_t t0 = steady_us();
+    for (int leg = 1; leg <= 3; ++leg) {
+        const int rc =
+            leg == 1 ? handshake_round(parts, n, init_frames, init_lens,
+                                       deadline)
+            : leg == 2 ? scatter_bulk(parts, n, payloads, lens,
+                                      part_offset, deadline)
+                       : handshake_round(parts, n, end_frames, end_lens,
+                                         deadline);
+        const int64_t t1 = steady_us();
+        leg_us[leg - 1] = static_cast<uint64_t>(t1 - t0);
+        t0 = t1;
+        if (rc != 0) return leg;
+    }
+    return 0;
 }
 
 // --- windowed / vectored scatter writes ------------------------------------

@@ -78,6 +78,14 @@ int lz_write_parts_scatterv(lz_part_req* parts, uint32_t n,
                             const uint64_t* lens, uint64_t part_offset,
                             uint32_t max_ms, uint32_t flags);
 int lz_write_collect_acks(lz_part_req* parts, uint32_t n, uint32_t max_ms);
+int lz_write_parts_exchange(lz_part_req* parts, uint32_t n,
+                            const uint8_t* const* init_frames,
+                            const uint32_t* init_lens,
+                            const uint8_t* const* payloads,
+                            const uint64_t* lens, uint64_t part_offset,
+                            const uint8_t* const* end_frames,
+                            const uint32_t* end_lens, uint32_t max_ms,
+                            uint64_t* leg_us);
 int lz_read_parts_gather(lz_part_req* parts, uint32_t d, uint32_t offset,
                          uint32_t region_blocks, uint8_t* out,
                          uint32_t max_ms);
@@ -207,6 +215,80 @@ bool write_end(int sock, uint64_t chunk_id) {
     return type == 1212 && pay.size() >= 18 && pay[17] == 0;
 }
 
+// The frames the python caller hands lz_write_parts_exchange.
+std::vector<uint8_t> init_frame(uint64_t chunk_id, uint32_t version,
+                                uint32_t part_id) {
+    return lzwire::Msg(1210).u32(1).u64(chunk_id).u32(version).u32(part_id)
+        .u32(0 /*empty chain*/).u8(0 /*create=false: the part exists*/)
+        .frame();
+}
+
+std::vector<uint8_t> end_frame(uint64_t chunk_id) {
+    return lzwire::Msg(1213).u32(0).u64(chunk_id).frame();
+}
+
+// The one-shot exchange (init, bulk, end rounds in one call) over the
+// connections a finished write session left open, as pooled sockets
+// are: a rewrite of block 1 of every part, then a stale version that
+// every server must refuse in leg 1 before a byte of data leaves.
+void exchange_leg(lz_part_req* reqs, uint32_t d, uint64_t chunk_id,
+                  const uint8_t* const* payloads, std::vector<uint8_t>& data,
+                  uint64_t part_len) {
+    std::vector<std::vector<uint8_t>> inits, ends;
+    std::vector<const uint8_t*> ip(d), ep(d), pay(d);
+    std::vector<uint32_t> il(d), el(d);
+    std::vector<uint64_t> lens(d, kBlock);
+    auto point = [&](uint32_t version) {
+        inits.clear(); ends.clear();
+        for (uint32_t p = 0; p < d; ++p) {
+            inits.push_back(init_frame(chunk_id, version, p));
+            ends.push_back(end_frame(chunk_id));
+        }
+        for (uint32_t p = 0; p < d; ++p) {
+            ip[p] = inits[p].data(); il[p] = inits[p].size();
+            ep[p] = ends[p].data(); el[p] = ends[p].size();
+        }
+    };
+    // block 1 of part p takes block 0's bytes: chunk block d+p := block p
+    for (uint32_t p = 0; p < d; ++p) pay[p] = payloads[p];
+    uint64_t leg_us[3] = {7, 7, 7};
+    point(1);
+    for (uint32_t p = 0; p < d; ++p) reqs[p].rc = 0;
+    int leg = lz_write_parts_exchange(reqs, d, ip.data(), il.data(),
+                                      pay.data(), lens.data(), kBlock,
+                                      ep.data(), el.data(), 10000, leg_us);
+    if (leg != 0) fail("serve: exchange");
+    if (!leg_us[0] || !leg_us[1] || !leg_us[2]) fail("serve: exchange legs");
+    for (uint32_t p = 0; p < d; ++p) {
+        if (reqs[p].rc != 0) fail("serve: exchange part rc");
+        std::memcpy(data.data() + (uint64_t{d} + p) * kBlock,
+                    data.data() + uint64_t{p} * kBlock, kBlock);
+    }
+    std::vector<uint8_t> whole(d * part_len, 0);
+    if (lz_read_parts_gather(reqs, d, 0,
+                             static_cast<uint32_t>(d * part_len / kBlock),
+                             whole.data(), 10000) != 0)
+        fail("serve: gather after exchange");
+    else if (std::memcmp(whole.data(), data.data(), whole.size()) != 0)
+        fail("serve: exchange bytes");
+    point(99);
+    leg = lz_write_parts_exchange(reqs, d, ip.data(), il.data(), pay.data(),
+                                  lens.data(), kBlock, ep.data(), el.data(),
+                                  10000, leg_us);
+    if (leg != 1) fail("serve: stale-version exchange not refused at init");
+    if (leg_us[1] != 0 || leg_us[2] != 0) fail("serve: a leg after a refusal");
+    bool refused = false;
+    for (uint32_t p = 0; p < d; ++p) refused |= reqs[p].rc > 0;
+    if (!refused) fail("serve: exchange refusal carries no status");
+    // misaligned offset: refused before a frame is sent
+    point(1);
+    leg = lz_write_parts_exchange(reqs, d, ip.data(), il.data(), pay.data(),
+                                  lens.data(), 17, ep.data(), el.data(),
+                                  10000, leg_us);
+    if (leg != 2 || reqs[0].rc != -2) fail("serve: misaligned exchange");
+    for (uint32_t p = 0; p < d; ++p) reqs[p].version = 1;
+}
+
 void serve_roundtrip(int port, uint64_t chunk_id, uint32_t seed) {
     const uint32_t d = 3, bpp = 2;
     const uint64_t part_len = uint64_t{bpp} * kBlock;
@@ -269,6 +351,7 @@ void serve_roundtrip(int port, uint64_t chunk_id, uint32_t seed) {
             fail("serve: read_parts_gather");
         else if (std::memcmp(whole.data(), data.data(), whole.size()) != 0)
             fail("serve: gather bytes");
+        exchange_leg(reqs, d, chunk_id, payloads, data, part_len);
         // error paths: wrong version, out-of-bounds offset — must
         // return an error code, not touch bad memory
         if (lz_read_part(socks[0], chunk_id, 99, 0, 0, kBlock,

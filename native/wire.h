@@ -241,10 +241,16 @@ class Msg {
         for (uint32_t i = 0; i < n; ++i) u32(v[i]);
         return *this;
     }
-    bool send(int fd) {
+    // The finished frame (header filled in), for a caller that sends it
+    // itself.
+    const std::vector<uint8_t>& frame() {
         put32(buf_.data(), type_);
         put32(buf_.data() + 4, static_cast<uint32_t>(buf_.size() - 8));
-        return send_all(fd, buf_.data(), buf_.size());
+        return buf_;
+    }
+    bool send(int fd) {
+        const std::vector<uint8_t>& f = frame();
+        return send_all(fd, f.data(), f.size());
     }
 
   private:

@@ -14,15 +14,23 @@ mirror the mount's read-task cancellation (src/mount/readdata.cc).
 
 import asyncio
 import socket as socket_mod
+import struct
+import time as _time
+import zlib
 
 import numpy as np
 import pytest
 
 from lizardfs_tpu.constants import MFSBLOCKSIZE, MFSCHUNKSIZE
 from lizardfs_tpu.core import native, native_io
+from lizardfs_tpu.proto import framing, messages as m
+from lizardfs_tpu.proto import status as st
+from lizardfs_tpu.runtime import accounting
+from lizardfs_tpu.runtime.metrics import phase_delta
 from lizardfs_tpu.utils import data_generator, striping
 
 from tests.test_cluster import EC_GOAL, Cluster
+from tests.test_write_phases import _find_part_files, _read_part
 
 pytestmark = pytest.mark.asyncio
 
@@ -453,17 +461,17 @@ async def test_cancelled_pwrite_aborts_its_exchange(tmp_path, monkeypatch):
         await c.setgoal(f.inode, EC_GOAL)
         started = threading.Event()
         seen: list[dict] = []
-        real = native_io._lib.lz_write_parts_scatter
+        real = native_io._lib.lz_write_parts_exchange
 
-        def stall(reqs, n, ptrs, lens, part_offset, max_ms):
-            """The C streamer, held until the abort shuts its sockets
+        def stall(*args):
+            """The C exchange, held until the abort shuts its sockets
             down: then the real call fails on them at once."""
             started.set()
             deadline = time_mod.monotonic() + 15.0
             while time_mod.monotonic() < deadline and not any(
                     cl.get("aborted") for cl in seen):
                 time_mod.sleep(0.01)
-            return real(reqs, n, ptrs, lens, part_offset, max_ms)
+            return real(*args)
 
         real_blocking = native_io.write_parts_scatter_blocking
 
@@ -473,7 +481,7 @@ async def test_cancelled_pwrite_aborts_its_exchange(tmp_path, monkeypatch):
                                  off, cell)
 
         monkeypatch.setattr(native_io, "write_parts_scatter_blocking", spy)
-        monkeypatch.setattr(native_io._lib, "lz_write_parts_scatter", stall)
+        monkeypatch.setattr(native_io._lib, "lz_write_parts_exchange", stall)
         task = asyncio.ensure_future(c.pwrite(f.inode, 0, b"y" * (3 * B)))
         await asyncio.wait_for(
             asyncio.get_running_loop().run_in_executor(None, started.wait, 10),
@@ -1064,5 +1072,424 @@ async def test_shm_init_refused_for_remote_peers(tmp_path):
         assert isinstance(ack, m.CstoclWriteStatus)
         assert ack.status == st.EINVAL, "remote ShmInit must be refused"
         assert "mm" not in shm_state, "remote peer mapped a segment"
+    finally:
+        await cluster.stop()
+
+
+# --- the one-shot part exchange: three legs in one native call -------------
+
+LEGS = ("init", "data", "end")
+_LEG_OF_TYPE = {m.CltocsWriteInit.MSG_TYPE: "init",
+                m.CltocsWriteBulk.MSG_TYPE: "data",
+                m.CltocsWriteEnd.MSG_TYPE: "end"}
+_WHAT = {"init": "write init", "data": "parts scatter write",
+         "end": "write end"}
+
+
+class _Peers:
+    """Scripted chunkserver stand-ins on one listener: a connection is
+    one part's socket (it says which in its WriteInit). Every frame is
+    recorded per part, byte for byte, and answered as ``script(part_id,
+    leg)`` says: ``"ok"``, a status code, ``"stall"`` (never, until the
+    test is over) or a float (answer after that many seconds).
+    ``events`` is the order the loop saw things in."""
+
+    def __init__(self, script=lambda part_id, leg: "ok"):
+        self.script = script
+        self.wire: dict[int, bytearray] = {}
+        self.events: list[tuple[str, int]] = []
+        self.stalled = asyncio.Event()
+        self.over = asyncio.Event()
+        self.conns = 0
+
+    async def __aenter__(self):
+        self.server = await asyncio.start_server(
+            self._serve, "127.0.0.1", 0)
+        self.addr = ("127.0.0.1",
+                     self.server.sockets[0].getsockname()[1])
+        return self
+
+    async def __aexit__(self, *exc):
+        self.over.set()
+        # pooled sockets first: wait_closed() waits for their handlers
+        for s in native_io.POOL._idle.pop(self.addr, []):
+            s.close()
+        self.server.close()
+        await self.server.wait_closed()
+
+    async def _serve(self, reader, writer):
+        self.conns += 1
+        part_id = None
+        try:
+            while True:
+                head = await reader.readexactly(8)
+                msg_type, length = struct.unpack(">II", head)
+                body = await reader.readexactly(length)
+                msg = framing.decode(msg_type, body)
+                leg = _LEG_OF_TYPE[msg_type]
+                if leg == "init":
+                    part_id = msg.part_id
+                self.wire.setdefault(part_id, bytearray()).extend(
+                    head + body)
+                self.events.append((leg + "_seen", part_id))
+                act = self.script(part_id, leg)
+                if act == "stall":
+                    self.stalled.set()
+                    await self.over.wait()
+                    return
+                if isinstance(act, float):
+                    await asyncio.sleep(act)
+                wid = msg.write_id if leg == "data" else 0
+                writer.write(framing.encode(m.CstoclWriteStatus(
+                    req_id=msg.req_id, chunk_id=msg.chunk_id, write_id=wid,
+                    status=st.OK if act == "ok" or isinstance(act, float)
+                    else act)))
+                self.events.append((leg + "_answered", part_id))
+                await writer.drain()
+        except (asyncio.IncompleteReadError, ConnectionError):
+            pass
+        finally:
+            writer.close()
+
+    def idle(self) -> int:
+        return len(native_io.POOL._idle.get(self.addr, []))
+
+    def out(self) -> int:
+        return native_io.POOL._out.get(self.addr, 0)
+
+
+def _exchange(peers, payloads, lengths, cell, part_offset=0,
+              chunk_id=77, version=3, trace=None):
+    """write_parts_scatter_blocking against the stand-ins, on the
+    executor (the loop stays free to play the servers)."""
+    n = len(payloads)
+    args = (native_io.write_parts_scatter_blocking, [peers.addr] * n,
+            chunk_id, version, list(range(1, n + 1)), payloads, lengths,
+            part_offset, cell)
+    if trace is not None:
+        args = (native_io._call_under_trace, trace, 0) + args
+    return asyncio.get_running_loop().run_in_executor(
+        native_io.EXECUTOR, *args)
+
+
+def _skip_without_exchange():
+    if not native_io.parts_scatter_available():
+        pytest.skip("native part exchange not built")
+
+
+async def test_exchange_puts_the_parents_frames_on_the_wire(monkeypatch):
+    """Per socket, byte for byte: the WriteInit Python encodes (trace id
+    and session id riding it), the bulk frame with the golden CRCs, the
+    WriteEnd, in that order; and no leg starts before every status of
+    the one before is in (one server answers its init and its bulk ack
+    late: nobody's data, nobody's End, overtakes it)."""
+    _skip_without_exchange()
+    monkeypatch.setattr(accounting, "_PROCESS_SESSION", 0x5E55)
+    trace = 0x1234_5678_9ABC
+    # lengths differ per part, as an RMW region ending inside a chunk
+    lengths = [3 * B, 2 * B + 100, B]
+    payloads = [np.frombuffer(
+        data_generator.generate(70 + i, 3 * B).tobytes(), dtype=np.uint8)
+        for i in range(3)]
+    late = {(2, "init"): 0.15, (1, "data"): 0.15}
+    async with _Peers(lambda p, leg: late.get((p, leg), "ok")) as peers:
+        cell: dict = {}
+        await asyncio.wait_for(
+            _exchange(peers, payloads, lengths, cell, part_offset=2 * B,
+                      trace=trace), 20.0)
+        assert cell.get("native") is True and "redialled" not in cell
+        assert cell["finished"] is True and "socks" not in cell
+        for i, part_id in enumerate((1, 2, 3)):
+            data = payloads[i][:lengths[i]].tobytes()
+            want = framing.encode(m.CltocsWriteInit(
+                req_id=1, chunk_id=77, version=3, part_id=part_id,
+                chain=[], create=False, trace_id=trace,
+                session_id=0x5E55,
+            )) + framing.encode(m.CltocsWriteBulk(
+                req_id=1, chunk_id=77, write_id=1, part_offset=2 * B,
+                crcs=[zlib.crc32(data[o:o + B])
+                      for o in range(0, len(data), B)],
+                data=data,
+            )) + framing.encode(m.CltocsWriteEnd(req_id=0, chunk_id=77))
+            assert bytes(peers.wire[part_id]) == want, f"part {part_id}"
+        order = [e for e, _ in peers.events]
+        for before, after in (("init_answered", "data_seen"),
+                              ("data_answered", "end_seen")):
+            last = max(i for i, e in enumerate(order) if e == before)
+            first = min(i for i, e in enumerate(order) if e == after)
+            assert last < first, f"a {after} before the last {before}"
+        # a clean End: all three sockets went back to the pool
+        assert (peers.idle(), peers.out(), peers.conns) == (3, 0, 3)
+
+
+@pytest.mark.parametrize("leg", LEGS)
+async def test_exchange_refusal_names_its_leg(leg):
+    """One server's refusal in a leg raises its status under the leg's
+    own name (the per-part fallback keys on nothing else), though the
+    round ends with the other parts still in flight (they answer that
+    leg late), and no socket of the exchange goes back to the pool."""
+    _skip_without_exchange()
+    payloads = [np.zeros(B, dtype=np.uint8) for _ in range(3)]
+
+    def refuse(part_id, at):
+        if at != leg:
+            return "ok"
+        return st.ENOSPC if part_id == 2 else 0.3
+    async with _Peers(refuse) as peers:
+        cell: dict = {}
+        with pytest.raises(native_io.NativeIOError) as e:
+            await asyncio.wait_for(
+                _exchange(peers, payloads, [B] * 3, cell), 20.0)
+        assert e.value.code == st.ENOSPC
+        assert _WHAT[leg] in str(e.value)
+        assert (peers.idle(), peers.out()) == (0, 0)
+        assert "native" not in cell and "redialled" not in cell
+        assert cell["finished"] is True
+        later = LEGS[LEGS.index(leg) + 1:]
+        assert not [e for e in peers.events if e[0][:-5] in later], \
+            "a frame of a later leg left after the refusal"
+
+
+@pytest.mark.parametrize("native_plane", [True, False],
+                         ids=["native-plane", "asyncio-plane"])
+async def test_exchange_init_refused_by_a_chunkserver(tmp_path, native_plane):
+    """Both chunkserver planes answer the one call's frames: a stale
+    chunk version is refused at the init (WRONG_VERSION from every
+    server), and the right one then writes through the same call."""
+    _skip_without_exchange()
+    cluster = Cluster(tmp_path, native_data_plane=native_plane)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "refused.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        await c.pwrite(f.inode, 0, b"a" * (3 * B))
+        info = await c.chunk_info(f.inode, 0)
+        addrs = [(loc.addr.host, loc.addr.port) for loc in info.locations]
+        part_ids = [loc.part_id for loc in info.locations]
+        payloads = [np.full(B, 9, dtype=np.uint8) for _ in addrs]
+        pool = native_io.POOL
+        for s in [s for a in addrs for s in pool._idle.pop(a, [])]:
+            s.close()
+        with pytest.raises(native_io.NativeIOError) as e:
+            await native_io.run(
+                native_io.write_parts_scatter_blocking, addrs,
+                info.chunk_id, info.version + 7, part_ids, payloads,
+                [B] * len(addrs), 0, {})
+        assert e.value.code == st.WRONG_VERSION
+        assert "write init" in str(e.value)
+        assert not any(pool._idle.get(a) for a in addrs)
+        cell: dict = {}
+        await native_io.run(
+            native_io.write_parts_scatter_blocking, addrs, info.chunk_id,
+            info.version, part_ids, payloads, [B] * len(addrs), 0, cell)
+        assert cell.get("native") is True
+        assert all(len(pool._idle.get(a, [])) == 1 for a in addrs)
+    finally:
+        await cluster.stop()
+
+
+async def test_init_refusal_falls_back_to_per_part_sends(
+    tmp_path, monkeypatch
+):
+    """One chunkserver refuses one init: the exchange raises that
+    status as "write init" with every socket discarded, ``_send_parts``
+    counts the fallback and sends per part, and what lands on the
+    disks is the golden split of the bytes (utils/striping.py)."""
+    _skip_without_exchange()
+    from lizardfs_tpu.chunkserver.chunk_store import ChunkStoreError
+    from lizardfs_tpu.core import geometry
+
+    cluster = Cluster(tmp_path, native_data_plane=False)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "refuse1.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        victim = cluster.chunkservers[2]
+        real_require = victim.store.require
+        refused = []
+
+        def require_once(chunk_id, version, part_id):
+            if not refused:
+                refused.append(part_id)
+                raise ChunkStoreError(st.ENOSPC, "injected")
+            return real_require(chunk_id, version, part_id)
+
+        monkeypatch.setattr(victim.store, "require", require_once)
+        raised = []
+        real_blocking = native_io.write_parts_scatter_blocking
+
+        def spy(addrs, *args):
+            try:
+                return real_blocking(addrs, *args)
+            except native_io.NativeIOError as e:
+                raised.append((e, list(addrs)))
+                raise
+
+        monkeypatch.setattr(native_io, "write_parts_scatter_blocking", spy)
+        payload = data_generator.generate(91, 6 * B + 4321).tobytes()
+        await c.pwrite(f.inode, 0, payload)
+        assert len(refused) == 1 and len(raised) == 1
+        err, addrs = raised[0]
+        assert err.code == st.ENOSPC and "write init" in str(err)
+        pool = native_io.POOL
+        assert not any(pool._idle.get(a) for a in addrs), "a socket pooled"
+        assert all(pool._out.get(a, 0) == 0 for a in addrs)
+        assert c.op_counters.get("parts_scatter_fallback", 0) == 1
+        assert c.op_counters.get("parts_scatter_write", 0) == 0
+        assert c.op_counters.get("parts_scatter_native", 0) == 0
+        c.cache.invalidate(f.inode)
+        assert bytes(await c.read_file(f.inode)) == payload
+        files = _find_part_files(
+            cluster, (await c.chunk_info(f.inode, 0)).chunk_id)
+        assert len(files) == 5
+        slice_type = geometry.ChunkPartType.from_id(next(iter(files))).type
+        golden = striping.split_chunk(
+            np.frombuffer(payload, dtype=np.uint8), slice_type)
+        for part_id, path in files.items():
+            part = geometry.ChunkPartType.from_id(part_id).part
+            body, _ = _read_part(path)
+            got = np.frombuffer(body, dtype=np.uint8)
+            assert got.size and np.array_equal(
+                got, golden[part][:got.size]), f"part {part}"
+    finally:
+        await cluster.stop()
+
+
+async def test_exchange_redials_once_after_a_peer_restart(tmp_path):
+    """One chunkserver's data plane restarts under a pooled socket: the
+    stale socket shows inside the C call's init leg as a socket error,
+    the exchange redials all five once and succeeds, and the client
+    counts exactly that."""
+    _skip_without_exchange()
+    from lizardfs_tpu.chunkserver import native_serve
+
+    cluster = Cluster(tmp_path)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "redial.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        stripe = 3 * B
+        rows = [data_generator.generate(50 + i, stripe).tobytes()
+                for i in range(3)]
+        await c.pwrite(f.inode, 0, rows[0])
+        assert c.op_counters.get("parts_scatter_redial", 0) == 0
+        holder = (await c.chunk_info(f.inode, 0)).locations[0].addr.port
+        cs = next(cs for cs in cluster.chunkservers
+                  if cs.data_server.port == holder)
+        await asyncio.to_thread(cs.data_server.stop)
+        cs.data_server = native_serve.DataPlaneServer(
+            [s.folder for s in cs.store.stores], cs.host, holder)
+        pool = native_io.POOL
+        d0 = pool.dials
+        await c.pwrite(f.inode, stripe, rows[1])
+        assert pool.dials - d0 == 5, "the redial dials every part afresh"
+        await c.pwrite(f.inode, 2 * stripe, rows[2])
+        assert pool.dials - d0 == 5
+        assert c.op_counters.get("parts_scatter_redial", 0) == 1
+        assert c.op_counters.get("parts_scatter_fallback", 0) == 0
+        assert (c.op_counters["parts_scatter_native"]
+                == c.op_counters["parts_scatter_write"] == 3)
+        c.cache.invalidate(f.inode)
+        assert await c.read_file(f.inode) == b"".join(rows)
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.parametrize("leg", LEGS)
+async def test_abort_parts_scatter_during_each_leg(leg):
+    """A server that goes silent in any of the three legs holds the C
+    call in its poll; abort_parts_scatter() from another thread shuts
+    the sockets, the call returns at once, the cell is finished and
+    nothing is pooled (and no redial: the cell says aborted)."""
+    _skip_without_exchange()
+    payloads = [np.zeros(2 * B, dtype=np.uint8) for _ in range(3)]
+    silent = lambda p, l: "stall" if (p, l) == (3, leg) else "ok"  # noqa: E731
+    async with _Peers(silent) as peers:
+        cell: dict = {"submitted": True}
+        fut = _exchange(peers, payloads, [2 * B] * 3, cell)
+        await asyncio.wait_for(peers.stalled.wait(), 10.0)
+        await asyncio.sleep(0.05)  # the worker is inside the C call
+        assert not fut.done() and len(cell["socks"]) == 3
+        t0 = _time.monotonic()
+        native_io.abort_parts_scatter(cell)
+        with pytest.raises(native_io.NativeIOError) as e:
+            await asyncio.wait_for(fut, 10.0)
+        assert _time.monotonic() - t0 < 5.0, "abort did not end the call"
+        assert e.value.code == -1 and _WHAT[leg] in str(e.value)
+        assert cell["finished"] is True and "socks" not in cell
+        assert "redialled" not in cell and peers.conns == 3
+        assert (peers.idle(), peers.out()) == (0, 0)
+
+
+async def test_exchange_end_never_answered_hits_the_deadline(monkeypatch):
+    """A server that takes the data and never answers the End: the one
+    deadline of the call ends it (twice: pooled sockets that die are
+    redialled once), it raises as "write end", and not one socket goes
+    back to the pool with an End unread."""
+    _skip_without_exchange()
+    monkeypatch.setattr(native_io, "_EXCHANGE_MAX_MS", 300)
+    payloads = [np.zeros(B, dtype=np.uint8) for _ in range(3)]
+    silent = lambda p, l: "stall" if (p, l) == (1, "end") else "ok"  # noqa: E731
+    async with _Peers(silent) as peers:
+        cell: dict = {}
+        t0 = _time.monotonic()
+        with pytest.raises(native_io.NativeIOError) as e:
+            await asyncio.wait_for(
+                _exchange(peers, payloads, [B] * 3, cell), 20.0)
+        took = _time.monotonic() - t0
+        assert 0.55 < took < 10.0, took
+        assert e.value.code == -1 and "write end" in str(e.value)
+        assert cell.get("redialled") is True and "native" not in cell
+        assert peers.conns == 6
+        assert (peers.idle(), peers.out()) == (0, 0)
+
+
+@pytest.mark.parametrize("native_plane", [True, False],
+                         ids=["native-plane", "asyncio-plane"])
+async def test_exchange_legs_charge_the_phase_rows(tmp_path, native_plane):
+    """The legs are timed inside the C call and laid under ``part``
+    afterwards: after one pwrite ``write_phases`` holds ``part_init``,
+    ``part_data`` and ``part_end`` above zero, together no more than
+    ``part``, and the exchange counts as native."""
+    _skip_without_exchange()
+    cluster = Cluster(tmp_path, native_data_plane=native_plane)
+    await cluster.start()
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "legs.bin")
+        await c.setgoal(f.inode, EC_GOAL)
+        await c.pwrite(f.inode, 0, b"w" * (3 * B))  # dials, warms
+        before = c.write_phases.snapshot()
+        counters = dict(c.op_counters)
+        c.trace_ring.clear()
+        await c.pwrite(f.inode, 3 * B, b"x" * (6 * B))
+        row = phase_delta(c.write_phases.snapshot(), before)
+        assert row["reps"] == 1
+        legs = [row[f"{leg}_ms"]
+                for leg in ("part_init", "part_data", "part_end")]
+        assert all(ms > 0 for ms in legs), row
+        assert sum(legs) <= row["part_ms"] + 1e-6, row
+        assert c.op_counters["parts_scatter_write"] \
+            == counters.get("parts_scatter_write", 0) + 1
+        assert c.op_counters["parts_scatter_native"] \
+            == c.op_counters["parts_scatter_write"]
+        assert c.op_counters.get("parts_scatter_fallback", 0) == 0
+        # and as spans: the three under the one part span, in order
+        spans = c.trace_ring.dump()
+        part = next(s for s in spans if s["name"] == "part")
+        under = sorted((s for s in spans
+                        if s["parent_id"] == part["span_id"]
+                        and s["name"] in ("part_init", "part_data",
+                                          "part_end")),
+                       key=lambda s: s["t0"])
+        assert [s["name"] for s in under] == [
+            "part_init", "part_data", "part_end"]
+        for a, b in zip(under, under[1:]):
+            assert a["t1"] <= b["t0"] + 1e-4  # two clocks read a span
+        assert part["t0"] <= under[0]["t0"] + 1e-4 and \
+            under[-1]["t1"] <= part["t1"] + 1e-4
     finally:
         await cluster.stop()
