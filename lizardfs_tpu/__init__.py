@@ -17,7 +17,7 @@ Layout:
   master/      metadata server
   chunkserver/ data server
   client/      client library (read/write paths)
-  models/      flagship end-to-end pipelines used by bench + graft entry
+  models/      flagship end-to-end pipelines behind the graft entry
   utils/       shared helpers (deterministic data generator, etc.)
 """
 

@@ -2,7 +2,7 @@
 
 Provides ``CppChunkEncoder`` — the ISA-L-class CPU backend: same bytes
 as the golden numpy path, SIMD speed. Used as the default chunkserver/
-client encoder when present and as the honest CPU baseline in bench.py.
+client encoder when present.
 """
 
 from __future__ import annotations

@@ -1,1 +1,1 @@
-"""Flagship end-to-end data-plane pipelines (bench + graft entry points)."""
+"""Flagship end-to-end data-plane pipelines (graft entry points)."""

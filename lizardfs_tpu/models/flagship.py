@@ -9,8 +9,7 @@ Two entry points, matching BASELINE.json configs:
   sharded over a device mesh and parity reduce-scattered by block
   (BASELINE config 5).
 
-These are what ``bench.py`` times and what ``__graft_entry__.py``
-exposes to the driver.
+These are what ``__graft_entry__.py`` exposes to the driver.
 """
 
 from __future__ import annotations

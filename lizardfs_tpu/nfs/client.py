@@ -3,7 +3,7 @@
 Speaks real wire format against any NFS3 server, primarily this
 package's gateway. Used three ways: the gateway's e2e tests (both
 directions of the codec exercised against the spec, not against
-itself), the NFS throughput bench row, and scripted multi-gateway
+itself) and scripted multi-gateway
 drives (see doc/migration.md "NFS scale-out"). Reference analog: the
 Ganesha FSAL test clients (reference: src/nfs-ganesha/).
 """

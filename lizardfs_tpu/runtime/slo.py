@@ -34,7 +34,7 @@ Three pieces, one module:
 Cost contract: ``LZ_SLO=0`` (or ``set_enabled(False)``) short-circuits
 ``observe()`` to a single attribute check — no ring math, no breach
 tests, no capture — and the engine registers nothing while disabled at
-construction. The bench's ec(8,4) row is the regression fiducial.
+construction.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """Minimal asyncio S3/HTTP client for the gateway's consumers in-tree:
-tests, the chaos harness, and the cluster bench.
+tests and the chaos harness.
 
 Deliberately tiny — one keep-alive connection, no signing (the gateway
 does not verify signatures), bytes in / bytes out. Not a general S3

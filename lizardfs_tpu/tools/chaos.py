@@ -48,8 +48,7 @@ Schedules:
                  survivor SELF-promotes (no operator), chunkservers and
                  clients converge on it, zero acknowledged writes are
                  lost, and the detect->elect->promote->first-acked-write
-                 outage is measured and bounded (the
-                 cluster_failover_rto_s bench fiducial shares this drill)
+                 outage is measured and bounded
 """
 
 from __future__ import annotations
@@ -814,7 +813,7 @@ async def run_kill_primary(cluster: ChaosCluster, rng: random.Random,
     converge on it, ZERO acknowledged writes may be lost, the fenced
     epoch must be claimed, and the detect->elect->promote->first-acked-
     write outage must fit inside KILL_PRIMARY_RTO_S. Returns the RTO
-    doc (the cluster_failover_rto_s bench fiducial reuses this drill).
+    doc.
     """
     from lizardfs_tpu.proto import status as st
     from lizardfs_tpu.s3.client import S3Client, S3Error
@@ -1031,8 +1030,7 @@ async def run_schedule(name: str, seed: int, workdir: str | None = None,
                        log=print):
     """Run one schedule at one seed; raises on any invariant violation.
     The whole run sits under the bounded-time budget. Returns whatever
-    the schedule returns (kill-primary's RTO doc feeds the
-    cluster_failover_rto_s bench fiducial; the rest return None)."""
+    the schedule returns (kill-primary its RTO doc; the rest None)."""
     fn, topo = SCHEDULES[name]
     rng = random.Random(seed)
     tmp_ctx = (

@@ -751,9 +751,8 @@ const char* liz_strerror(int code) {
 // marking, AUTH_SYS) — the non-Python measuring client for the NFS
 // gateway. Scope: MNT + LOOKUP + CREATE + READ + WRITE + COMMIT, enough
 // to drive dd-style throughput against the gateway without Python
-// anywhere on the client side (the gateway bench's other row uses the
-// asyncio client; comparing the two separates server cost from
-// measuring-client cost).
+// anywhere on the client side (comparing it with the asyncio client
+// separates server cost from measuring-client cost).
 // ---------------------------------------------------------------------------
 
 namespace {

@@ -96,8 +96,7 @@ async def test_native_pool_latency_beats_loop_path(tmp_path):
                 c.cache.invalidate(f.inode)
                 await c.read_file(f.inode, (i * 8192) % 900_000, 4096)
             loop_s = time.perf_counter() - t0
-            # generous bound: just assert the native path isn't slower;
-            # absolute numbers land in benches/bench_cluster.py
+            # generous bound: just assert the native path isn't slower
             assert native_s < loop_s * 1.5, (native_s, loop_s)
         finally:
             await asyncio.to_thread(pool.close)

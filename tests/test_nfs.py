@@ -655,8 +655,8 @@ async def test_nfs_native_c_client_roundtrip(tmp_path):
     """The non-Python measuring client: the C NFS3 client
     (native/client_native.cpp liz_nfs_* over ONC-RPC/AUTH_SYS) drives
     MNT/CREATE/WRITE/COMMIT/LOOKUP/READ against the gateway and the
-    bytes roundtrip — so the gateway bench's C-client row measures a
-    real wire client, not this package's own asyncio codec."""
+    bytes roundtrip — a real wire client, not this package's own
+    asyncio codec."""
     import asyncio
 
     from lizardfs_tpu.nfs import cnfs

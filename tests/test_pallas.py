@@ -104,7 +104,7 @@ def test_pallas_fused_decode_verify():
 
 @pytest.mark.parametrize("tile", [32768, 65536])
 def test_pallas_fused_large_tiles_byte_identical(tile):
-    """The grid-step reduction (benches/ROOFLINE.md #1) runs the same
+    """The grid-step reduction (``BIG_TILE_CONFIG``, ROOFLINE #1) runs the same
     kernel at 32/64 KiB tiles — bytes must not depend on tile size."""
     rng = np.random.default_rng(7)
     k, m, bs = 8, 4, 65536
@@ -179,8 +179,7 @@ def test_pallas_roofline_small_tile_falls_back():
 def test_pallas_decode_verify_roofline_config_byte_identical():
     """fused_decode_verify must accept the staged ROOFLINE config and
     recover byte-identically through a RECOVERY bitmatrix (the encode
-    parity tests cover only generator-matrix shapes; the rec bench row
-    uses exactly this path with the ladder's winning config)."""
+    parity tests cover only generator-matrix shapes)."""
     from lizardfs_tpu.ops import gf256
 
     rng = np.random.default_rng(13)

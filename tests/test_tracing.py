@@ -614,8 +614,7 @@ async def test_traced_write_merges_across_roles(tmp_path):
         assert "CltomaWriteChunk" in names  # master grant under the trace
         tl = tracing.merge_timeline(spans, tid, wall_name="write_file")
         assert tl["wall_ms"] > 0
-        # the acceptance bar (>=90%) is measured by the bench on a quiet
-        # box; here just require substantial attribution despite CI load
+        # require substantial attribution despite CI load
         assert tl["coverage_pct"] >= 50.0, tl
         assert set(tl["by_role_ms"]) >= {"client", "chunkserver"}
     finally:
