@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import os as _os
 import time as _time
 
 import numpy as np
@@ -32,7 +31,6 @@ from lizardfs_tpu.constants import (
     EATTR_NOENTRYCACHE,
     MFSBLOCKSIZE,
     MFSCHUNKSIZE,
-    env_flag,
 )
 from lizardfs_tpu.core import geometry, plans
 from lizardfs_tpu.core.encoder import (
@@ -43,6 +41,7 @@ from lizardfs_tpu.proto import framing
 from lizardfs_tpu.proto import messages as m
 from lizardfs_tpu.proto import status as st
 from lizardfs_tpu.client.cache import BlockCache, ReadaheadAdviser
+from lizardfs_tpu.client.write_window import MAX_DEPTH, WriteWindow
 from lizardfs_tpu.runtime import accounting
 from lizardfs_tpu.runtime import faults as _faults
 from lizardfs_tpu.runtime import qos as qosmod
@@ -251,20 +250,9 @@ class Client:
         # tracing.span of an op lands here with its parent; merge with
         # daemon `trace-dump` output via tracing.merge_timeline
         self.trace_ring = tracing.SpanRing()
-        # double-buffered stripe pipeline for striped (xor/ec) chunk
-        # writes: encode stripe segment i+1 while segment i's parts are
-        # in flight. LZ_WRITE_PIPELINE=0 is the kill switch (strictly
-        # serial stage->encode->send ordering, the byte-identity golden
-        # reference); LZ_WRITE_PIPELINE_SEGMENTS tunes pipeline depth.
-        self.write_pipeline = env_flag("LZ_WRITE_PIPELINE")
-        try:
-            self.write_pipeline_segments = max(
-                2, int(_os.environ.get("LZ_WRITE_PIPELINE_SEGMENTS", "4"))
-            )
-        except ValueError:
-            self.write_pipeline_segments = 4
-        # below this chunk payload size the per-segment handshake
-        # overhead outweighs the overlap win — serial path handles it
+        # below this chunk payload size the per-segment cost of the
+        # windowed whole-chunk write outweighs the overlap win: the
+        # overlapped whole-part sends handle it (_pipeline_eligible)
         self.WRITE_PIPELINE_MIN_BYTES = 8 * 1024 * 1024
         # client-side metrics registry: the write window registers its
         # depth/credit/coalesce series here. Embedders that export a
@@ -287,38 +275,12 @@ class Client:
             self.read_phases, self.trace_ring, "client", self.metrics
         )
         # adaptive N-deep write window (spends PR 1's phase telemetry):
-        # up to LZ_WRITE_WINDOW stripe segments ride unacknowledged per
-        # striped chunk write under per-chunkserver credits + a shared
-        # staging-byte budget, with depth adapted from live encode/send
-        # busy fractions; finished chunks coalesce their WriteChunkEnd
-        # commits into one master round trip per window flush.
-        # LZ_WRITE_WINDOW=0 is the kill switch: the PR-1 double-buffered
-        # pipeline (per-segment ack barriers, per-chunk commits) runs
-        # byte- and wire-identically to before.
-        from lizardfs_tpu.client.write_window import WriteWindow
-
-        try:
-            _depth = int(_os.environ.get("LZ_WRITE_WINDOW", "8"))
-        except ValueError:
-            _depth = 8
-        try:
-            _cs_credits = int(_os.environ.get("LZ_WRITE_CS_CREDITS", "0"))
-        except ValueError:
-            _cs_credits = 0
-        try:
-            _budget_mb = int(
-                _os.environ.get("LZ_WRITE_WINDOW_BYTES_MB", "128")
-            )
-        except ValueError:
-            _budget_mb = 128
-        self.write_window = (
-            WriteWindow(
-                _depth, metrics=self.metrics,
-                cs_credits=_cs_credits or None,
-                budget_bytes=max(_budget_mb, 1) * 2**20,
-            )
-            if _depth > 0 else None
-        )
+        # stripe segments ride unacknowledged per striped chunk write
+        # under per-chunkserver credits + a shared staging-byte budget,
+        # with depth adapted from live encode/send busy fractions;
+        # finished chunks coalesce their WriteChunkEnd commits into one
+        # master round trip per window flush.
+        self.write_window = WriteWindow(metrics=self.metrics)
         # shadow read replicas (LZ_SHADOW_READS kill switch, default on
         # when more than one master address is configured): read-mostly
         # metadata RPCs route to a shadow serving consistency-tokened
@@ -1407,18 +1369,14 @@ class Client:
             # and the master's WriteChunkEnd only ever grows the file,
             # so completion order doesn't matter
             window = asyncio.Semaphore(2)
-            # with the write window active, clean chunk ends coalesce
-            # into one CltomaWriteChunkEndBatch per flush instead of a
-            # commit handshake per chunk (multi-chunk files pay one
-            # master round trip per window drain)
-            defer = self.write_window is not None
-
+            # clean chunk ends coalesce into one CltomaWriteChunkEndBatch
+            # per flush instead of a commit handshake per chunk (multi-
+            # chunk files pay one master round trip per window drain)
             async def write_one(ci: int, piece: np.ndarray, end: int) -> None:
                 async with window:
                     async def attempt():
                         await self._write_chunk(
                             inode, ci, piece, file_length=end,
-                            defer_end=defer,
                         )
 
                     await self._retry_transient(f"write chunk {ci}", attempt)
@@ -1839,7 +1797,7 @@ class Client:
 
     async def _write_chunk(
         self, inode: int, chunk_index: int, chunk_data: np.ndarray,
-        file_length: int, defer_end: bool = False,
+        file_length: int,
     ) -> None:
         grant = await self._grant(inode, chunk_index)
         self.cache.invalidate(inode, chunk_index)
@@ -1848,8 +1806,7 @@ class Client:
             await self._push_chunk_parts(grant, chunk_data)
             status_code = st.OK
         finally:
-            if (defer_end and status_code == st.OK
-                    and self.write_window is not None):
+            if status_code == st.OK:
                 # commit coalescing: queue the end record; the window's
                 # owner (write_file) flushes the batch as ONE master
                 # round trip. Only CLEAN ends coalesce — a failed write
@@ -1879,7 +1836,7 @@ class Client:
         CltomaWriteChunkEndBatch (the window pays one commit handshake
         per flush instead of one per chunk)."""
         win = self.write_window
-        if win is None or not win.pending_ends:
+        if not win.pending_ends:
             return
         batch = win.drain_ends()
         try:
@@ -1966,18 +1923,15 @@ class Client:
             return
         # striped slices: scatter into contiguous part streams first
         # (one memcpy, the `stage` phase), then hand off to one of:
-        #   * the segmented stripe pipeline (default, preconditions
-        #     permitting): encode segment i+1 while segment i's data AND
-        #     parity are in flight — parity lands straight in the send
-        #     buffer, no second staging copy;
-        #   * the overlapped whole-chunk path (pipeline on, but chains/
-        #     missing parts/no native scatter): whole-chunk encode
-        #     overlaps the data-part transfer (chunk_writer.cc computes
-        #     parity inline per stripe; this is its coarse analog);
-        #   * the strictly serial path (LZ_WRITE_PIPELINE=0 kill
-        #     switch): stage -> encode -> send(data) -> send(parity),
-        #     the byte-identity golden reference whose phase totals sum
-        #     to ~the rep wall time.
+        #   * the windowed segment sends (_pipeline_eligible): encode
+        #     segment i+1 while earlier segments' data AND parity are
+        #     in flight — parity lands straight in the send buffer, no
+        #     second staging copy;
+        #   * the overlapped whole-part sends (chains, missing parts,
+        #     armed faults, a small payload, no native library, or the
+        #     window raised): the whole-chunk encode overlaps the
+        #     data-part transfer (chunk_writer.cc computes parity inline
+        #     per stripe; this is its coarse analog).
         d = slice_type.data_parts
         nblocks = -(-len(chunk_data) // MFSBLOCKSIZE)
         part_len = -(-nblocks // d) * MFSBLOCKSIZE
@@ -2004,7 +1958,7 @@ class Client:
 
         try:
             throttled = False
-            if self.write_pipeline and self._pipeline_eligible(
+            if self._pipeline_eligible(
                 slice_type, by_part, chunk_data, part_len
             ):
                 # charge the QoS budget up front (one acquire for the
@@ -2015,37 +1969,20 @@ class Client:
                 ))
                 throttled = True
                 try:
-                    if (self.write_window is not None
-                            and native_io.parts_scatterv_available()):
-                        # adaptive window: N unacked segments in flight
-                        # over shared per-chunkserver connections
-                        await self._push_striped_windowed(
-                            grant, chunk_data, slice_type, by_part,
-                            stacked, part_len, full_chunk, send_cells,
-                        )
-                        self._record("write_window")
-                    else:
-                        await self._push_striped_pipelined(
-                            grant, chunk_data, slice_type, by_part, stacked,
-                            part_len, full_chunk, send_cells,
-                        )
-                    # both overlapped paths count as the pipeline for
-                    # observability (the window is its deeper form)
+                    # adaptive window: N unacked segments in flight
+                    # over shared per-chunkserver connections
+                    await self._push_striped_windowed(
+                        grant, chunk_data, slice_type, by_part,
+                        stacked, part_len, full_chunk, send_cells,
+                    )
+                    self._record("write_window")
                     self._record("write_pipeline")
                     return
                 except (native_io.NativeIOError, OSError, ConnectionError,
                         st.StatusError):
                     # torn segments are healed by the full-part rewrite
-                    # the paths below perform
+                    # the sends below perform
                     self._record("write_pipeline_fallback")
-            if not self.write_pipeline:
-                par = await parity_parts()
-                await send_batch(
-                    [(first + i, stacked[i]) for i in range(d)],
-                    skip_throttle=throttled,
-                )
-                await send_batch(sorted(par.items()), skip_throttle=throttled)
-                return
             par_task = asyncio.ensure_future(parity_parts())
             tasks = [asyncio.ensure_future(
                 send_batch(
@@ -2098,7 +2035,7 @@ class Client:
             bucket.append(buf)
 
     def _parity_acquire(self, m: int, part_len: int) -> np.ndarray:
-        """Parity send buffer for the pipelined path ((m, part_len),
+        """Parity send buffer for the windowed path ((m, part_len),
         pooled with the stage buffers): the encoder writes parity
         straight into it and the native scatter streams from it — the
         per-chunk parity staging copy is gone."""
@@ -2110,14 +2047,17 @@ class Client:
     def _pipeline_eligible(
         self, slice_type, by_part, chunk_data, part_len: int
     ) -> bool:
-        """Segmented stripe pipeline preconditions: native scatter
-        built, every expected part granted with exactly one holder (no
-        relay chains — the session sends chain-less frames), and a
-        payload big enough that per-segment overlap beats the extra
-        segment barriers. Anything else takes the fallback paths."""
+        """What the windowed whole-chunk write needs: the native
+        library built, no armed fault, every expected part granted with
+        exactly one holder (no relay chains — the session sends
+        chain-less frames), and a payload big enough that per-segment
+        overlap beats the segments' own cost. Anything else takes the
+        overlapped whole-part sends."""
         from lizardfs_tpu.core import native_io
 
         if not native_io.parts_scatter_available():
+            # one library, built from this tree: the one-shot exchange
+            # and the vectored scatter are present or absent together
             return False
         if _faults.ACTIVE:
             # armed faults: native scatter sessions can't be
@@ -2134,14 +2074,14 @@ class Client:
 
     def _stripe_send_plan(
         self, grant, chunk_data, slice_type, by_part, stacked,
-        part_len: int, send_cells: list[dict], share: bool, nseg_min: int,
+        part_len: int, send_cells: list[dict],
     ):
-        """Shared prologue of the two overlapped stripe senders (the
-        double-buffered pipeline and the adaptive window): part order
-        and per-part lengths, the pooled parity send buffer, the
-        scatter session + abort cell, slot-aligned segment bounds, and
-        the per-segment encode/payload/length closures — a stripe-
-        geometry or encoder-boundary change lands in exactly one place.
+        """Prologue of the windowed stripe sender: part order and
+        per-part lengths, the pooled parity send buffer, the scatter
+        session + abort cell, slot-aligned segment bounds (as many
+        segments as the window's ceiling, so that it can fill), and the
+        per-segment encode/payload/length closures — a stripe-geometry
+        or encoder-boundary change lands in exactly one place.
         Returns ``(par_buf, cell, session, bounds, encode_segment,
         seg_payloads, seg_lengths)``."""
         from lizardfs_tpu.core import native_io
@@ -2164,12 +2104,10 @@ class Client:
              for p in order],
             grant.chunk_id, grant.version,
             [by_part[p][0].part_id for p in order],
-            cell, share_connections=share,
+            cell,
         )
         blocks_per_part = part_len // MFSBLOCKSIZE
-        nseg = min(
-            max(self.write_pipeline_segments, nseg_min), blocks_per_part
-        )
+        nseg = min(MAX_DEPTH, blocks_per_part)
         seg_blocks = -(-blocks_per_part // nseg)
         bounds = [
             (a * MFSBLOCKSIZE,
@@ -2214,106 +2152,34 @@ class Client:
         return (par_buf, cell, session, bounds, encode_segment,
                 seg_payloads, seg_lengths)
 
-    async def _push_striped_pipelined(
-        self, grant, chunk_data, slice_type, by_part, stacked,
-        part_len: int, full_chunk: bool, send_cells: list[dict],
-    ) -> None:
-        """Double-buffered stripe pipeline: ONE WriteInit/End handshake
-        pair per part for the whole chunk, the part streams cut into
-        slot-aligned segments, and segment i+1's parity encoding (into
-        the send buffer, via the ChunkEncoder boundary) overlapping
-        segment i's data+parity transfer.
-
-        Byte-identical to the serial path by construction: RS/xor
-        parity is columnwise (parity[j][x] depends only on column x of
-        the data parts), so a per-segment encode equals the matching
-        slice of a whole-part encode; segment boundaries stay 64 KiB
-        aligned, so the chunkservers see the same per-block pieces and
-        store the same CRCs. Raises on any failure — the caller falls
-        back to the serial path, whose full-part rewrite heals torn
-        segments. The caller has already charged the QoS throttle."""
-        from lizardfs_tpu.core import native_io
-
-        (par_buf, cell, session, bounds, encode_segment, seg_payloads,
-         seg_lengths) = self._stripe_send_plan(
-            grant, chunk_data, slice_type, by_part, stacked, part_len,
-            send_cells, share=False, nseg_min=2,
-        )
-
-        async def send_segment(a: int, b: int, wid: int, after) -> None:
-            # chained on the previous segment's task: the session's
-            # sockets carry one exchange at a time, and a predecessor's
-            # failure propagates down the chain
-            if after is not None:
-                await after
-            with tracing.span("send", phase="send", bucket="net", seg=wid):
-                await native_io.run(
-                    session.send_segment, seg_payloads(a, b),
-                    seg_lengths(a, b), a, wid,
-                )
-
-        send_tasks: list[asyncio.Task] = []
-        try:
-            with tracing.span("send", phase="send", bucket="net", seg=0):
-                await native_io.run(session.open)
-            for wid, (a, b) in enumerate(bounds, start=1):
-                with tracing.span("encode", phase="encode",
-                                  bucket="compute", seg=wid):
-                    await asyncio.to_thread(encode_segment, a, b)
-                send_tasks.append(asyncio.ensure_future(send_segment(
-                    a, b, wid, send_tasks[-1] if send_tasks else None
-                )))
-            await send_tasks[-1]
-            with tracing.span("send", phase="send", bucket="net", seg=-1):
-                await native_io.run(session.finish)
-        except BaseException:
-            for t in send_tasks:
-                t.cancel()
-            await asyncio.gather(*send_tasks, return_exceptions=True)
-            # the session's executor thread may still be streaming from
-            # stacked/par_buf — kill the exchange before those buffers
-            # can be released (the caller's zombie-abort also covers
-            # this cell, but do it promptly here)
-            native_io.abort_write(cell)
-            raise
-        finally:
-            self._stage_release(
-                par_buf,
-                poolable=full_chunk and not (
-                    cell.get("submitted") and not cell.get("finished")
-                ),
-            )
-
     async def _push_striped_windowed(
         self, grant, chunk_data, slice_type, by_part, stacked,
         part_len: int, full_chunk: bool, send_cells: list[dict],
     ) -> None:
-        """Adaptive N-deep write window over the stripe pipeline: up to
-        ``write_window.depth`` slot-aligned segments ride UNACKNOWLEDGED
-        (part-addressed 1215 frames, vectored header+payload sendmsg,
+        """Adaptive N-deep write window over a chunk's stripe segments:
+        up to ``write_window.depth`` slot-aligned segments ride
+        UNACKNOWLEDGED (part-addressed 1215 frames, vectored header+payload sendmsg,
         parts sharing a chunkserver multiplexed over one connection),
         with per-chunkserver credits + a shared staging-byte budget as
         flow control. Acks are collected oldest-first as the window
-        fills — the per-segment round-trip barrier the PR-1 pipeline
-        paid (its send phase dominated the ec(8,4) telemetry) is gone.
+        fills: no segment waits for a round trip of its own.
 
-        Byte-identical to the serial path for the same reason the
-        pipelined path is: parity is columnwise, segments stay 64 KiB
-        aligned, and the chunkservers land the same per-block pieces
-        and CRCs — only the framing and ack cadence differ. Raises on
-        any failure; the caller's serial fallback heals torn segments.
-        The caller has already charged the QoS throttle."""
+        The part files come out as a whole-part send's would: RS/xor
+        parity is columnwise (parity[j][x] depends only on column x of
+        the data parts), so a per-segment encode equals the matching
+        slice of a whole-part encode; segment boundaries stay 64 KiB
+        aligned, so the chunkservers land the same per-block pieces
+        and store the same CRCs. Raises on any failure; the caller's
+        whole-part fallback heals torn segments. The caller has
+        already charged the QoS throttle."""
         from lizardfs_tpu.core import native_io
 
         win = self.write_window
         d = slice_type.data_parts  # ring widths: data rows vs parity
-        # nseg_min=win.max_depth: enough segments that the window can
-        # actually fill (a 4-deep window over 4 segments would
-        # degenerate to the old barrier)
         (par_buf, cell, session, bounds, encode_segment, seg_payloads,
          seg_lengths) = self._stripe_send_plan(
             grant, chunk_data, slice_type, by_part, stacked, part_len,
-            send_cells, share=True, nseg_min=win.max_depth,
+            send_cells,
         )
 
         from collections import deque

@@ -1,16 +1,14 @@
-"""Adaptive write window for the striped chunk-write pipeline.
+"""Adaptive write window for the striped whole-chunk write.
 
 PR 1's phase telemetry blamed the ec(8,4) write gap on stripe-serial
-round trips: the double-buffered pipeline paid one ack barrier per
-stripe segment. This controller replaces the fixed depth with an
+round trips: one ack barrier per stripe segment. This controller is an
 **adaptive N-deep window** (the classic pipeline-depth/flow-control
 shape from striped-storage systems — cf. the chain-replication write
 executor in the LizardFS reference and credit-based stripe writers in
 Colossus-style systems):
 
 * up to ``depth`` stripe segments ride unacknowledged per chunk write
-  (``LZ_WRITE_WINDOW`` caps it; 0 kills the window entirely and
-  restores the PR-1 double-buffered path);
+  (``MAX_DEPTH`` caps it);
 * **credit-based flow control**: a :class:`CreditBucket` per
   chunkserver bounds unacknowledged bulk frames per connection, and
   one shared byte bucket bounds total staged bytes across every
@@ -40,28 +38,27 @@ _ADAPT_RATIO = 1.3
 # every one would chase scheduling noise
 _ADAPT_EVERY = 4
 _EWMA_ALPHA = 0.3
+# depth ceiling: unacknowledged segments a chunk write may have in
+# flight, and the number of segments a chunk is cut into
+MAX_DEPTH = 8
+# client-wide staging budget across all in-flight windowed segments
+BUDGET_BYTES = 128 * 2**20
 
 
 class WriteWindow:
     """Shared, client-wide window state (one instance per Client)."""
 
-    def __init__(
-        self,
-        max_depth: int,
-        metrics=None,
-        cs_credits: int | None = None,
-        budget_bytes: int = 128 * 2**20,
-    ):
-        self.max_depth = max(1, int(max_depth))
-        # start double-buffered (the PR-1 shape) and adapt from there
+    def __init__(self, metrics=None):
+        self.max_depth = MAX_DEPTH
+        # start double-buffered and adapt from there
         self.depth = min(2, self.max_depth)
         # per-chunkserver credit capacity: how many unacked bulk frames
-        # one connection may carry; defaults to the window ceiling so a
-        # single writer is never credit-bound before it is depth-bound,
-        # while concurrent writers to the same server share the cap
-        self.cs_credits = int(cs_credits) if cs_credits else self.max_depth
+        # one connection may carry; the window ceiling, so a single
+        # writer is never credit-bound before it is depth-bound, while
+        # concurrent writers to the same server share the cap
+        self.cs_credits = self.max_depth
         self._cs: dict[tuple[str, int], CreditBucket] = {}
-        self._budget = CreditBucket(float(budget_bytes))
+        self._budget = CreditBucket(float(BUDGET_BYTES))
         self._enc_ewma = 0.0
         self._send_ewma = 0.0
         self._since_adapt = 0
@@ -81,7 +78,7 @@ class WriteWindow:
             self._m_depth.set(float(self.depth))
             metrics.gauge(
                 "write_window_depth_max",
-                help="configured write-window ceiling (LZ_WRITE_WINDOW)",
+                help="write-window depth ceiling",
             ).set(float(self.max_depth))
             self._m_waits = metrics.counter(
                 "write_window_credit_waits",

@@ -78,16 +78,6 @@ if _lib is not None:
         except AttributeError:
             pass  # stale .so: the whole-stripe fast path stays off
         try:
-            _lib.lz_write_parts_scatter.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint32,
-                ctypes.POINTER(ctypes.c_void_p),
-                ctypes.POINTER(ctypes.c_uint64),
-                ctypes.c_uint64, ctypes.c_uint32,
-            ]
-            _lib.lz_write_parts_scatter.restype = ctypes.c_int
-        except AttributeError:
-            pass  # stale .so: multi-part write fast path stays off
-        try:
             _lib.lz_write_parts_exchange.argtypes = [
                 ctypes.c_void_p, ctypes.c_uint32,
                 ctypes.POINTER(ctypes.c_char_p),
@@ -1039,19 +1029,12 @@ abort_parts_scatter = abort_parts_gather
 
 
 def parts_scatter_available() -> bool:
-    """The one-shot part exchange (lz_write_parts_exchange: WriteInit,
-    bulk and WriteEnd legs in one native call) is built."""
+    """The multi-part write entries are built: the one-shot part
+    exchange (lz_write_parts_exchange: WriteInit, bulk and WriteEnd
+    legs in one native call) and, older than it in the same library,
+    the windowed scatter (lz_write_parts_scatterv /
+    lz_write_collect_acks)."""
     return _lib is not None and hasattr(_lib, "lz_write_parts_exchange")
-
-
-def parts_scatterv_available() -> bool:
-    """Vectored + windowed scatter writes (lz_write_parts_scatterv /
-    lz_write_collect_acks): required by the adaptive write window."""
-    return (
-        _lib is not None
-        and hasattr(_lib, "lz_write_parts_scatterv")
-        and hasattr(_lib, "lz_write_collect_acks")
-    )
 
 
 # lz_write_parts_scatterv flags (keep in sync with io_native.cpp)
@@ -1102,7 +1085,7 @@ def _marshal_part_reqs(
     fds: list[int], chunk_id: int, write_id: int, part_ids: list[int],
     payloads: list[np.ndarray], lengths: list[int],
 ):
-    """-> (reqs, ptrs, lens) ctypes arrays for lz_write_parts_scatter.
+    """-> (reqs, ptrs, lens) ctypes arrays for lz_write_parts_scatterv.
     The req's ``version`` slot carries the bulk frame's write_id."""
     n = len(fds)
     reqs = (_PartReq * n)()
@@ -1131,21 +1114,23 @@ def _write_end_handshake(socks: list[socket.socket], chunk_id: int) -> None:
 
 
 class PartsScatterSession:
-    """Pipelined multi-segment part writes over persistent connections.
+    """Windowed multi-segment part writes over persistent connections.
 
-    The write-path building block of the client's double-buffered stripe
-    pipeline: ``open()`` dials every part's holder once and runs the
-    WriteInit handshakes; ``send_segment()`` streams one slot-aligned
-    segment of every part (one poll-driven ``lz_write_parts_scatter``
-    call: bulk frame + ack per part, per-block CRCs computed in C);
-    ``finish()`` runs the WriteEnd handshakes. One handshake pair per
-    part per *chunk* instead of per segment — the per-segment cost is
-    only the bulk frames themselves, so encode(i+1) can overlap
-    send(i) without paying n extra round trips per segment.
+    The write-path building block of the client's windowed whole-chunk
+    write: ``open()`` takes one connection a chunkserver (parts that
+    share a holder ride it together; the part-addressed 1215 frames
+    demux them server-side) and runs the WriteInit handshakes;
+    ``send_segment_window()`` streams one slot-aligned segment of
+    every part (one poll-driven ``lz_write_parts_scatterv`` call: a
+    bulk frame per part, per-block CRCs computed in C) without waiting
+    for its acks, ``collect_acks()`` reaps them; ``finish()`` runs the
+    WriteEnd handshakes. One handshake pair per part per *chunk*
+    instead of per segment — the per-segment cost is only the bulk
+    frames themselves, so encode(i+1) overlaps the sends before it.
 
     Every method is blocking (call via :func:`run`). Any failure leaves
     the sockets closed and the exchange dead; the caller falls back to
-    the serial write path (a full-part rewrite heals torn segments).
+    whole-part sends (a full-part rewrite heals torn segments).
     ``cell`` follows the abort contract of write_parts_scatter_blocking:
     abort_write(cell) from another thread kills the exchange,
     ``cell["finished"]`` marks when no thread reads the payloads anymore.
@@ -1158,32 +1143,21 @@ class PartsScatterSession:
         version: int,
         part_ids: list[int],
         cell: dict | None = None,
-        share_connections: bool = False,
     ):
         assert len(addrs) == len(part_ids)
-        self.addrs = addrs
         self.chunk_id = chunk_id
         self.version = version
         self.part_ids = part_ids
         self.cell = cell if cell is not None else {}
-        # share_connections: parts that target the same chunkserver
-        # ride ONE connection (the windowed/vectored path demuxes them
-        # with part-addressed 1215 frames server-side). The legacy
-        # barrier path keeps one socket per part — its 1214 frames
-        # carry no part id, so a shared connection cannot demux them.
-        self.share = share_connections
-        if share_connections:
-            self.unique_addrs: list[tuple[str, int]] = []
-            self._conn_of: list[int] = []
-            index: dict[tuple[str, int], int] = {}
-            for addr in addrs:
-                if addr not in index:
-                    index[addr] = len(self.unique_addrs)
-                    self.unique_addrs.append(addr)
-                self._conn_of.append(index[addr])
-        else:
-            self.unique_addrs = list(addrs)
-            self._conn_of = list(range(len(addrs)))
+        # parts that target the same chunkserver ride ONE connection
+        self.unique_addrs: list[tuple[str, int]] = []
+        self._conn_of: list[int] = []
+        index: dict[tuple[str, int], int] = {}
+        for addr in addrs:
+            if addr not in index:
+                index[addr] = len(self.unique_addrs)
+                self.unique_addrs.append(addr)
+            self._conn_of.append(index[addr])
         self._socks: list[socket.socket] = []
         # write_id -> live part indices of an unacked windowed segment
         self._pending: dict[int, list[int]] = {}
@@ -1202,7 +1176,7 @@ class PartsScatterSession:
         return self._socks[self._conn_of[part_index]]
 
     def _ring_eligible(self) -> bool:
-        return self.share and shm_ring_enabled() and parts_shm_available()
+        return shm_ring_enabled() and parts_shm_available()
 
     def open(self) -> None:
         self.cell["submitted"] = True
@@ -1256,11 +1230,9 @@ class PartsScatterSession:
     # --- shm-ring staging (native/shm_ring.h) -------------------------
 
     def _setup_rings(self) -> None:
-        """Negotiate a memfd ring per shared connection where the
-        same-host fast path applies. Only the windowed/shared mode uses
-        rings (the legacy per-part barrier path keeps its wire shape);
-        any per-connection failure just leaves that connection on the
-        socket-copy path — never fails the session."""
+        """Negotiate a memfd ring per connection where the same-host
+        fast path applies; any per-connection failure just leaves that
+        connection on the socket-copy path — never fails the session."""
         self._rings = [None] * len(self._socks)
         if not self._ring_eligible():
             return
@@ -1366,52 +1338,6 @@ class PartsScatterSession:
             bad = next((int(r.rc) for r in reqs if r.rc != 0), -1)
             raise NativeIOError(bad, "shm descriptor send")
         self.ring_stats["desc_parts"] += n
-
-    def send_segment(
-        self,
-        payloads: list[np.ndarray],
-        lengths: list[int],
-        part_offset: int,
-        write_id: int,
-    ) -> None:
-        """Stream ``payloads[i][:lengths[i]]`` at ``part_offset`` within
-        every live part and wait for every ack (the barrier path). A
-        zero length skips that part this segment (tail segments cover
-        fewer parts)."""
-        assert self._socks, "session not open"
-        n = len(self.part_ids)
-        assert n == len(payloads) == len(lengths)
-        live = [i for i in range(n) if lengths[i] > 0]
-        if not live:
-            return
-        try:
-            if self.cell.get("aborted"):
-                raise NativeIOError(-1, "scatter session (aborted)")
-            reqs, ptrs, lens = _marshal_part_reqs(
-                [self._sock_of(i).fileno() for i in live],
-                self.chunk_id, write_id,
-                [self.part_ids[i] for i in live],
-                [payloads[i] for i in live],
-                [lengths[i] for i in live],
-            )
-            if self.share:
-                # shared connections need part-addressed frames (and a
-                # duplicate-fd-aware send loop): the vectored call
-                rc = _lib.lz_write_parts_scatterv(
-                    ctypes.cast(reqs, ctypes.c_void_p), len(live), ptrs,
-                    lens, part_offset, 120_000, 0,
-                )
-            else:
-                rc = _lib.lz_write_parts_scatter(
-                    ctypes.cast(reqs, ctypes.c_void_p), len(live), ptrs,
-                    lens, part_offset, 120_000,
-                )
-            if rc != 0:
-                bad = next((int(r.rc) for r in reqs if r.rc != 0), -1)
-                raise NativeIOError(bad, "scatter session segment")
-        except BaseException:
-            self.close()
-            raise
 
     def send_segment_window(
         self,

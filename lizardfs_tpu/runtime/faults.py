@@ -55,7 +55,7 @@ the rule's index — the same spec plus the same sequence of match calls
 yields the same decisions, so a failing chaos schedule replays exactly
 from its printed seed.
 
-Kill-switch discipline (the LZ_WRITE_WINDOW / LZ_SHM_RING contract):
+Kill-switch discipline (the LZ_SHM_RING contract):
 with ``LZ_FAULTS`` unset and no rules armed, :data:`ACTIVE` is False and
 every instrumented site reduces to one module-attribute check — zero
 added syscalls, zero behavior change, byte-identical output. While any

@@ -50,7 +50,6 @@ SWITCHES = {
     "LZ_SHM_RING",         # same-host shared-memory data plane (on)
     "LZ_SHADOW_READS",     # shadow read replicas (on)
     "LZ_SHARDED_RECOVERY", # mesh-sharded rebuild compute (on)
-    "LZ_WRITE_PIPELINE",   # double-buffered stripe pipeline (on)
     "LZ_TPU_ALLOW_CPU",    # encoder escape hatch (default OFF)
     "LZ_NO_UDS",           # disable same-host UDS fast path (default OFF)
     "LZ_S3",               # S3 object gateway (on; off refuses start)
@@ -69,10 +68,6 @@ VALUES = {
     "LZ_NATIVE_SO",               # alternate native library path
     "LZ_CLIENT_SO",               # alternate C-client library path
     "LZ_SHM_RING_MB",             # shm segment size
-    "LZ_WRITE_WINDOW",            # window depth (0 = kill switch)
-    "LZ_WRITE_CS_CREDITS",        # per-chunkserver credit override
-    "LZ_WRITE_WINDOW_BYTES_MB",   # staging-byte budget
-    "LZ_WRITE_PIPELINE_SEGMENTS", # pipeline depth
     "LZ_DETSCHED",                # deterministic-scheduler seed (tests)
 }
 

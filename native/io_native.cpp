@@ -685,7 +685,7 @@ struct RoundSend {
 // One poll-driven round over n connected sockets: socket i sends
 // out[i] (head, then payload) and reads ONE CstoclWriteStatus back.
 // The shared body of the three legs of a part exchange (WriteInit,
-// bulk data, WriteEnd) and of lz_write_parts_scatter. Entries whose rc
+// bulk data, WriteEnd). Entries whose rc
 // the caller has already set nonzero are left alone and fail the round
 // before a byte is sent. match_write_id: the status must echo
 // parts[i].version (a bulk ack); a handshake's status carries no id.
@@ -821,9 +821,10 @@ int status_round(lz_part_req* parts, uint32_t n, const RoundSend* out,
 }
 
 // The bulk leg: one 1214 frame (per-block CRCs computed here, GIL-free)
-// + one ack per part. parts[i].version carries the bulk write_id for
-// part i (reusing the request struct; the chunk version is already
-// bound by WriteInit).
+// + one ack per part, all n in ONE poll-driven loop (the mirror of
+// lz_read_parts_gather for the write path). parts[i].version carries
+// the bulk write_id for part i (reusing the request struct; the chunk
+// version is already bound by WriteInit).
 int scatter_bulk(lz_part_req* parts, uint32_t n,
                  const uint8_t* const* payloads, const uint64_t* lens,
                  uint64_t part_offset, int64_t deadline) {
@@ -859,31 +860,10 @@ int handshake_round(lz_part_req* parts, uint32_t n,
 
 }  // namespace
 
-// Whole-stripe fan-out: stream n part payloads as bulk writes (one
-// 1214 frame + one ack each) over n already-initialized sockets in ONE
-// poll-driven loop. The mirror of lz_read_parts_gather for the write
-// path: one native call replaces n thread dispatches, and the
-// per-block CRC pass over every payload runs here, GIL-free. The
-// caller has already exchanged WriteInit on each socket and sends
-// WriteEnd afterwards (PartsScatterSession: one handshake pair a
-// chunk, many segments); a one-shot write runs all three legs in
-// lz_write_parts_exchange.
-//
-// parts[i].version carries the bulk write_id for part i.
-// parts[i].rc: 0 ok; >0 peer status; -1 socket; -2 protocol. Returns
-// 0 iff every part succeeded (caller falls back to per-part writes).
-int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
-                           const uint8_t* const* payloads,
-                           const uint64_t* lens, uint64_t part_offset,
-                           uint32_t max_ms) {
-    return scatter_bulk(parts, n, payloads, lens, part_offset,
-                        steady_ms() + max_ms);
-}
-
 // The whole one-shot part exchange in one call: over n connected
 // sockets, three status rounds with no caller code between them:
 //   leg 1  one caller-built WriteInit frame per socket, one status each;
-//   leg 2  the bulk leg of lz_write_parts_scatter;
+//   leg 2  one bulk frame per socket, one ack each (scatter_bulk);
 //   leg 3  one caller-built WriteEnd frame per socket, one status each.
 // A leg starts only when every status of the one before is in and OK:
 // no data goes to a server that has not accepted the init, and no End
@@ -895,9 +875,10 @@ int lz_write_parts_scatter(lz_part_req* parts, uint32_t n,
 // One deadline (max_ms) covers the call. leg_us[0..2] receive each
 // leg's duration in microseconds on the steady clock (0 for a leg that
 // never started). Returns 0 iff every part passed every leg, else the
-// leg that failed (1, 2, 3) with parts[i].rc as lz_write_parts_scatter
-// sets it; bad arguments fail leg 2 (whose precondition they break)
-// with every rc -2, before a byte is sent.
+// leg that failed (1, 2, 3) with parts[i].rc as status_round sets it
+// (0 ok; >0 peer status; -1 socket; -2 protocol); bad arguments fail
+// leg 2 (whose precondition they break) with every rc -2, before a
+// byte is sent.
 int lz_write_parts_exchange(lz_part_req* parts, uint32_t n,
                             const uint8_t* const* init_frames,
                             const uint32_t* init_lens,
@@ -931,9 +912,10 @@ int lz_write_parts_exchange(lz_part_req* parts, uint32_t n,
 
 // --- windowed / vectored scatter writes ------------------------------------
 //
-// lz_write_parts_scatterv is the vectored successor of
-// lz_write_parts_scatter: frames are part-addressed (type 1215), so
-// several parts of one chunk can multiplex ONE connection to their
+// lz_write_parts_scatterv sends one segment of a chunk's parts over
+// connections a session keeps open (PartsScatterSession: one handshake
+// pair a chunk, many segments): frames are part-addressed (type 1215),
+// so several parts of one chunk can multiplex ONE connection to their
 // shared chunkserver; header + payload leave through a single
 // scatter-gather sendmsg per socket pass (no separate header syscall,
 // no payload staging copy); and with kScatterNoAck the call returns as

@@ -67,10 +67,20 @@ async def test_flaky_chunkserver_demoted(tmp_path):
         for _ in range(3):
             c.cache.invalidate(f.inode)
             assert await c.read_file(f.inode) == payload
-        after = served_bytes()
-        delta = {p: after[p] - before[p] for p in after}
         healthy_port = addrs[1][1]
         flaky_port = addrs[0][1]
+        # a serve thread counts its bytes after the last send returns:
+        # the reader can hold the answer before the counter moves, so
+        # wait for the count itself (it cost a whole run its exit code
+        # when a loaded host parked that thread in between)
+        deadline = asyncio.get_running_loop().time() + 20.0
+        while True:
+            after = served_bytes()
+            delta = {p: after[p] - before[p] for p in after}
+            if (delta[healthy_port] >= 3 * len(payload)
+                    or asyncio.get_running_loop().time() >= deadline):
+                break
+            await asyncio.sleep(0.02)
         assert delta[healthy_port] >= 3 * len(payload)
         assert delta[flaky_port] == 0
     finally:

@@ -570,9 +570,11 @@ async def test_cancelled_striped_write_does_not_pool_staging(
     await cluster.start()
     try:
         c = await cluster.client()
-        # pin the scatter-batch (serial) path: the pipelined path has
+        # pin the whole-part batches through _pipeline_eligible's own
+        # input (a payload under the minimum): the windowed path has
         # its own session sender and is exercised below
-        c.write_pipeline = False
+        min_bytes = c.WRITE_PIPELINE_MIN_BYTES
+        c.WRITE_PIPELINE_MIN_BYTES = MFSCHUNKSIZE + 1
         f = await c.create(1, "pool.bin")
         await c.setgoal(f.inode, EC_GOAL)
         full = data_generator.generate(21, MFSCHUNKSIZE).tobytes()
@@ -619,13 +621,11 @@ async def test_cancelled_striped_write_does_not_pool_staging(
         assert any(cl and cl.get("aborted") for cl in seen_cells), \
             "cancelled write did not abort its in-flight sender"
 
-        # 3) same invariant for the PIPELINED/WINDOWED sender: a
-        # cancelled session segment must abort its cell and keep both
-        # the stage and the parity send buffer out of the pool (the
-        # windowed default sends via send_segment_window, the kill-
-        # switch path via send_segment — hang whichever engages)
+        # 3) same invariant for the WINDOWED sender: a cancelled
+        # session segment must abort its cell and keep both the stage
+        # and the parity send buffer out of the pool
         monkeypatch.undo()
-        c.write_pipeline = True
+        c.WRITE_PIPELINE_MIN_BYTES = min_bytes
         started3 = threading.Event()
         cells3: list[dict] = []
 
@@ -640,9 +640,6 @@ async def test_cancelled_striped_write_does_not_pool_staging(
             self.close()
             raise native_io.NativeIOError(-1, "hung segment aborted")
 
-        monkeypatch.setattr(
-            native_io.PartsScatterSession, "send_segment", hang_segment
-        )
         monkeypatch.setattr(
             native_io.PartsScatterSession, "send_segment_window",
             hang_segment,
@@ -662,7 +659,7 @@ async def test_cancelled_striped_write_does_not_pool_staging(
         assert sum(len(b) for b in c._stage_buffers.values()) == 0, \
             "buffers pooled while a zombie session sender may hold them"
         assert any(cl and cl.get("aborted") for cl in cells3), \
-            "cancelled pipelined write did not abort its session"
+            "cancelled windowed write did not abort its session"
     finally:
         await cluster.stop()
 
@@ -753,7 +750,6 @@ async def test_shm_ring_engages(tmp_path):
     try:
         c = await cluster.client()
         c.WRITE_PIPELINE_MIN_BYTES = 1
-        assert c.write_window is not None
         await _striped_roundtrip(cluster, c, "ring.bin", 8 * 2**20)
         assert c.op_counters.get("write_shm", 0) >= 1, \
             "shm ring path did not engage"
@@ -784,7 +780,6 @@ async def test_shm_ring_engages_on_asyncio_chunkserver(tmp_path):
     try:
         c = await cluster.client()
         c.WRITE_PIPELINE_MIN_BYTES = 1
-        assert c.write_window is not None
         await _striped_roundtrip(cluster, c, "pyring2.bin", 8 * 2**20)
         assert c.op_counters.get("write_shm", 0) >= 1, \
             "shm ring path did not engage against the asyncio plane"

@@ -457,7 +457,7 @@ def _lzshm_mappings(pid: int) -> int:
         return 0
 
 
-def _data_uds_ports(before: set[str] | None = None) -> set[str]:
+def _data_uds_ports() -> set[str]:
     """Abstract data-plane listener ports visible on this host
     (serve_native.cpp binds @lzfs-data-<host>-<port>)."""
     out = set()
@@ -470,7 +470,28 @@ def _data_uds_ports(before: set[str] | None = None) -> set[str]:
                     out.add(line[idx + len(marker):].strip())
     except OSError:
         pass
-    return out - (before or set())
+    return out
+
+
+async def _cs_data_port(cluster) -> int:
+    """The data port the master hands out for the cluster's one
+    chunkserver. Asked of the master, not read off the host's socket
+    table: /proc/net/unix lists every worker's chunkservers, and a
+    listener another test bound meanwhile was taken for this one's."""
+    import json
+
+    from lizardfs_tpu.proto import framing
+    from lizardfs_tpu.proto import messages as m
+
+    r, w = await asyncio.open_connection("127.0.0.1", cluster.master_port)
+    try:
+        await framing.send_message(w, m.AdminInfo(req_id=1))
+        reply = await asyncio.wait_for(framing.read_message(r), 10.0)
+    finally:
+        w.close()
+    (cs,) = [s for s in json.loads(reply.json)["chunkservers"]
+             if s["connected"] and not s.get("mirror")]
+    return cs["data_port"]
 
 
 async def test_shm_segment_lifecycle_survives_peer_sigkill(tmp_path):
@@ -482,13 +503,12 @@ async def test_shm_segment_lifecycle_survives_peer_sigkill(tmp_path):
 
     if not native_io.parts_shm_available():
         pytest.skip("native shm ring not built")
-    ports_before = _data_uds_ports()
     cluster = ProcCluster(tmp_path, n_cs=1)
     try:
         await cluster.start()
-        ports = _data_uds_ports(ports_before)
-        assert ports, "chunkserver bound no abstract data listener"
-        port = sorted(ports)[0]
+        port = await _cs_data_port(cluster)
+        assert str(port) in _data_uds_ports(), \
+            "chunkserver bound no abstract data listener"
         cs_pid = cluster.procs["cs0"].pid
         assert _lzshm_mappings(cs_pid) == 0
 
@@ -514,7 +534,7 @@ async def test_shm_segment_lifecycle_survives_peer_sigkill(tmp_path):
                 )
                 assert b"MAPPED" in line, "helper never mapped a ring"
                 # the segment is live in the SERVER's address space now
-                for _ in range(100):
+                for _ in range(300):
                     if _lzshm_mappings(cs_pid) > 0:
                         break
                     await asyncio.sleep(0.1)
@@ -523,7 +543,7 @@ async def test_shm_segment_lifecycle_survives_peer_sigkill(tmp_path):
             finally:
                 helper.send_signal(signal.SIGKILL)
                 helper.wait(timeout=10)
-            for _ in range(100):
+            for _ in range(300):
                 if _lzshm_mappings(cs_pid) == 0:
                     break
                 await asyncio.sleep(0.1)

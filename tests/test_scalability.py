@@ -102,6 +102,10 @@ async def test_forked_dump_does_not_stall_loop(tmp_path, monkeypatch):
     # not the serialization). The test process has jax loaded, which the
     # fork gate refuses (tests/test_fork_safety.py covers that side), so
     # force the gate open here.
+    # The stall is read on the loop thread's own CPU clock: what the
+    # loop spends between two ticks is what its code made it do, where a
+    # wall-clock gap also counts every slice a loaded host gives to
+    # someone else (it cost whole runs their exit code under -n 6).
     from lizardfs_tpu.master import server as msrv
 
     monkeypatch.setattr(msrv, "_fork_safe", lambda: True)
@@ -109,29 +113,31 @@ async def test_forked_dump_does_not_stall_loop(tmp_path, monkeypatch):
     await master.start()
     try:
         _populate(master.meta, n_files=50_000)
-        # how long a synchronous serialization would block
-        t0 = time.perf_counter()
+        # how long a synchronous serialization would hold the loop
+        t0 = time.thread_time()
         master.meta.to_sections()
-        sync_cost = time.perf_counter() - t0
+        sync_cost = time.thread_time() - t0
 
         gaps = []
 
         async def ticker():
-            prev = time.perf_counter()
+            prev = time.thread_time()
             while True:
                 await asyncio.sleep(0.005)
-                now = time.perf_counter()
-                gaps.append(now - prev - 0.005)
+                now = time.thread_time()
+                gaps.append(now - prev)
                 prev = now
 
         t = asyncio.ensure_future(ticker())
         await asyncio.sleep(0.05)
-        await master._dump_image()
+        await asyncio.wait_for(master._dump_image(), 120.0)
         t.cancel()
         worst = max(gaps)
-        # the loop may pause for the fork itself, never for the full
-        # serialization
-        assert worst < max(0.1, sync_cost / 4), (
+        # the loop may pause for the fork itself (the page tables of a
+        # process with jax loaded: a fifth to a quarter of sync_cost on
+        # a loaded host, both on the same clock), never for the full
+        # serialization, which would read sync_cost or more
+        assert worst < max(0.1, sync_cost / 2), (
             f"loop stalled {worst*1e3:.0f} ms during dump "
             f"(sync serialization would be {sync_cost*1e3:.0f} ms)"
         )
