@@ -24,9 +24,12 @@ class BlockCache:
 
     - the master pushes ``MatoclCacheInvalidate`` when ANOTHER session
       mutates the file -> ``invalidate()``;
-    - every locate returns (chunk_id, version); ``note_version()`` drops
-      blocks cached under a different identity, so even a missed push is
-      caught at the next locate;
+    - every locate returns (chunk_id, version, content_gen);
+      ``note_version()`` drops blocks cached under a different identity,
+      so even a missed push is caught at the next locate. The content
+      generation is the component every completed write changes (the
+      version rises only where a copy may have missed a write); it is
+      the file's, so a write to one chunk revalidates them all;
     - entries expire after ``max_age`` seconds as the last-resort bound
       (e.g. this client's master connection dropped mid-push).
     """
@@ -45,6 +48,9 @@ class BlockCache:
         # (inode, ci) -> resident blocks, so note_version/invalidate
         # touch only their own chunk instead of scanning every entry
         self._chunk_blocks: dict[tuple[int, int], set[int]] = {}
+        # (inode, ci) -> when a read of the chunk last went to the
+        # chunkservers on a cached locate (note_unlocated_fetch)
+        self._suspect_since: dict[tuple[int, int], float] = {}
         # (inode, ci) -> last version tag seen by a locate; LRU-bounded
         # (evicting a note only costs a skipped cache fill — see put())
         self._versions: OrderedDict[tuple[int, int], object] = OrderedDict()
@@ -68,6 +74,7 @@ class BlockCache:
             blocks.discard(key[2])
             if not blocks:
                 del self._chunk_blocks[key[:2]]
+                self._suspect_since.pop(key[:2], None)
 
     def get(self, inode: int, ci: int, block: int) -> bytes | None:
         key = (inode, ci, block)
@@ -104,10 +111,43 @@ class BlockCache:
         while self._used > self.max_bytes and self._entries:
             self._remove(next(iter(self._entries)))
 
-    def note_version(self, inode: int, ci: int, version: object) -> None:
+    def now(self) -> float:
+        """The clock fills and locates are ordered on."""
+        return self._now()
+
+    def note_unlocated_fetch(self, inode: int, ci: int) -> None:
+        """A read of the chunk goes to the chunkservers on a CACHED
+        locate: nothing the master said vouches for the chunk as of
+        now, so blocks filled before now become suspect."""
+        if (inode, ci) in self._chunk_blocks:
+            self._suspect_since[(inode, ci)] = self._now()
+
+    def is_suspect(self, inode: int, ci: int, lo: int, hi: int) -> bool:
+        """Whether a resident block in [lo, hi] was filled before the
+        chunk's latest unlocated fetch, with no locate asked since
+        then: the caller asks the master before it serves one."""
+        since = self._suspect_since.get((inode, ci))
+        if since is None:
+            return False
+        for b in range(lo, hi + 1):
+            entry = self._entries.get((inode, ci, b))
+            if entry is not None and entry[1] < since:
+                return True
+        return False
+
+    def note_version(
+        self, inode: int, ci: int, version: object,
+        asked: float | None = None,
+    ) -> None:
         """Record the chunk identity a locate just returned; drop any
-        blocks cached under a different one (stale by definition)."""
+        blocks cached under a different one (stale by definition).
+        ``asked``: when that locate was sent, if it was sent for this
+        (a cached reply vouches for nothing new): the blocks that stay
+        are current as of then, and an unlocated fetch no later than
+        that makes them suspect no longer."""
         key = (inode, ci)
+        if asked is not None and self._suspect_since.get(key, asked + 1) <= asked:
+            del self._suspect_since[key]
         if self._versions.get(key) == version:
             self._versions.move_to_end(key)
             return

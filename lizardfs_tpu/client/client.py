@@ -1503,8 +1503,10 @@ class Client:
             try:
                 # a failed attempt can leave parts torn (some written,
                 # some not, parity stale); each retry takes a FRESH grant
-                # — the version bump drops unreachable holders and the
-                # full region rewrite restores stripe consistency on the
+                # — the failed attempt's error end (or its missing end)
+                # makes that grant raise the version, which drops
+                # unreachable holders, and the full region rewrite
+                # restores stripe consistency on the
                 # survivors. The RMW read-back happens ONCE and is
                 # reused across retries (rmw_cache): a retry that
                 # re-read the region would decode a MIX of first-attempt
@@ -2683,6 +2685,8 @@ class Client:
         lo_b = off // MFSBLOCKSIZE
         hi_b = (off + size - 1) // MFSBLOCKSIZE
         if not bulk:
+            if self.cache.is_suspect(inode, chunk_index, lo_b, hi_b):
+                await self._revalidate_blocks(inode, chunk_index)
             # cache fast path: all covering blocks resident
             cached = [
                 self.cache.get(inode, chunk_index, b)
@@ -2723,6 +2727,7 @@ class Client:
                     await asyncio.sleep(min(0.1 * 2 ** attempt, 2.0))
             loc = None
             fresh = False
+            asked = None  # when a locate was sent for this attempt
             if attempt == 0:
                 cached = self._locate_cache.get((inode, chunk_index))
                 if (cached is not None and _time.monotonic() - cached[1]
@@ -2731,9 +2736,14 @@ class Client:
                     self.op_counters["locate_cache_hit"] = (
                         self.op_counters.get("locate_cache_hit", 0) + 1
                     )
+                    # nobody vouches for this fetch's view of the
+                    # chunk: blocks cached before it are asked about
+                    # before they are served again (_revalidate_blocks)
+                    self.cache.note_unlocated_fetch(inode, chunk_index)
             if loc is None:
                 with tracing.span("locate", phase="locate",
                                   bucket="net") as locate_span:
+                    asked = self.cache.now()
                     token = self._locate_token(inode)
                     # first attempt may serve the locate from a replica;
                     # RETRY locates go to the primary — a failed read may
@@ -2764,21 +2774,11 @@ class Client:
                     # locate phase: the master round trip(s), replica
                     # fallback included; cache hits charge nothing
                     self._note_srv(locate_span, "locate_srv", loc)
-                if self._locate_token(inode) == token:
-                    # refuse stores that raced an invalidation: the
-                    # reply may predate the mutation that bumped epoch
-                    # (the token folds in the clear generation, so a
-                    # bulk clear can never alias an old epoch value)
-                    self._locate_cache[(inode, chunk_index)] = (
-                        loc, _time.monotonic()
-                    )
-                    if len(self._locate_cache) > 4096:
-                        self._locate_cache.clear()  # crude bound
-            # revalidate cached blocks against the chunk identity this
-            # locate returned: a rewrite bumps the version, a truncate+
-            # regrow swaps the chunk_id — either way stale blocks drop
-            chunk_tag = (loc.chunk_id, loc.version)
-            self.cache.note_version(inode, chunk_index, chunk_tag)
+                    if getattr(loc, "_replica_served", False):
+                        asked = None  # may lag: vouches for no block
+                self._store_locate(inode, chunk_index, loc, token)
+            chunk_tag = self._chunk_tag(loc)
+            self.cache.note_version(inode, chunk_index, chunk_tag, asked=asked)
             if file_length is None or (
                 fresh and loc.file_length > file_length
             ):
@@ -2858,6 +2858,56 @@ class Client:
             rel = off - aligned_off
             return data[rel : rel + size]
         raise st.StatusError(st.EIO, f"read failed after retries: {last_error}")
+
+    def _store_locate(self, inode: int, chunk_index: int, loc,
+                      token: tuple[int, int]) -> None:
+        """Cache a locate reply, unless an invalidation raced the RPC:
+        the reply may then predate the mutation that bumped the epoch
+        (``token`` folds in the clear generation, so a bulk clear can
+        never alias an old epoch value)."""
+        if self._locate_token(inode) == token:
+            self._locate_cache[(inode, chunk_index)] = (
+                loc, _time.monotonic()
+            )
+            if len(self._locate_cache) > 4096:
+                self._locate_cache.clear()  # crude bound
+
+    @staticmethod
+    def _chunk_tag(loc) -> tuple[int, int, int]:
+        """The identity cached blocks are revalidated against at every
+        locate: a truncate+regrow swaps the chunk_id, a grant that could
+        not vouch for every copy raises the version, and every completed
+        write raises the inode's content generation (0 from a master
+        that predates the field, whose version rises with every write).
+        The generation is the file's, not the chunk's: a write to any
+        chunk of the file drops this chunk's blocks too."""
+        return (loc.chunk_id, loc.version, getattr(loc, "content_gen", 0))
+
+    async def _revalidate_blocks(self, inode: int, chunk_index: int) -> None:
+        """Cached blocks of the chunk are about to be served that were
+        filled BEFORE a later read of it went to the chunkservers on a
+        cached locate: ask the master first, so that blocks a write has
+        overtaken drop even where its invalidation push was missed.
+        While every grant raised the version, that later read failed
+        with WRONG_VERSION and its retry's locate dropped them; a
+        version that rises only where a copy may be stale no longer
+        tells the reader, and its content generation has to. A stream
+        never comes back to such blocks and never asks. The primary is
+        asked, as that retry did: a replica that lags may not have
+        replayed the write yet."""
+        with tracing.span("locate", phase="locate",
+                          bucket="net") as locate_span:
+            asked = self.cache.now()
+            token = self._locate_token(inode)
+            loc = await self._call(
+                m.CltomaReadChunk, inode=inode, chunk_index=chunk_index,
+                **self._ident(None, None),
+            )
+            self._note_srv(locate_span, "locate_srv", loc)
+        self._store_locate(inode, chunk_index, loc, token)
+        self.cache.note_version(
+            inode, chunk_index, self._chunk_tag(loc), asked=asked
+        )
 
     async def _send_prefetch(self, loc, chunk_off: int, size: int) -> None:
         """Fire-and-forget CltocsPrefetch to the data-part holders for

@@ -51,6 +51,24 @@ class ChunkServerInfo:
         return max(self.total_space - self.used_space, 0)
 
 
+@dataclass(slots=True)
+class WriteState:
+    """What the ACTIVE master has seen of one chunk's writes in its
+    current active life: enough to know whether every holder has every
+    acknowledged write, in which case the next write grant leaves the
+    version where it is (chunks.cc ``needverincrease`` analog). Volatile:
+    never journaled, never in the image; a chunk without one (fresh
+    ChunkInfo, restart, promoted shadow) counts as not clean."""
+
+    life: int  # ChunkRegistry.life it was made in; stale = forgotten
+    # counts every change of ``parts`` and every copy started from them
+    touched: int = 0
+    # why the next grant must raise the version; "" = it need not
+    dirty: str = "no_end"
+    # the grant outstanding: (session_id, ``touched`` when it was given)
+    grant: tuple[int, int] | None = None
+
+
 @dataclass
 class ChunkInfo:
     chunk_id: int
@@ -68,6 +86,8 @@ class ChunkInfo:
     locked_until: float = 0.0
     # live locations: (cs_id, slice part index) set; volatile
     parts: set[tuple[int, int]] = field(default_factory=set)
+    # volatile, None until this master grants a write on the chunk
+    writes: WriteState | None = None
 
     def parts_by_index(self) -> dict[int, list[int]]:
         out: dict[int, list[int]] = {}
@@ -179,6 +199,10 @@ class ChunkRegistry:
         # LZ_HEAT-off state — means pure free-space weighting, the
         # pre-heat behavior, byte for byte.
         self.server_load: dict[int, float] = {}
+        # this master's active life: a WriteState made in another one
+        # (before a demotion) knows nothing of the writes the other
+        # master granted meanwhile, and is forgotten (forget_writes)
+        self.life = 1
 
     # --- chunkserver db -------------------------------------------------------
 
@@ -238,6 +262,7 @@ class ChunkRegistry:
             cs_id, {}
         ).items():
             chunk.parts.discard((cs_id, part))
+            self.touch(chunk)
             append(chunk_id)
         return affected
 
@@ -306,6 +331,7 @@ class ChunkRegistry:
         self._server_parts.setdefault(cs_id, {})[
             (chunk.chunk_id, part)
         ] = chunk
+        self.touch(chunk)
 
     def unregister_parts(
         self, chunk: ChunkInfo, stale: set[tuple[int, int]]
@@ -317,6 +343,7 @@ class ChunkRegistry:
             idx = self._server_parts.get(cs_id)
             if idx is not None:
                 idx.pop((chunk.chunk_id, part), None)
+        self.touch(chunk)
 
     def drop_part(self, chunk_id: int, cs_id: int, part_id: int) -> None:
         chunk = self.chunks.get(chunk_id)
@@ -327,6 +354,71 @@ class ChunkRegistry:
         idx = self._server_parts.get(cs_id)
         if idx is not None:
             idx.pop((chunk_id, cpt.part), None)
+        self.touch(chunk)
+
+    # --- which write grants must raise the version --------------------------------
+
+    def _writes_of(self, chunk: ChunkInfo) -> WriteState | None:
+        ws = chunk.writes
+        return ws if ws is not None and ws.life == self.life else None
+
+    def touch(self, chunk: ChunkInfo) -> None:
+        """The chunk's holder set changed, or a copy is about to be made
+        from it (replication, rebuild, move): whatever the write state
+        vouched for, it no longer does. Every mutator of ``parts`` calls
+        this, changed or not: a call too many costs one version bump."""
+        ws = chunk.writes
+        if ws is not None:
+            ws.touched += 1
+            if not ws.dirty:
+                ws.dirty = "holders_changed"
+
+    def grant_needs_bump(self, chunk: ChunkInfo) -> str:
+        """Why a write grant on this chunk must raise its version first
+        (``first_grant``, ``error_end``, ``no_end``, ``holders_changed``),
+        or "" where every holder has every acknowledged write: the last
+        grant was ended clean by its own session with the holder set
+        untouched since it was given."""
+        ws = self._writes_of(chunk)
+        return "first_grant" if ws is None else ws.dirty
+
+    def note_grant(self, chunk: ChunkInfo, session_id: int) -> None:
+        """A write is in flight from here on: not clean until its own
+        clean end, so a client that dies or a lock that runs out needs
+        no code at all."""
+        ws = self._writes_of(chunk)
+        if ws is None:
+            ws = chunk.writes = WriteState(self.life)
+        ws.grant = (session_id, ws.touched)
+        ws.dirty = "no_end"
+
+    def note_write_end(self, chunk: ChunkInfo, session_id: int,
+                       ok: bool) -> None:
+        """One WriteChunkEnd (or EndBatch entry). Only a clean end of
+        the outstanding grant, from the session it was given to, with
+        the holder set untouched since, makes the chunk clean. An end
+        names no grant: it is taken for its session's latest, since a
+        session's RPCs arrive in order and a client ends a grant before
+        it asks the next on the same chunk (client.py
+        ``_chunk_write_locks``; the C client is synchronous). An error
+        end from anyone makes the chunk not clean."""
+        ws = self._writes_of(chunk)
+        if ws is None:
+            return
+        if not ok:
+            ws.dirty, ws.grant = "error_end", None
+        elif ws.grant is not None and ws.grant[0] == session_id:
+            ws.dirty = (
+                "" if ws.grant[1] == ws.touched else "holders_changed"
+            )
+            ws.grant = None
+
+    def forget_writes(self) -> None:
+        """This master stops or starts being the active one: what it
+        knew of its chunks' writes says nothing of the writes another
+        master grants meanwhile. O(1): states of an older life are
+        ignored and replaced at their chunk's next grant."""
+        self.life += 1
 
     def record_stale(
         self, chunk_id: int, cs_id: int, part_id: int, version: int

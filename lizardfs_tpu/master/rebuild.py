@@ -58,7 +58,7 @@ class Rebuild:
     kind: str = "replicate"  # "replicate" | "move"
     bytes_est: int = 0
     src_cs: int = 0  # moves: the holder being drained
-    dst_cs: int = 0  # moves: the target
+    dst_cs: int = 0  # the target (a replicate's once it is chosen)
     trace_id: int = 0
     queued_at: float = field(default_factory=time.monotonic)
     started_at: float = 0.0

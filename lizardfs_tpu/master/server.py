@@ -9,8 +9,10 @@ with their first message (register), then stay in a per-role loop.
 
 Write-path protocol (fuse_write_chunk analog, matoclserv.cc:2938):
   WriteChunk -> create chunk (choose servers per part, command creates)
-                or bump version on existing parts; lock; reply locations
-  WriteChunkEnd -> set file length, unlock, changelog.
+                or, where a copy may have missed a write, bump version
+                on existing parts; lock; reply locations
+  WriteChunkEnd -> set file length, unlock, changelog; a clean end of
+                the outstanding grant lets the next grant skip the bump.
 
 Health loop (ChunkWorker analog, chunks.cc:1807): every tick, serve the
 endangered queue first, then walk chunks; replicate missing parts
@@ -21,6 +23,7 @@ redundant ones.
 from __future__ import annotations
 
 import asyncio
+import collections
 import json
 import logging
 import os
@@ -862,7 +865,7 @@ class MasterServer(Daemon):
                 tid = getattr(msg, "trace_id", 0)
                 self.trace_ring.record(
                     tid, type(msg).__name__, tw0, time.time(), role="master",
-                    bucket="compute",
+                    bucket="compute", **getattr(reply, "span_attrs", {}),
                 )
                 self._stamp_srv(reply, dt)
                 # per-session accounting: the same op charged to its
@@ -1886,7 +1889,7 @@ class MasterServer(Daemon):
             self._invalidate_client_caches(
                 msg.inode, msg.chunk_index, exclude_sid=session_id
             )
-            return await self._write_chunk_end(msg)
+            return await self._write_chunk_end(msg, session_id)
         if isinstance(msg, m.CltomaWriteChunkEndBatch):
             # coalesced commit: seal every chunk the client's write
             # window finished since its last flush — one round trip
@@ -1914,7 +1917,8 @@ class MasterServer(Daemon):
                 )
                 try:
                     self._apply_write_chunk_end(
-                        e.chunk_id, e.inode, e.file_length, e.status
+                        e.chunk_id, e.inode, e.file_length, e.status,
+                        session_id,
                     )
                 except fsmod.FsError as err:
                     if status == st.OK:
@@ -2508,6 +2512,9 @@ class MasterServer(Daemon):
             req_id=msg.req_id, status=st.OK, chunk_id=chunk_id,
             version=chunk.version, file_length=node.length,
             locations=self._locations_of(chunk, client_ip),
+            # what still changes with every completed write, now that
+            # the version need not: the reader's block-cache tag
+            content_gen=self.meta.content_gen.get(msg.inode, 0),
         )
 
     async def _write_chunk(self, msg: m.CltomaWriteChunk,
@@ -2525,8 +2532,12 @@ class MasterServer(Daemon):
         chunk_id = (
             node.chunks[msg.chunk_index] if msg.chunk_index < len(node.chunks) else 0
         )
+        self.metrics.counter(
+            "write_grants",
+            "write grants asked for (a busy chunk's refusal included)",
+        ).inc()
         if chunk_id == 0:
-            return await self._create_new_chunk(msg, node)
+            return await self._create_new_chunk(msg, node, session_id)
         chunk = self.meta.registry.chunk(chunk_id)
         if constants_mod.heat_enabled():
             # chunk-kind heat, ops only (bytes ride the CS folds)
@@ -2538,9 +2549,43 @@ class MasterServer(Daemon):
             )
         if chunk.refcount > 1:
             # snapshot-shared chunk: copy-on-write before mutating
-            return await self._cow_chunk(msg, node, chunk)
-        # version bump so stale copies are detectable (chunk lock + bump,
-        # matoclserv.cc fuse_write_chunk semantics)
+            return await self._cow_chunk(msg, node, chunk, session_id)
+        registry = self.meta.registry
+        why = registry.grant_needs_bump(chunk)
+        status = st.OK
+        if why:
+            self.metrics.labeled_counter(
+                "write_grant_bumps", {"why": why},
+                "write grants that raised the chunk's version first, by "
+                "why the master could not vouch for every copy",
+            ).inc()
+            status = await self._bump_version(chunk)
+        # else every holder has every acknowledged write (the last grant
+        # was ended clean by its own session, the holder set untouched
+        # since): a version that rose would detect nothing, and the
+        # grant commands no chunkserver (chunks.cc needverincrease)
+        if status == st.OK:
+            registry.note_grant(chunk, session_id)
+            chunk.locked_until = time.monotonic() + CHUNK_LOCK_SECONDS
+            reply = m.MatoclWriteChunk(
+                req_id=msg.req_id, status=st.OK, chunk_id=chunk_id,
+                version=chunk.version, file_length=node.length,
+                locations=self._locations_of(chunk),
+            )
+        else:
+            reply = self._error_reply(msg, status)
+        # rides this RPC's span in the master's ring (_client_loop)
+        reply.span_attrs = {"bumped": int(bool(why))}
+        return reply
+
+    async def _bump_version(self, chunk) -> int:
+        """Raise the chunk's version on every holder so that a copy
+        which may have missed a write is detectably stale (chunk lock +
+        bump, matoclserv.cc fuse_write_chunk semantics): holders that
+        miss the bump are dropped, the rest journaled at the new
+        version. Taken by a write grant only where the master cannot
+        vouch for every holder (ChunkRegistry.grant_needs_bump)."""
+        chunk_id = chunk.chunk_id
         new_version = chunk.version + 1
         holders = sorted(chunk.parts)
         t = geometry.SliceType(chunk.slice_type)
@@ -2574,10 +2619,7 @@ class MasterServer(Daemon):
             if reply.status == st.OK:
                 ok_holders.append((cs_id, part))
         if not ok_holders:
-            return m.MatoclWriteChunk(
-                req_id=msg.req_id, status=st.NO_CHUNK_SERVERS, chunk_id=0,
-                version=0, file_length=0, locations=[],
-            )
+            return st.NO_CHUNK_SERVERS
         # copies that missed the bump are stale: unregister them so the
         # reply's locations are all at new_version, and queue re-repair
         stale = chunk.parts - set(ok_holders)
@@ -2587,14 +2629,10 @@ class MasterServer(Daemon):
         self.commit({
             "op": "bump_chunk_version", "chunk_id": chunk_id, "version": new_version,
         })
-        chunk.locked_until = time.monotonic() + CHUNK_LOCK_SECONDS
-        return m.MatoclWriteChunk(
-            req_id=msg.req_id, status=st.OK, chunk_id=chunk_id,
-            version=new_version, file_length=node.length,
-            locations=self._locations_of(chunk),
-        )
+        return st.OK
 
-    async def _cow_chunk(self, msg: m.CltomaWriteChunk, node, chunk):
+    async def _cow_chunk(self, msg: m.CltomaWriteChunk, node, chunk,
+                         session_id: int = 0):
         """Duplicate a snapshot-shared chunk on its part holders, point
         the file at the private copy, then grant the write on it."""
         new_id = self.meta.registry.next_chunk_id
@@ -2656,6 +2694,7 @@ class MasterServer(Daemon):
         new_chunk = self.meta.registry.chunk(new_id)
         for cs_id, part in created:
             self.meta.registry.record_part(new_chunk, cs_id, part)
+        self.meta.registry.note_grant(new_chunk, session_id)
         new_chunk.locked_until = time.monotonic() + CHUNK_LOCK_SECONDS
         if self.meta.registry.evaluate(new_chunk).needs_work:
             self.meta.registry.mark_endangered(new_id)
@@ -2693,7 +2732,8 @@ class MasterServer(Daemon):
             for p in part_list
         ]
 
-    async def _create_new_chunk(self, msg: m.CltomaWriteChunk, node):
+    async def _create_new_chunk(self, msg: m.CltomaWriteChunk, node,
+                                session_id: int = 0):
         t = self._slice_type_for_goal(node.goal)
         goal = self.goals.get(node.goal)
         copies = goal.expected_copies() if (goal and t.is_standard) else 1
@@ -2769,27 +2809,34 @@ class MasterServer(Daemon):
         chunk = self.meta.registry.chunk(chunk_id)
         for part, srv in created:
             self.meta.registry.record_part(chunk, srv.cs_id, part)
+        self.meta.registry.note_grant(chunk, session_id)
         chunk.locked_until = time.monotonic() + CHUNK_LOCK_SECONDS
         return m.MatoclWriteChunk(
             req_id=msg.req_id, status=st.OK, chunk_id=chunk_id, version=version,
             file_length=node.length, locations=self._locations_of(chunk),
         )
 
-    async def _write_chunk_end(self, msg: m.CltomaWriteChunkEnd):
+    async def _write_chunk_end(self, msg: m.CltomaWriteChunkEnd,
+                               session_id: int = 0):
         self._apply_write_chunk_end(
-            msg.chunk_id, msg.inode, msg.file_length, msg.status
+            msg.chunk_id, msg.inode, msg.file_length, msg.status, session_id
         )
         return m.MatoclStatusReply(req_id=msg.req_id, status=st.OK)
 
     def _apply_write_chunk_end(
-        self, chunk_id: int, inode: int, file_length: int, status: int
+        self, chunk_id: int, inode: int, file_length: int, status: int,
+        session_id: int = 0,
     ) -> None:
-        """Seal one chunk's write: unlock, re-evaluate redundancy, and
-        (on a clean end) journal the length/mtime. Shared by the
-        per-chunk RPC and the coalesced CltomaWriteChunkEndBatch."""
+        """Seal one chunk's write: unlock, note whether the next grant
+        must raise the version, re-evaluate redundancy, and (on a clean
+        end) journal the length/mtime. Shared by the per-chunk RPC and
+        the coalesced CltomaWriteChunkEndBatch."""
         chunk = self.meta.registry.chunks.get(chunk_id)
         if chunk is not None:
             chunk.locked_until = 0.0
+            self.meta.registry.note_write_end(
+                chunk, session_id, status == st.OK
+            )
             state = self.meta.registry.evaluate(chunk)
             if state.needs_work:
                 self.meta.registry.mark_endangered(chunk_id)
@@ -3744,25 +3791,45 @@ class MasterServer(Daemon):
         tw0 = time.time()
         try:
             t = geometry.SliceType(chunk.slice_type)
-            holders = {cs for cs, _ in chunk.parts}
+            registry = self.meta.registry
+            # how many of the chunk's parts each server holds, counting
+            # what the chunk's other rebuilds in flight have chosen:
+            # they pick at once, and two picks of one server stack
+            # parts there that a free server would have taken
+            crowd = collections.Counter(cs for cs, _ in chunk.parts)
+            crowd.update(
+                other.dst_cs for other in self.rebuild.active.values()
+                if other.chunk_id == chunk.chunk_id and other is not rb
+                and other.dst_cs
+            )
             label = self._labels_for_goal(chunk.goal_id, t, [part])[0]
             try:
-                target = self.meta.registry.choose_servers(
-                    1, exclude=holders, labels=[label]
+                target = registry.choose_servers(
+                    1, exclude=set(crowd), labels=[label]
                 )[0]
             except ValueError:
                 # every connected server already holds some part (e.g.
                 # ec(3,2) on 5 servers after one died). Doubling up on a
                 # server that lacks THIS part beats leaving the chunk
                 # endangered forever — the reference fills goals with
-                # repeats too when servers run short.
+                # repeats too when servers run short. Of those servers,
+                # one that holds the fewest: three parts of an ec(3,2)
+                # chunk on one of three survivors, where 2/2/1 was to
+                # be had, is a chunk that server's loss would take
                 same_part = {cs for cs, p in chunk.parts if p == part}
+                fewest = min(
+                    (crowd[s.cs_id] for s in registry.connected_servers()
+                     if s.cs_id not in same_part),
+                    default=0,
+                )
+                crowded = {cs for cs, n in crowd.items() if n > fewest}
                 try:
-                    target = self.meta.registry.choose_servers(
-                        1, exclude=same_part, labels=[label]
+                    target = registry.choose_servers(
+                        1, exclude=same_part | crowded, labels=[label]
                     )[0]
                 except ValueError:
                     return
+            rb.dst_cs = target.cs_id
             link = self.cs_links.get(target.cs_id)
             if link is None:
                 return
@@ -3777,6 +3844,9 @@ class MasterServer(Daemon):
             if chunk.locked_until > time.monotonic():
                 return
             attempted = True
+            # a copy is made from the holders from here on: a write
+            # granted meanwhile must raise the version under it
+            self.meta.registry.touch(chunk)
             try:
                 reply = await link.command(
                     m.MatocsReplicate,
@@ -3862,6 +3932,9 @@ class MasterServer(Daemon):
             part_id = geometry.ChunkPartType(t, part).id
             await self.rebuild.throttle(rb.bytes_est)
             attempted = True
+            # as in _replicate_part: a write granted under the copy
+            # raises the version, which the check below then sees
+            self.meta.registry.touch(chunk)
             try:
                 reply = await link.command(
                     m.MatocsReplicate,
@@ -4207,6 +4280,7 @@ class MasterServer(Daemon):
         if self.personality == "master":
             return
         self.personality = "master"
+        self.meta.registry.forget_writes()
         self._follow_connected = False
         if self._shadow_task is not None:
             self._shadow_task.cancel()

@@ -319,6 +319,12 @@ class MatoclReadChunk(Message):
     # microseconds (0 from a master that predates it); the client lays
     # it inside its locate span, so what is left of the span is the
     # wire and the two event loops.
+    # Trailing ``content_gen``: the inode's content generation, which
+    # every completed write raises (master/metadata.py; 0 from a master
+    # that predates it). A write grant raises the chunk's version only
+    # where a copy may have missed a write, so the version alone no
+    # longer tells a reader that the bytes changed: the client folds
+    # this into the tag its cached blocks are revalidated against.
     MSG_TYPE = 1021
     SKEW_TOLERANT_FROM = 6
     FIELDS = (
@@ -330,6 +336,7 @@ class MatoclReadChunk(Message):
         ("locations", "list:msg:PartLocation"),
         ("meta_version", "u64"),
         ("srv_us", "u32"),
+        ("content_gen", "u64"),
     )
 
 

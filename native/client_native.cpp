@@ -38,7 +38,7 @@
 //                          gids:list:u32 trace_id:u64
 //   MatoclReadChunk(1021): req_id:u32 status:u8 chunk_id:u64 version:u32
 //                          file_length:u64 locations:list:msg:PartLocation
-//                          meta_version:u64 srv_us:u32
+//                          meta_version:u64 srv_us:u32 content_gen:u64
 //   CltomaWriteChunk(1022): req_id:u32 inode:u32 chunk_index:u32 uid:u32
 //                           gids:list:u32 trace_id:u64
 //   MatoclWriteChunk(1023): req_id:u32 status:u8 chunk_id:u64 version:u32
@@ -400,7 +400,7 @@ int write_chunk_range(liz_t* fs, const ChunkGrant& g, uint32_t inode,
     int slice = g.locations.empty() ? -1 : slice_type_of(g.locations[0].part_id);
     if (slice != 0) {
         // striped writes need the parity planner (FUSE path) — but the
-        // grant already version-bumped and LOCKED the chunk; an error
+        // grant already LOCKED the chunk; an error
         // WriteChunkEnd releases the lock instead of leaking it 30 s
         Msg endm(kCltomaWriteChunkEnd);
         endm.u32(fs->req_id++).u64(g.chunk_id).u32(inode).u32(chunk_index);

@@ -47,7 +47,35 @@ def pytest_pyfunc_call(pyfuncitem):
     return None
 
 
+def _build_native() -> None:
+    """``make -C native`` once a session, before anything collects: a
+    checkout with no ``*.so`` (they are never committed) then counts
+    the tests a warm one does, instead of skipping or failing whatever
+    needs the native plane until some test happens to build it. The
+    controller gets here before it starts its workers; every worker
+    comes through too, one at a time under a lock on the directory,
+    and finds nothing left to make. A tree that cannot build is left
+    to the tests' own skips."""
+    import fcntl
+    import subprocess
+
+    native = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          os.pardir, "native")
+    try:
+        fd = os.open(native, os.O_RDONLY)
+    except OSError:
+        return
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        subprocess.run(["make", "-C", native], capture_output=True)
+    except OSError:
+        pass  # no make here
+    finally:
+        os.close(fd)
+
+
 def pytest_configure(config):
+    _build_native()
     config.addinivalue_line("markers", "asyncio: run test in an event loop")
     config.addinivalue_line(
         "markers",

@@ -61,7 +61,11 @@ async def test_cross_session_write_invalidates_reader_cache(tmp_path):
 async def test_version_revalidation_catches_missed_push(tmp_path):
     """If the invalidation push is lost (handler suppressed here), the
     next locate A performs — for ANY range of the chunk — drops blocks
-    cached under the old (chunk_id, version) tag."""
+    cached under the old (chunk_id, version, content_gen) tag. B's
+    grant follows A's clean write and raises no version: what differs
+    is the file's content generation, and the locate is the one A
+    sends before it serves a block cached before that miss
+    (Client._revalidate_blocks)."""
     cluster = Cluster(tmp_path, n_cs=3)
     await cluster.start()
     try:
@@ -80,10 +84,73 @@ async def test_version_revalidation_catches_missed_push(tmp_path):
         await asyncio.sleep(0.2)
 
         # A reads a DIFFERENT block -> miss -> locate -> note_version
-        # sees the bumped chunk version and drops the stale block 0
+        # sees the raised content generation and drops the stale block 0
         await a.read_file(f.inode, 3 * MFSBLOCKSIZE, 4096)
         # re-read of block 0 within the TTL must now miss and refetch
         assert (await a.read_file(f.inode, 0, 8)) == b"NEWDATA!"
+    finally:
+        await cluster.stop()
+
+
+async def test_revalidation_where_the_version_stood_still(tmp_path):
+    """The same missed push, the overwrite being B's third clean pwrite
+    in a row: the chunk's version provably never moved, and A's next
+    locate still drops the block."""
+    cluster = Cluster(tmp_path, n_cs=3)
+    await cluster.start()
+    try:
+        a = await cluster.client()
+        b = await cluster.client()
+        f = await a.create(1, "still.dat")
+        old = bytes(range(256)) * ((4 * MFSBLOCKSIZE) // 256)
+        await a.write_file(f.inode, old)
+        await b.pwrite(f.inode, MFSBLOCKSIZE, b"one")
+        await b.pwrite(f.inode, 2 * MFSBLOCKSIZE, b"two")
+        version = (await a.chunk_info(f.inode, 0)).version
+
+        assert await a.read_file(f.inode, 0, 4096) == old[:4096]
+        a.master._push_handlers.pop(m.MatoclCacheInvalidate, None)
+        await b.pwrite(f.inode, 0, b"NEWDATA!")
+        assert (await b.chunk_info(f.inode, 0)).version == version == 1
+        assert "write_grant_bumps" not in cluster.master.metrics.labeled
+
+        # still served from the cache: nothing told A yet
+        assert await a.read_file(f.inode, 0, 8) == old[:8]
+        await a.read_file(f.inode, 3 * MFSBLOCKSIZE, 4096)
+        assert (await a.read_file(f.inode, 0, 8)) == b"NEWDATA!"
+    finally:
+        await cluster.stop()
+
+
+async def test_revalidation_is_per_file_not_per_chunk(tmp_path, monkeypatch):
+    """The tag's generation is the inode's: a write to ANOTHER chunk of
+    the file drops this chunk's cached blocks at the next locate too,
+    though their bytes are as they were. Pinned as intended: the price
+    of a version that no longer rises with every write."""
+    from lizardfs_tpu.client import client as client_mod
+
+    # two chunks without 64 MiB of data: the client's chunk arithmetic
+    # is all that has to agree on the size
+    monkeypatch.setattr(client_mod, "MFSCHUNKSIZE", 4 * MFSBLOCKSIZE)
+    cluster = Cluster(tmp_path, n_cs=3)
+    await cluster.start()
+    try:
+        a = await cluster.client()
+        b = await cluster.client()
+        f = await a.create(1, "twochunks.dat")
+        body = bytes(range(256)) * ((8 * MFSBLOCKSIZE) // 256)
+        await a.pwrite(f.inode, 0, body)
+        assert len(cluster.master.meta.fs.file_node(f.inode).chunks) == 2
+
+        assert await a.read_file(f.inode, 0, 4096) == body[:4096]
+        assert a.cache.get(f.inode, 0, 0) is not None
+        a.master._push_handlers.pop(m.MatoclCacheInvalidate, None)
+        await b.pwrite(f.inode, 5 * MFSBLOCKSIZE, b"elsewhere")  # chunk 1
+
+        hits = a.cache.hits
+        await a.read_file(f.inode, 2 * MFSBLOCKSIZE, 4096)  # chunk 0, a miss
+        assert await a.read_file(f.inode, 0, 4096) == body[:4096]
+        assert a.cache.hits == hits  # block 0 was dropped and read anew
     finally:
         await cluster.stop()
 
@@ -111,6 +178,42 @@ def test_blockcache_version_tagging():
     # chunk_id swap (truncate + regrow) also invalidates
     c.note_version(7, 1, (99, 1))
     assert c.get(7, 1, 0) is None
+
+
+def test_blockcache_blocks_before_an_unlocated_fetch_are_suspect():
+    """A read that goes to the chunkservers on a cached locate makes
+    the blocks filled before it suspect, until a locate SENT no earlier
+    than that read vouches for their tag; blocks filled by the read
+    itself, other chunks, and a chunk with nothing cached are not."""
+    clock = [100.0]
+    c = BlockCache(max_age=1000.0)
+    c._now = lambda: clock[0]
+    c.note_version(7, 0, (11, 1, 5))
+    c.put(7, 0, 0, b"x" * 100, version=(11, 1, 5))
+    assert not c.is_suspect(7, 0, 0, 3)
+    c.note_unlocated_fetch(7, 1)  # nothing cached there: nothing to doubt
+    assert not c.is_suspect(7, 1, 0, 3)
+
+    clock[0] = 101.0
+    c.note_unlocated_fetch(7, 0)
+    clock[0] = 101.5
+    c.put(7, 0, 3, b"y" * 100, version=(11, 1, 5))  # what that read fetched
+    assert c.is_suspect(7, 0, 0, 0) and c.is_suspect(7, 0, 0, 3)
+    assert not c.is_suspect(7, 0, 3, 3) and not c.is_suspect(7, 0, 1, 2)
+
+    # a cached reply vouches for nothing, nor does a locate sent before
+    c.note_version(7, 0, (11, 1, 5))
+    c.note_version(7, 0, (11, 1, 5), asked=100.5)
+    assert c.is_suspect(7, 0, 0, 0)
+    # one sent after it does: same tag, the block stays and is trusted
+    c.note_version(7, 0, (11, 1, 5), asked=102.0)
+    assert not c.is_suspect(7, 0, 0, 0) and c.get(7, 0, 0) == b"x" * 100
+
+    # and where the tag moved, the doubt was right: the blocks drop
+    clock[0] = 103.0
+    c.note_unlocated_fetch(7, 0)
+    c.note_version(7, 0, (11, 1, 6), asked=103.5)
+    assert c.get(7, 0, 0) is None and not c.is_suspect(7, 0, 0, 3)
 
 
 def test_blockcache_put_refuses_revoked_version():

@@ -493,6 +493,32 @@ def test_srv_us_version_skew():
             cls.parse(body[:20])  # cut inside file_length: no zero-fill
 
 
+def test_content_gen_version_skew():
+    """``content_gen`` trails the locate reply behind ``srv_us``: a
+    master that predates it is decoded (0: the client's tag is then the
+    chunk's id and version alone), a reply that carries none is
+    byte-identical to the encoding before the field, and a cut inside
+    the field still fails."""
+    fields = dict(req_id=1, status=0, chunk_id=5, version=2,
+                  file_length=10, locations=[], meta_version=9, srv_us=77)
+    body = m.MatoclReadChunk(content_gen=41, **fields).pack_body()
+    assert m.MatoclReadChunk.parse(body).content_gen == 41
+    old = body[:-8]  # exactly the encoding before the field
+    decoded = m.MatoclReadChunk.parse(old)
+    assert decoded.content_gen == 0 and decoded.srv_us == 77
+    assert decoded.meta_version == 9 and decoded.chunk_id == 5
+    assert m.MatoclReadChunk(**fields).pack_body() == old
+    # a master that predates both trailing fields
+    assert m.MatoclReadChunk.parse(body[:-12]).content_gen == 0
+    with pytest.raises(Exception):
+        m.MatoclReadChunk.parse(body[:-3])  # cut inside the field
+
+    from lizardfs_tpu.client.client import Client
+
+    assert Client._chunk_tag(decoded) == (5, 2, 0)
+    assert Client._chunk_tag(m.MatoclReadChunk.parse(body)) == (5, 2, 41)
+
+
 @pytest.mark.asyncio
 async def test_master_without_srv_us_is_served(tmp_path, monkeypatch):
     """E2E skew: against a master that stamps no ``srv_us`` (one that
