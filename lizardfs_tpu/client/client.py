@@ -21,6 +21,7 @@ Data paths:
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 import time as _time
 
@@ -227,15 +228,17 @@ class Client:
         self.locate_cache_ttl = 3.0
         self.cache.add_invalidate_listener(self._drop_locates)
         # per-phase busy-time accounting of a logical write, as a tree
-        # (runtime.metrics.WRITE_PHASES): getattr, lock, grant, rmw_read,
-        # rmw_patch, stage, throttle, encode, send, ack, commit at the
-        # top level; pipelined phases overlap, so the phase sum may
-        # exceed wall time — see runtime.metrics. "send" is the push
+        # (runtime.metrics.WRITE_PHASES): ingest, getattr, lock, grant,
+        # rmw_read, rmw_patch, stage, throttle, encode, send, ack,
+        # credit, chunk_gate, commit at the top level; pipelined phases
+        # overlap, so the phase sum may exceed wall time — see
+        # runtime.metrics. "send" is the push
         # cost (socket copy, or descriptor writes on the shm-ring
         # plane); "ack" is the windowed path's completion wait
         # (downstream backpressure). Through PR 23 "commit" held the
         # grant too.
         # Beside the times it counts the read-modify-write branch
+        # and what write_file's window did
         # (runtime.metrics.WRITE_COUNTS; _count_write).
         self.write_phases = PhaseBreakdown(
             "client_write", WRITE_PHASES, WRITE_COUNTS)
@@ -1346,8 +1349,7 @@ class Client:
         Overwriting with shorter content truncates to the new length
         (the master's WriteChunkEnd only ever grows the file, matching
         the reference's extend-on-write semantics)."""
-        data = np.frombuffer(bytes(data), dtype=np.uint8)
-        total = len(data)
+        total = memoryview(data).nbytes  # what bytes(data) below holds
         wall_t0 = _time.perf_counter()
         # each top-level write is one span tree under one trace (its
         # own, unless the caller already runs under one); chunk tasks
@@ -1361,6 +1363,10 @@ class Client:
             # native scatter path reads the session from it in-task
             session_ctx = accounting.task_session(self.session_id)
             session_ctx.__enter__()
+            # one flat uint8 view for the chunks below: a copy of the
+            # whole argument, on the loop, unless it is ``bytes`` already
+            with tracing.span("ingest", phase="ingest", bucket="compute"):
+                data = np.frombuffer(bytes(data), dtype=np.uint8)
             with tracing.span("getattr", phase="getattr", bucket="net"):
                 old_length = (await self.getattr(inode)).length
             # a small in-flight window pipelines chunk N+1's grant +
@@ -1373,13 +1379,20 @@ class Client:
             # per flush instead of a commit handshake per chunk (multi-
             # chunk files pay one master round trip per window drain)
             async def write_one(ci: int, piece: np.ndarray, end: int) -> None:
-                async with window:
+                # a span only where both places are taken
+                with (tracing.span("chunk_gate", phase="chunk_gate",
+                                   bucket="queue", chunk=ci)
+                      if window.locked() else contextlib.nullcontext()):
+                    await window.acquire()
+                try:
                     async def attempt():
                         await self._write_chunk(
                             inode, ci, piece, file_length=end,
                         )
 
                     await self._retry_transient(f"write chunk {ci}", attempt)
+                finally:
+                    window.release()
 
             tasks = []
             pos = 0
@@ -1979,12 +1992,14 @@ class Client:
                     )
                     self._record("write_window")
                     self._record("write_pipeline")
+                    self._count_write("window_chunks")
                     return
                 except (native_io.NativeIOError, OSError, ConnectionError,
                         st.StatusError):
                     # torn segments are healed by the full-part rewrite
                     # the sends below perform
                     self._record("write_pipeline_fallback")
+            self._count_write("fallback_chunks")
             par_task = asyncio.ensure_future(parity_parts())
             tasks = [asyncio.ensure_future(
                 send_batch(
@@ -2236,23 +2251,31 @@ class Client:
                 # waiting for credits only the other's reap can free);
                 # blocking with nothing outstanding is safe, since any
                 # credit holder then has acks of its own to reap.
-                waited = False
-                w0 = tracing.phase_t0()
-                while not win.try_acquire(session.unique_addrs, seg_bytes):
-                    waited = True
-                    if outstanding:
-                        await self._window_collect(session, win, outstanding)
-                    else:
-                        await win.acquire(session.unique_addrs, seg_bytes)
-                        break
-                win.note_segment(waited)
+                waited = not win.try_acquire(session.unique_addrs, seg_bytes)
                 if waited:
                     # credit-gate queue wait (reap-or-block included):
                     # the segment did no work while the window was full
+                    w0 = tracing.phase_t0()
+                    with tracing.span("credit", phase="credit",
+                                      bucket="queue", seg=wid):
+                        while outstanding:
+                            await self._window_collect(
+                                session, win, outstanding)
+                            if win.try_acquire(
+                                    session.unique_addrs, seg_bytes):
+                                break
+                        else:
+                            await win.acquire(
+                                session.unique_addrs, seg_bytes)
+                    # the labeled timing alone: the span above is the
+                    # ring's record of this wait
                     tracing.charge_queue_wait(
-                        self.metrics, self.trace_ring, "write_credit",
-                        "default", w0, role="client",
+                        self.metrics, None, "write_credit", "default", w0,
                     )
+                    self._count_write("window_credit_waits")
+                win.note_segment(waited)
+                self._count_write("window_segments")
+                self._count_write("window_depth_sum", win.depth)
                 try:
                     t0 = _time.perf_counter()
                     with tracing.span("send", phase="send", bucket="net",
@@ -2310,15 +2333,18 @@ class Client:
         stats = getattr(session, "ring_stats", None)
         if not stats:
             return
-        for key, val in stats.items():
-            if val:
+        for key, help_text in self._SHM_RING_HELP.items():
+            if stats.get(key):
                 self.metrics.counter(
-                    f"shm_ring_{key}", help=self._SHM_RING_HELP[key]
-                ).inc(float(val))
+                    f"shm_ring_{key}", help=help_text
+                ).inc(float(stats[key]))
         if stats.get("desc_parts"):
             # visible alongside write_pipeline/write_window counters:
             # this chunk moved (at least partly) over the ring plane
             self._record("write_shm")
+            self._count_write("ring_parts", stats["desc_parts"])
+        if stats.get("socket_parts"):
+            self._count_write("socket_parts", stats["socket_parts"])
         session.ring_stats = {k: 0 for k in stats}
 
     async def _window_collect(self, session, win, outstanding) -> None:

@@ -1170,6 +1170,9 @@ class PartsScatterSession:
         self.ring_stats = {
             "segments_mapped": 0, "desc_parts": 0, "full_waits": 0,
             "fallbacks": 0,
+            # part-segments sent by socket copy, rings or none: beside
+            # desc_parts, every part-segment the session has sent
+            "socket_parts": 0,
         }
 
     def _sock_of(self, part_index: int) -> socket.socket:
@@ -1400,6 +1403,7 @@ class PartsScatterSession:
                 bad = next((int(r.rc) for r in reqs if r.rc != 0), -1)
                 raise NativeIOError(bad, "windowed segment send")
             self._pending[write_id] = live
+            self.ring_stats["socket_parts"] += len(live)
         except BaseException:
             self.close()
             raise

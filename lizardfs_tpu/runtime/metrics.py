@@ -186,6 +186,8 @@ _BOUNDARY_PHASES = {
     "dev_fetch": "boundary",    # np.asarray: upload, kernel, download, wake-up
 }
 WRITE_PHASES = {
+    # write_file's copy of its argument, before the first RPC
+    "ingest": None,
     "getattr": None, "lock": None, "grant": None, "grant_srv": "grant",
     "wait": "grant",            # a BUSY shed's backoff, inside its RPC
     "rmw_read": None,
@@ -199,6 +201,11 @@ WRITE_PHASES = {
     "part_init": "part", "part_data": "part", "part_ack": "part",
     "part_end": "part",
     "ack": None, "commit": None,
+    # the two gates of the windowed whole-chunk write: a segment's wait
+    # for chunkserver credits and staging bytes (the reaps of older
+    # segments' acks it makes meanwhile lie under it), and a chunk's
+    # wait for one of write_file's two places
+    "credit": None, "chunk_gate": None,
     # the read-back of a partial-stripe write, under rmw_read
     "waves": "rmw_read", "dial": "waves", "net": "waves",
     "decode": "rmw_read",
@@ -206,9 +213,18 @@ WRITE_PHASES = {
 # What the write path counts beside its times (PhaseBreakdown.count):
 # pwrite calls that read stripes back, the live bytes they asked of the
 # chunkservers, the data bytes of the regions encoded and sent, and the
-# bytes the callers handed pwrite (charged where the rep closes).
+# bytes the callers handed pwrite (charged where the rep closes); then
+# what write_file's striped chunks did: chunks the window carried to
+# the end and chunks that took the whole-part sends (not eligible, or
+# the window raised), segments sent and those that waited at the credit
+# gate, part-segments handed over as shm-ring descriptors and sent by
+# socket copy, and the window's live depth summed at each segment
+# (its mean is window_depth_sum / window_segments).
 WRITE_COUNTS = ("rmw_reads", "rmw_read_bytes", "rmw_region_bytes",
-                "payload_bytes")
+                "payload_bytes",
+                "window_chunks", "fallback_chunks", "window_segments",
+                "window_credit_waits", "ring_parts", "socket_parts",
+                "window_depth_sum")
 READ_PHASES = {
     "locate": None, "locate_srv": "locate", "wait": None, "plan": None,
     # the plan's part reads, in parallel: net and dial sum over them

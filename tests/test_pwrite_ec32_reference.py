@@ -69,19 +69,19 @@ def expected_counts(first: int, calls: int) -> dict:
 
 
 def compare_stored(cluster, chunk_id: int, data: np.ndarray,
-                   written_from: int) -> None:
-    """The chunk's five part files against the reference's parts of
+                   written_from: int, k: int = K, m: int = M) -> None:
+    """The chunk's k + m part files against the reference's parts of
     ``data`` (the chunk's bytes). Blocks below ``written_from`` (a
     chunk offset, whole stripes) were never written: they read as
     zeros, and their CRC words are the store's own business."""
     files = _find_part_files(cluster, chunk_id)
-    want_ids = {layout.ec_part_id(K, M, p) for p in range(K + M)}
+    want_ids = {layout.ec_part_id(k, m, p) for p in range(k + m)}
     assert set(files) == want_ids
     assert len({os.path.dirname(os.path.dirname(f))
-                for f in files.values()}) == K + M, "distinct servers"
-    want_parts = layout.expected_parts(data, K, M, MFSBLOCKSIZE)
-    live = layout.part_lengths(K, M, len(data), MFSBLOCKSIZE)
-    first_slot = written_from // STRIPE
+                for f in files.values()}) == k + m, "distinct servers"
+    want_parts = layout.expected_parts(data, k, m, MFSBLOCKSIZE)
+    live = layout.part_lengths(k, m, len(data), MFSBLOCKSIZE)
+    first_slot = written_from // (k * MFSBLOCKSIZE)
     for part_id, path in files.items():
         p = geometry.ChunkPartType.from_id(part_id).part
         body, table = layout.read_part_file(path, MFSBLOCKSIZE)
@@ -104,8 +104,9 @@ def stored_blocks(cluster, chunk_id: int) -> dict:
             for pid, path in _find_part_files(cluster, chunk_id).items()}
 
 
-async def stop_holder_of(cluster, chunk_id: int, part: int) -> None:
-    part_id = layout.ec_part_id(K, M, part)
+async def stop_holder_of(cluster, chunk_id: int, part: int,
+                         k: int = K, m: int = M) -> None:
+    part_id = layout.ec_part_id(k, m, part)
     victim = next(cs for cs in cluster.chunkservers
                   for cf in cs.store.all_parts()
                   if cf.chunk_id == chunk_id and cf.part_id == part_id)

@@ -1,0 +1,325 @@
+"""``Client.write_file`` of whole objects at $ec(8,4), the S3 gateway's
+PUT and the CLI's copy, held to the plain reference
+(``benchmark/reference``: numpy GF(2^8), the part-file layout, zlib
+CRC32; it imports nothing of the program).
+
+A striped chunk of 8 MiB or more goes through the windowed whole-chunk
+write (``Client._push_striped_windowed``): scattered once into 8 part
+streams, cut into at most 8 slot-aligned segments, each encoded and sent
+unacknowledged under credits. A shorter one, or one whose window raises,
+takes the whole-part sends. Each case compares the bytes read back, all
+twelve part files of every chunk on the chunkservers' disks (data, the
+four parities, the stored CRC words), a read with a data part's server
+stopped, and what the window counted beside its phase rows against what
+the geometry alone predicts.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+from lizardfs_tpu.client.write_window import MAX_DEPTH
+from lizardfs_tpu.constants import MFSBLOCKSIZE, MFSCHUNKSIZE
+from lizardfs_tpu.core import native_io
+from lizardfs_tpu.runtime.metrics import WRITE_COUNTS, phase_delta
+
+from tests.test_cluster import STD2_GOAL, WIDE_EC_GOAL, Cluster
+from tests.test_pwrite_ec32_reference import compare_stored, stop_holder_of
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import generator  # noqa: E402
+import manifest  # noqa: E402
+from reference import layout  # noqa: E402
+
+K, M = 8, 4
+MiB = 2 ** 20
+PIPELINE_MIN = 8 * MiB  # Client.WRITE_PIPELINE_MIN_BYTES
+
+OBJECTS = {
+    "least_the_window_takes": PIPELINE_MIN,
+    # a whole chunk, and a tail of five blocks that falls back
+    "chunk_and_short_tail": MFSCHUNKSIZE + 5 * MFSBLOCKSIZE,
+    "two_whole_chunks": 2 * MFSCHUNKSIZE,
+}
+
+pytestmark = pytest.mark.skipif(
+    not native_io.parts_scatter_available(),
+    reason="the windowed write needs the native library")
+
+
+def expected_counts(length: int) -> dict:
+    """What ``write_file`` of a fresh object of ``length`` bytes has to
+    count, from the geometry alone. ``parts`` is ``ring_parts`` +
+    ``socket_parts``: which plane carries a part-segment is the
+    cluster's business (same host or not), their sum is not."""
+    want = {"window_chunks": 0, "fallback_chunks": 0, "window_segments": 0,
+            "parts": 0}
+    for a, b in layout.chunk_spans(length, MFSCHUNKSIZE):
+        if b - a < PIPELINE_MIN:
+            want["fallback_chunks"] += 1
+            continue
+        want["window_chunks"] += 1
+        live = layout.part_lengths(K, M, b - a, MFSBLOCKSIZE)
+        blocks = max(live) // MFSBLOCKSIZE
+        seg = -(-blocks // min(MAX_DEPTH, blocks)) * MFSBLOCKSIZE
+        for lo in range(0, blocks * MFSBLOCKSIZE, seg):
+            want["window_segments"] += 1
+            want["parts"] += sum(1 for n in live if n > lo)
+    return want
+
+
+def counted(delta: dict) -> dict:
+    got = {n: delta[n] for n in ("window_chunks", "fallback_chunks",
+                                 "window_segments")}
+    got["parts"] = delta["ring_parts"] + delta["socket_parts"]
+    return got
+
+
+async def ec84_file(client, name: str):
+    f = await client.create(1, name)
+    await client.setgoal(f.inode, WIDE_EC_GOAL)
+    return f
+
+
+async def compare_chunks(cluster, client, inode: int, data: np.ndarray):
+    """Every chunk's twelve part files against the reference; returns
+    the chunks' ids."""
+    ids = []
+    for ci, (a, b) in enumerate(layout.chunk_spans(len(data), MFSCHUNKSIZE)):
+        info = await client.chunk_info(inode, ci)
+        compare_stored(cluster, info.chunk_id, data[a:b], 0, K, M)
+        ids.append(info.chunk_id)
+    return ids
+
+
+async def read_back(client, inode: int, length: int) -> np.ndarray:
+    client.cache.invalidate(inode)
+    return np.frombuffer(await client.read_file(inode, 0, length), np.uint8)
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("seed", [30, 2147493630])
+@pytest.mark.parametrize("obj", sorted(OBJECTS))
+async def test_whole_object_matches_the_reference(tmp_path, obj, seed):
+    length = OBJECTS[obj]
+    data = np.random.default_rng([seed, length]).integers(
+        0, 256, length, dtype=np.uint8)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)  # no rebuild under the test
+    try:
+        c = await cluster.client()
+        f = await ec84_file(c, f"{obj}.bin")
+        before = c.write_phases.snapshot()
+        counters = {n: c.op_counters.get(n, 0) for n in WRITE_COUNTS}
+        batches = c.op_counters.get("write_commit_batch", 0)
+        await c.write_file(f.inode, data)
+        d = phase_delta(c.write_phases.snapshot(), before)
+
+        # (a) the bytes written read back
+        assert np.array_equal(await read_back(c, f.inode, length), data)
+
+        # (b) the part files on the chunkservers' disks
+        chunk_ids = await compare_chunks(cluster, c, f.inode, data)
+
+        # what the window counted, beside the phase rows and in op_counters
+        want = expected_counts(length)
+        assert counted(d) == want
+        assert counted({n: c.op_counters.get(n, 0) - counters[n]
+                        for n in WRITE_COUNTS}) == want
+        segs = want["window_segments"]
+        assert segs <= d["window_depth_sum"] <= MAX_DEPTH * segs
+        assert 0 <= d["window_credit_waits"] <= segs
+        assert (d["credit_ms"] > 0) == (d["window_credit_waits"] > 0)
+        assert d["reps"] == 1 and d["ingest_ms"] > 0
+        # two chunks fit write_file's two places: neither waits
+        assert d["chunk_gate_ms"] == 0
+        assert not any(d[n] for n in WRITE_COUNTS if n.startswith("rmw_"))
+        # the chunks' ends went to the master as one batch
+        assert c.op_counters.get("write_commit_batch", 0) - batches == 1
+
+        # (c) what was written, with a data part's server stopped
+        await stop_holder_of(cluster, chunk_ids[0], 1, K, M)
+        assert np.array_equal(await read_back(c, f.inode, length), data)
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.asyncio
+async def test_a_window_that_raises_mid_chunk_is_healed(tmp_path):
+    """The third segment's send fails with the first two on the
+    chunkservers' disks: the whole-part sends that follow rewrite every
+    part, so the part files come out as the reference's. The object's
+    131 blocks end in a ragged segment (data parts 3 to 7 are a block
+    shorter)."""
+    length = PIPELINE_MIN + 3 * MFSBLOCKSIZE
+    data = np.random.default_rng(30).integers(0, 256, length, dtype=np.uint8)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        f = await ec84_file(c, "torn.bin")
+        before = c.write_phases.snapshot()
+        orig = native_io.PartsScatterSession.send_segment_window
+        calls = {"n": 0}
+
+        def raises_once(self, *args, **kw):
+            calls["n"] += 1
+            if calls["n"] == 3:
+                self.close()
+                raise native_io.NativeIOError(-1, "injected")
+            return orig(self, *args, **kw)
+
+        native_io.PartsScatterSession.send_segment_window = raises_once
+        try:
+            await c.write_file(f.inode, data)
+        finally:
+            native_io.PartsScatterSession.send_segment_window = orig
+        d = phase_delta(c.write_phases.snapshot(), before)
+        assert calls["n"] == 3, "the fallback sends no segment"
+        assert counted(d) == {"window_chunks": 0, "fallback_chunks": 1,
+                              "window_segments": 3, "parts": 2 * (K + M)}
+        assert np.array_equal(await read_back(c, f.inode, length), data)
+        await compare_chunks(cluster, c, f.inode, data)
+    finally:
+        await cluster.stop()
+
+
+ARGUMENTS = {
+    # what the S3 gateway (``req.body``) and the CLI (``f.read()``) hand it
+    "bytes": bytes,
+    "uint8_array": lambda a: a,
+    # a buffer whose items are wider than a byte: its length is no byte count
+    "uint32_view": lambda a: memoryview(a).cast("I"),
+}
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("form", sorted(ARGUMENTS))
+async def test_the_object_may_be_any_buffer(tmp_path, form):
+    """``write_file`` takes its object's bytes from whatever buffer it
+    is handed: the length it writes, counts and commits is the
+    buffer's byte length, whatever its items are."""
+    length = PIPELINE_MIN + 4 * MFSBLOCKSIZE
+    data = np.random.default_rng(31).integers(0, 256, length, dtype=np.uint8)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        f = await ec84_file(c, f"{form}.bin")
+        before = c.write_phases.snapshot()
+        c.trace_ring.clear()
+        await c.write_file(f.inode, ARGUMENTS[form](data))
+        d = phase_delta(c.write_phases.snapshot(), before)
+        assert (await c.getattr(f.inode)).length == length
+        assert np.array_equal(await read_back(c, f.inode, length), data)
+        assert counted(d) == expected_counts(length)
+        root, = [s for s in c.trace_ring.dump() if s["name"] == "write_file"]
+        assert root["attrs"]["bytes"] == length
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.asyncio
+async def test_the_gates_are_spans_of_the_write_file_tree(tmp_path):
+    """One credit a chunkserver: every segment but the first finds the
+    gate shut and reaps its predecessor's acks inside a ``credit`` span.
+    A third chunk finds both of ``write_file``'s places taken and waits
+    in a ``chunk_gate`` span (plain copies: the gate is the same)."""
+    rng = np.random.default_rng(30)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        c.write_window.cs_credits = 1
+        f = await ec84_file(c, "credits.bin")
+        before = c.write_phases.snapshot()
+        c.trace_ring.clear()
+        await c.write_file(
+            f.inode, rng.integers(0, 256, PIPELINE_MIN, dtype=np.uint8))
+        d = phase_delta(c.write_phases.snapshot(), before)
+        assert d["window_segments"] == 8 and d["window_credit_waits"] == 7
+        assert d["window_chunks"] == 1 and d["credit_ms"] > 0
+        spans = c.trace_ring.dump()
+        root, = [s for s in spans if s["name"] == "write_file"]
+        by_id = {s["span_id"]: s for s in spans}
+        credit = [s for s in spans if s["name"] == "credit"]
+        assert len(credit) == 7
+        assert all(s["parent_id"] == root["span_id"]
+                   and s["bucket"] == "queue" for s in credit)
+        reaps = [s for s in spans if s["name"] == "ack"
+                 and by_id[s["parent_id"]]["name"] == "credit"]
+        assert len(reaps) == 7
+        ingest, = [s for s in spans if s["name"] == "ingest"]
+        assert ingest["parent_id"] == root["span_id"]
+        assert not [s for s in spans if s["name"] == "chunk_gate"]
+
+        g = await c.create(1, "three_chunks.bin")
+        await c.setgoal(g.inode, STD2_GOAL)
+        before = c.write_phases.snapshot()
+        c.trace_ring.clear()
+        await c.write_file(g.inode, bytes(2 * MFSCHUNKSIZE + MFSBLOCKSIZE))
+        d = phase_delta(c.write_phases.snapshot(), before)
+        assert d["chunk_gate_ms"] > 0 and d["reps"] == 1
+        spans = c.trace_ring.dump()
+        root, = [s for s in spans if s["name"] == "write_file"]
+        gate, = [s for s in spans if s["name"] == "chunk_gate"]
+        assert gate["parent_id"] == root["span_id"]
+        assert gate["attrs"]["chunk"] == 2 and gate["bucket"] == "queue"
+        # plain copies go through no window and no whole-part fallback
+        assert not any(d[n] for n in WRITE_COUNTS)
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.asyncio
+async def test_the_put_sequence_publishes_a_whole_object(tmp_path):
+    """The benchmark's verb (``benchmark/traffic/verbs/put_whole.py``)
+    under the generator, one PUT at the rehearsal's size: the staged
+    name is gone, the key is listed in the bucket's directory with the
+    object's length, the ETag reads back, and the model holds what the
+    chunkservers do."""
+    cell = manifest.Cell(manifest.load_manifest(), "ec84-put")
+    manifest.rehearsal_of(cell)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        goal, = cell.config["goals"]
+        dirs = []
+        for entry in cell.config["directories"]:
+            d = await c.mkdir(1, entry["name"])
+            await c.setgoal(d.inode, WIDE_EC_GOAL)
+            dirs.append(generator.Directory(entry["name"], d.inode, goal))
+        t = generator.Traffic(cell.mix, 30, [c], dirs, None,
+                              int(cell.config["chunk_bytes"]))
+        t.recording = True
+        step, = cell.mix["steps"]
+        verb = t.verbs[step["verb"]]
+        await verb.do(t, 0, t._state(0), step, False)
+
+        staging, bucket = dirs
+        assert await c.readdir(staging.inode) == []
+        listed, = await c.readdir(bucket.inode)
+        f, = t.model.live()
+        assert (listed.name, f.name, f.dir) == ("s0_0", "s0_0", 1)
+        assert f.length == cell.config["object_bytes"] == 9 * MiB
+        attr = await c.lookup(bucket.inode, "s0_0")
+        assert (attr.inode, attr.length) == (f.inode, f.length)
+        etag = await c.get_xattr(f.inode, verb.ETAG_XATTR)
+        assert len(etag) == 32 and int(etag, 16) >= 0
+        op, = t.ops
+        assert (op.cls, op.nbytes, op.ok) == ("write", f.length, True)
+        want = t.model.bytes_of(f)
+        assert np.array_equal(await read_back(c, f.inode, f.length), want)
+        await compare_chunks(cluster, c, f.inode, want)
+        assert c.op_counters["window_chunks"] == 1
+        assert not c.op_counters.get("fallback_chunks")
+        assert not t.uncertain
+    finally:
+        await cluster.stop()
