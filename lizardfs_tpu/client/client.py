@@ -412,12 +412,17 @@ class Client:
         self.oplog.append((_time.time(), op, kw))
         self.op_counters[op] = self.op_counters.get(op, 0) + 1
 
+    def _count_op(self, name: str, n: int = 1) -> None:
+        """A count of ``op_counters`` that rises by more than one a
+        time and is no logical op (``execute_plan``'s native waves)."""
+        self.op_counters[name] = self.op_counters.get(name, 0) + n
+
     def _count_write(self, name: str, n: int = 1) -> None:
         """One of the write path's counts (WRITE_COUNTS): beside the
         phase rows, for the interval a snapshot delta scopes, and in
         ``op_counters``, for the mount's ``.stats``."""
         self.write_phases.count(name, n)
-        self.op_counters[name] = self.op_counters.get(name, 0) + n
+        self._count_op(name, n)
 
     async def _retry_transient(self, what: str, attempt_fn) -> None:
         """Run ``attempt_fn`` under the unified RetryPolicy
@@ -1681,6 +1686,7 @@ class Client:
                 buf = await execute_plan(
                     plan, grant.chunk_id, grant.version, by_part,
                     wave_timeout=self.wave_timeout,
+                    count=self._count_op,
                 )
             self._count_write("rmw_reads")
             self._count_write("rmw_read_bytes", asked)
@@ -3104,6 +3110,7 @@ class Client:
                     wave_timeout=self.wave_timeout,
                     buffer=buffer,
                     on_part_failure=self._part_failure_observer(loc),
+                    count=self._count_op,
                 )
             except (ReadError, ConnectionError, OSError) as e:
                 raise _tag(e)
@@ -3211,6 +3218,7 @@ class Client:
             plan, loc.chunk_id, loc.version, by_part,
             wave_timeout=self.wave_timeout,
             on_part_failure=self._part_failure_observer(loc),
+            count=self._count_op,
         )
         # reassemble the stripes we read, then slice the requested bytes.
         # The gather runs off-loop (native stripe_gather releases the

@@ -78,6 +78,17 @@ if _lib is not None:
         except AttributeError:
             pass  # stale .so: the whole-stripe fast path stays off
         try:
+            _lib.lz_read_parts_wave.argtypes = [
+                ctypes.c_void_p, ctypes.c_uint32,
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint32,
+                ctypes.POINTER(ctypes.c_uint64),
+            ]
+            _lib.lz_read_parts_wave.restype = ctypes.c_int
+        except AttributeError:
+            pass  # stale .so: a wave's parts read on a thread each
+        try:
             _lib.lz_write_parts_exchange.argtypes = [
                 ctypes.c_void_p, ctypes.c_uint32,
                 ctypes.POINTER(ctypes.c_char_p),
@@ -138,7 +149,8 @@ def available() -> bool:
 class NativeIOError(Exception):
     def __init__(self, code: int, what: str):
         self.code = code
-        names = {-1: "socket error", -2: "protocol violation", -3: "CRC mismatch"}
+        names = {-1: "socket error", -2: "protocol violation",
+                 -3: "CRC mismatch", -4: "not finished at the deadline"}
         msg = names.get(code, f"status {st.name(code) if code > 0 else code}")
         super().__init__(f"native {what}: {msg}")
 
@@ -751,9 +763,12 @@ def read_part_blocking(
     size: int,
     out: np.ndarray,
     cell: dict | None = None,
+    fresh: bool = False,
 ) -> None:
     """Fill ``out[:size]`` with the requested range (called via
-    asyncio.to_thread). Retries once on a stale pooled socket.
+    asyncio.to_thread). Retries once on a stale pooled socket;
+    ``fresh`` goes to that dial at once (the caller has seen the pool
+    empty or stale).
 
     Block-aligned requests use the bulk exchange (one reply frame,
     receiver-verified CRCs, server sendfile) — the fast path; unaligned
@@ -765,7 +780,7 @@ def read_part_blocking(
     ptr = out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
     fn = (_lib.lz_read_part_bulk if offset % MFSBLOCKSIZE == 0
           else _lib.lz_read_part)
-    for attempt in (0, 1):
+    for attempt in ((1,) if fresh else (0, 1)):
         # second attempt dials fresh: the pool may hold several sockets
         # staled by the same server restart
         sock = POOL.acquire(addr, fresh=attempt == 1)
@@ -1002,6 +1017,132 @@ def read_parts_gather_blocking(
         finally:
             for addr, s in socks:
                 POOL.discard(addr, s)
+
+
+# lz_read_parts_wave: the rc of a part the call is to read and, while
+# the call runs, of one whose exchange is under way (io_native.cpp)
+WAVE_PENDING = 1 << 30
+
+
+def parts_wave_available() -> bool:
+    return _lib is not None and hasattr(_lib, "lz_read_parts_wave")
+
+
+class PartsWave:
+    """One wave of a read plan as one native exchange: what it asks of
+    the wire and what has come of it so far.
+
+    A part rides the call on a socket the pool holds idle, taken here,
+    on the caller's thread, and never on a dial: a dial to a holder
+    that died silently hangs for as long as a whole plan may take, and
+    on the one worker it would keep every other part of the wave from
+    being asked. A part the pool has no socket for reads -1 from the
+    start, as does one whose pooled socket fails (its server has
+    restarted): no verdict on the holder, the caller reads such a part
+    on a thread of its own, which dials.
+
+    The loop thread builds it, hands it to
+    :func:`read_parts_wave_blocking` on ONE worker thread where
+    ``live`` is not empty, and may read :meth:`outcome` while that
+    runs: C stores a part's rc (release order) after the part's last
+    byte has landed and every block's CRC has been checked, so a part
+    that reads 0 is whole in ``out`` though the call is still waiting
+    for another. ``cell`` publishes the sockets for
+    :func:`abort_parts_gather` from the start."""
+
+    def __init__(
+        self,
+        addrs: list[tuple[str, int]],
+        chunk_id: int,
+        version: int,
+        part_ids: list[int],
+        offsets: list[int],
+        sizes: list[int],
+        out: np.ndarray,
+        out_offsets: list[int],
+        max_ms: int,
+    ):
+        n = len(addrs)
+        # C writes through raw pointers: no part may land outside `out`
+        if not (n == len(part_ids) == len(offsets) == len(sizes)
+                == len(out_offsets)):
+            raise ValueError("a wave's lists differ in length")
+        if not (out.flags.c_contiguous and out.dtype == np.uint8):
+            raise ValueError("a wave lands in a contiguous uint8 buffer")
+        if any(o < 0 or size <= 0 or o + size > out.nbytes
+               for o, size in zip(out_offsets, sizes)):
+            raise ValueError("a part of the wave lands outside the buffer")
+        self.addrs = addrs
+        self.max_ms = max_ms
+        self.out = out  # alive for as long as C may write through dsts
+        self.reqs = (_PartReq * n)()
+        self.live: dict[int, socket.socket] = {}
+        for i in range(n):
+            sock = POOL.try_acquire(addrs[i])
+            if sock is not None:
+                self.live[i] = sock
+            self.reqs[i].fd = -1 if sock is None else sock.fileno()
+            self.reqs[i].chunk_id = chunk_id
+            self.reqs[i].version = version
+            self.reqs[i].part_id = part_ids[i]
+            self.reqs[i].rc = -1 if sock is None else WAVE_PENDING
+        self.offsets = (ctypes.c_uint32 * n)(*offsets)
+        self.sizes = (ctypes.c_uint32 * n)(*sizes)
+        base = out.ctypes.data
+        self.dsts = (ctypes.c_void_p * n)(*(base + o for o in out_offsets))
+        self.done_us = (ctypes.c_uint64 * n)()
+        self.cell: dict = {"socks": list(self.live.values())}
+
+    def outcome(self, i: int) -> int | None:
+        """Part i's rc, or None while its exchange is under way."""
+        rc = int(self.reqs[i].rc)
+        return None if rc == WAVE_PENDING else rc
+
+    def in_flight(self) -> bool:
+        return any(req.rc == WAVE_PENDING for req in self.reqs)
+
+
+def read_parts_wave_blocking(wave: PartsWave) -> None:
+    """Run ``wave`` to its end on the calling (worker) thread: ONE
+    ``lz_read_parts_wave`` call over its ``live`` sockets (one poll
+    loop, the GIL given up once; each part lands contiguous at its
+    place in ``wave.out``). A part's socket goes back to the pool where
+    its rc is 0 and is discarded otherwise. Each part is a ``net`` row
+    under the caller's open span, from the call's start to the moment C
+    saw the part end (``done_us``): the wait for this thread is the
+    ``hop`` beside them, not inside. Raises nothing for a part's
+    failure: the caller reads ``wave.outcome``."""
+    from lizardfs_tpu.runtime import tracing
+
+    reqs, cell = wave.reqs, wave.cell
+    try:
+        if cell.get("aborted"):
+            return
+        t_call = time.perf_counter()
+        _lib.lz_read_parts_wave(
+            ctypes.cast(reqs, ctypes.c_void_p), len(reqs),
+            wave.offsets, wave.sizes, wave.dsts, wave.max_ms, wave.done_us,
+        )
+        now = time.perf_counter()
+        for i in wave.live:
+            tracing.span(
+                "net", layer="wire", phase="net", bucket="net",
+                part=int(reqs[i].part_id), bytes=wave.sizes[i],
+                plane="wave",
+            ).begin(at=t_call).end(
+                at=min(t_call + wave.done_us[i] / 1e6, now))
+    finally:
+        cell.pop("socks", None)
+        # an abort that found the sockets before this pop has shut them
+        # down, whole parts' too: none goes back to the pool then
+        whole = not cell.get("aborted")
+        for i, sock in wave.live.items():
+            if whole and reqs[i].rc == 0:
+                POOL.release(wave.addrs[i], sock)
+            else:
+                POOL.discard(wave.addrs[i], sock)
+            if reqs[i].rc == WAVE_PENDING:  # aborted, or this thread raised
+                reqs[i].rc = -1
 
 
 def abort_parts_gather(cell: dict) -> None:

@@ -15,6 +15,7 @@ import logging
 
 import numpy as np
 
+from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.core.plans import SliceReadPlan
 from lizardfs_tpu.ops import crc32 as crc_mod
 from lizardfs_tpu.proto import framing
@@ -49,12 +50,15 @@ async def read_part_range(
     size: int,
     into: np.ndarray | None = None,
     into_offset: int = 0,
+    fresh: bool = False,
 ) -> np.ndarray:
     """Read one range of one part from one chunkserver, verifying piece
     CRCs (ReadOperationExecutor analog). Connections come from the
     process-wide pool and are returned after a clean, fully-drained
     exchange (ConnectionPool analog). Every outcome feeds the shared
-    per-chunkserver health scores (chunkserver_stats.cc analog)."""
+    per-chunkserver health scores (chunkserver_stats.cc analog).
+    ``fresh``: the native exchange dials, once, whatever its pool holds
+    idle (a native wave found nothing idle, or a dead socket)."""
     from lizardfs_tpu.core.conn_pool import GLOBAL_POOL
     from lizardfs_tpu.core.cs_stats import GLOBAL_STATS
 
@@ -109,7 +113,7 @@ async def read_part_range(
             native_io.partial_with_trace(
                 native_io.read_part_blocking,
                 addr, chunk_id, version, part_id, offset, size, tmp,
-                cell if scatter_direct else None,
+                cell if scatter_direct else None, fresh,
             ),
         )
         try:
@@ -124,10 +128,18 @@ async def read_part_range(
         except asyncio.CancelledError:
             if scatter_direct:
                 native_io.abort_read(cell)
-                try:
-                    await asyncio.wait_for(asyncio.shield(fut), 10.0)
-                except (Exception, asyncio.CancelledError):
-                    pass
+                # a thread inside the exchange is joined: its recv fails
+                # now. One that has published no socket is dialling (a
+                # dead holder: for up to 30 s) or has ended, and finds
+                # the cell aborted before it asks for a byte
+                if "sock" in cell:
+                    try:
+                        await asyncio.wait_for(asyncio.shield(fut), 10.0)
+                    except (Exception, asyncio.CancelledError):
+                        pass
+                else:
+                    fut.add_done_callback(
+                        lambda f: f.cancelled() or f.exception())
             raise
         except native_io.NativeIOError as e:
             GLOBAL_STATS.record_failure(addr)
@@ -209,6 +221,81 @@ async def read_part_range(
             GLOBAL_POOL.discard(conn)
 
 
+def _wave_goes_native(ops: list) -> bool:
+    """Whether one native call serves a wave's part reads: it pays from
+    two ops on, where each is one :func:`read_part_range` would hand to
+    the native bulk exchange on a worker thread of its own (the one
+    call takes the place of those threads)."""
+    from lizardfs_tpu.core import native_io
+
+    return (
+        len(ops) >= 2
+        and native_io.parts_wave_available()
+        and not _faults.ACTIVE  # as in read_part_range
+        and all(
+            op.request_size >= native_io.NATIVE_READ_THRESHOLD
+            and op.request_offset % MFSBLOCKSIZE == 0
+            for op in ops
+        )
+    )
+
+
+# What one worker thread reads of a native wave. The eight 256 KiB parts
+# of a 2 MiB read at $ec(8,4) share one worker: eight threads' starts
+# and their turns at the GIL cost more than the bytes. A rebuild's or a
+# whole chunk's 8 MiB parts take a worker each, as before the native
+# wave: one thread's recv and CRC pass over 64 MiB took 53 ms where
+# eight take 13 (PERF.md section 6, PR 32, which has the sweep this
+# number is from). Decided from the bytes the wave holds.
+WAVE_WORKER_BYTES = 8 * 2**20
+
+
+def _worker_loads(ops: list) -> list[list]:
+    """A wave's ops, in order, in runs of at most WAVE_WORKER_BYTES
+    (a run is at least one op): one native call and one worker a run."""
+    loads: list[list] = []
+    room = 0
+    for op in ops:
+        if not loads or op.request_size > room:
+            loads.append([])
+            room = WAVE_WORKER_BYTES
+        loads[-1].append(op)
+        room -= op.request_size
+    return loads
+
+
+class _NativeWave:
+    """A wave in flight as native calls, one a worker: each call's
+    state (``native_io.PartsWave``) beside the plan's ops it was built
+    for, index for index, and which of them ``execute_plan`` has yet to
+    settle."""
+
+    __slots__ = ("calls", "unsettled")
+
+    def __init__(self, calls: list):
+        self.calls = calls  # [(PartsWave, its ops)]
+        self.unsettled = {(c, i) for c, (_, ops) in enumerate(calls)
+                          for i in range(len(ops))}
+
+    def ended(self):
+        """(op, holder, rc) of each part that has ended since the last
+        look: all that are left, once the workers have returned."""
+        for c, i in sorted(self.unsettled):
+            wave, ops = self.calls[c]
+            rc = wave.outcome(i)
+            if rc is None:
+                continue
+            self.unsettled.discard((c, i))
+            yield ops[i], wave.addrs[i], rc
+
+    def parts_read(self) -> tuple[int, int]:
+        """(parts the calls read whole, parts the wave asked for)."""
+        return (
+            sum(req.rc == 0 for wave, _ in self.calls for req in wave.reqs),
+            sum(len(ops) for _, ops in self.calls),
+        )
+
+
 async def execute_plan(
     plan: SliceReadPlan,
     chunk_id: int,
@@ -218,6 +305,7 @@ async def execute_plan(
     total_timeout: float = DEFAULT_TOTAL_TIMEOUT,
     buffer: np.ndarray | None = None,
     on_part_failure=None,
+    count=None,
 ) -> np.ndarray:
     """Execute a plan; returns the post-processed result bytes.
 
@@ -229,50 +317,150 @@ async def execute_plan(
     observes every per-part failure as it happens — the client threads
     its damaged-part reporter through here so a CRC-rejected part is
     reported to the master even when the read itself recovers.
+    ``count`` (optional ``fn(name, n)``) receives the native waves'
+    counts: ``wave_native`` (a wave its native calls served whole),
+    ``wave_native_parts`` (parts such calls read), and
+    ``wave_native_fallback`` (a wave that went native and had a part
+    the call did not read: per-part reads served in its place).
+
+    A wave of two or more bulk reads is ONE native call on ONE worker
+    thread for every WAVE_WORKER_BYTES it holds (:func:`_worker_loads`:
+    a 2 MiB read's eight parts are one call, a rebuild's 8 MiB parts a
+    call each; :func:`_wave_goes_native`, ``native_io.PartsWave``); any
+    other wave is a :func:`read_part_range` task a part, and so is a
+    part of a native wave that needs a dial: the pool holds no idle
+    socket to its holder, or the one it held had died. A native call
+    stays in flight past the wave timeout, as a slow per-part task
+    does: the parts it has finished are harvested at every wake-up (C
+    publishes each part's rc as the part ends), so a straggler holds
+    back neither the next wave nor the parts that came with it.
     """
+    from lizardfs_tpu.core import native_io
+    from lizardfs_tpu.core.cs_stats import GLOBAL_STATS
+
     if buffer is None:
         buffer = np.zeros(plan.buffer_size, dtype=np.uint8)
     else:
         assert buffer.size == plan.buffer_size and buffer.dtype == np.uint8
     available: list[int] = []
     unreadable: list[int] = []
-    pending: dict[asyncio.Task, int] = {}
+    # a per-part task -> its part; a native call's future -> its wave
+    pending: dict[asyncio.Future, int | _NativeWave] = {}
+    native_waves: list[_NativeWave] = []  # all started, for the counts
     max_wave = max((op.wave for op in plan.read_operations), default=0)
     loop = asyncio.get_running_loop()
     deadline = loop.time() + total_timeout
     current_wave = -1
 
     def start_wave(w: int):
+        ops = []
         for op in plan.read_operations:
             if op.wave != w:
                 continue
             if op.part not in locations:
                 unreadable.append(op.part)
                 continue
-            addr, wire_part_id = locations[op.part]
-            task = asyncio.ensure_future(
-                read_part_range(
-                    addr,
-                    chunk_id,
-                    version,
-                    wire_part_id,
-                    op.request_offset,
-                    op.request_size,
-                    into=buffer,
-                    into_offset=op.buffer_offset,
+            ops.append(op)
+        # an op of no bytes (part_sizes clipped it) never reaches the
+        # wire: read_part_range answers it at once
+        wired = [op for op in ops if op.request_size]
+        if buffer.flags.c_contiguous and _wave_goes_native(wired):
+            ops = [op for op in ops if not op.request_size]
+            # the calls outlive the wave timeout (see above); the plan's
+            # own deadline ends them
+            max_ms = max(int((deadline - loop.time()) * 1e3), 1)
+            native = _NativeWave([
+                (native_io.PartsWave(
+                    [locations[op.part][0] for op in load],
+                    chunk_id, version,
+                    [locations[op.part][1] for op in load],
+                    [op.request_offset for op in load],
+                    [op.request_size for op in load],
+                    buffer,
+                    [op.buffer_offset for op in load],
+                    max_ms,
+                ), load)
+                for load in _worker_loads(wired)
+            ])
+            native_waves.append(native)
+            for wave, _ in native.calls:
+                if not wave.live:
+                    continue  # the pool has no socket for any of them
+                fut = loop.run_in_executor(
+                    native_io.EXECUTOR,
+                    # partial_with_trace: the open `waves` span and the
+                    # sink ride into the worker, whose wait is one `hop`
+                    native_io.partial_with_trace(
+                        native_io.read_parts_wave_blocking, wave),
                 )
+                pending[fut] = native
+            harvest(native)  # the parts the pool had no socket for
+        for op in ops:
+            start_part(op)
+
+    def start_part(op, fresh: bool = False) -> None:
+        addr, wire_part_id = locations[op.part]
+        task = asyncio.ensure_future(
+            read_part_range(
+                addr,
+                chunk_id,
+                version,
+                wire_part_id,
+                op.request_offset,
+                op.request_size,
+                into=buffer,
+                into_offset=op.buffer_offset,
+                fresh=fresh,
             )
-            pending[task] = op.part
+        )
+        pending[task] = op.part
+
+    def harvest(native: _NativeWave) -> None:
+        for op, addr, rc in native.ended():
+            if rc == 0:
+                GLOBAL_STATS.record_success(addr)
+                settle(op.part, None)
+            elif rc == -1:
+                # no idle socket, or a dead one (and then the pool's
+                # others to that server are dead too): no verdict on
+                # the holder before a task of the part's own has
+                # dialled it, which also leaves the pool one deeper
+                start_part(op, fresh=True)
+            else:
+                GLOBAL_STATS.record_failure(addr)
+                settle(op.part, ReadError(
+                    str(native_io.NativeIOError(rc, "read")),
+                    crc=rc in (-3, st.CRC_ERROR),
+                ))
+
+    def settle(part: int, exc: BaseException | None) -> None:
+        if exc is None:
+            available.append(part)
+            return
+        log.debug("part %d failed: %s", part, exc)
+        if on_part_failure is not None and part in locations:
+            addr, wire_part_id = locations[part]
+            try:
+                on_part_failure(part, wire_part_id, addr, exc)
+            except Exception:  # noqa: BLE001
+                log.debug("part-failure observer failed", exc_info=True)
+        unreadable.append(part)
+        if not plan.is_finishing_possible(unreadable):
+            raise ReadError(f"too many failed parts: {unreadable}")
 
     # the waves' part reads run in parallel: one span holds them, so
     # that the op's top level stays serial (net and dial nest in it)
     waves = tracing.span(
         "waves", layer="wire", phase="waves", bucket="net"
     ).begin()
-    current_wave = 0
-    start_wave(0)
-    wave_start = loop.time()
+    cancelled = False
     try:
+        # inside the try: a native part that fails at once is settled
+        # where its wave starts, and a plan that cannot finish raises
+        # there with the wave's other parts in flight
+        current_wave = 0
+        start_wave(0)
+        wave_start = loop.time()
         while not plan.is_reading_finished(available):
             if not pending:
                 # everything in flight resolved; fire the next wave now
@@ -298,22 +486,16 @@ async def execute_plan(
                 return_when=asyncio.FIRST_COMPLETED,
             )
             for task in done:
-                part = pending.pop(task)
-                exc = task.exception()
-                if exc is None:
-                    available.append(part)
+                what = pending.pop(task)
+                if isinstance(what, _NativeWave):
+                    task.result()  # the worker raises for no part's sake
                 else:
-                    log.debug("part %d failed: %s", part, exc)
-                    if on_part_failure is not None and part in locations:
-                        addr, wire_part_id = locations[part]
-                        try:
-                            on_part_failure(part, wire_part_id, addr, exc)
-                        except Exception:  # noqa: BLE001
-                            log.debug("part-failure observer failed",
-                                      exc_info=True)
-                    unreadable.append(part)
-                    if not plan.is_finishing_possible(unreadable):
-                        raise ReadError(f"too many failed parts: {unreadable}")
+                    settle(what, task.exception())
+            # the parts the native calls have finished, whether their
+            # worker has returned or not (a finished part counts before
+            # its call ends)
+            for native in native_waves:
+                harvest(native)
             # wave timeout: stragglers trigger the next wave (reference
             # startReadsForWave, read_plan_executor.cc:162-176)
             if (
@@ -323,12 +505,36 @@ async def execute_plan(
                 current_wave += 1
                 start_wave(current_wave)
                 wave_start = loop.time()
+    except asyncio.CancelledError:
+        cancelled = True
+        raise
     finally:
-        for task in pending:
-            task.cancel()
-        if pending:
-            await asyncio.gather(*pending.keys(), return_exceptions=True)
+        # no byte may land in the plan buffer once this returns: cancel
+        # the tasks (read_part_range aborts its socket and joins its
+        # thread), shut a native wave's sockets and join its worker
+        for native in native_waves:
+            for wave, _ in native.calls:
+                # a call whose parts have all ended has returned or is
+                # about to (a wake-up for another call found the plan
+                # finished): its sockets are whole, and stay so
+                if wave.in_flight():
+                    native_io.abort_parts_gather(wave.cell)
+        joins = []
+        for task, what in pending.items():
+            if isinstance(what, _NativeWave):
+                joins.append(asyncio.wait_for(asyncio.shield(task), 10.0))
+            else:
+                task.cancel()
+                joins.append(task)
+        if joins:
+            await asyncio.gather(*joins, return_exceptions=True)
         waves.end()
+        if count is not None and not cancelled:
+            for native in native_waves:
+                ok, asked = native.parts_read()
+                count("wave_native_parts", ok)
+                count("wave_native" if ok == asked
+                      else "wave_native_fallback", 1)
 
     # postprocess is the decode leg: parity recovery / block CRC checks
     # for striped plans (a plain pass-through for healthy std reads).

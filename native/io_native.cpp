@@ -201,6 +201,41 @@ thread_local uint64_t g_trace_id = 0;
 // only rides frames that also carry a (nonzero) trace
 thread_local uint64_t g_session_id = 0;
 
+namespace {
+
+constexpr uint32_t kTypeReadBulk = 1206;
+constexpr uint32_t kTypeReadBulkData = 1207;
+
+// One read request (CltocsRead or CltocsReadBulk: the same fields) on a
+// blocking socket, the calling thread's trace and session ids riding
+// it (the optional trailing fields).
+bool send_read_request(int fd, uint32_t type, uint64_t chunk_id,
+                       uint32_t version, uint32_t part_id, uint32_t offset,
+                       uint32_t size) {
+    uint8_t req[8 + 1 + 4 + 8 + 4 + 4 + 4 + 4 + 8 + 8];
+    size_t body = 1 + 4 + 8 + 4 + 4 + 4 + 4;
+    req[8] = kProtoVersion;
+    put32(req + 9, 1);
+    put64(req + 13, chunk_id);
+    put32(req + 21, version);
+    put32(req + 25, part_id);
+    put32(req + 29, offset);
+    put32(req + 33, size);
+    if (g_trace_id != 0) {  // optional trailing trace + session (wire.h)
+        put64(req + 37, g_trace_id);
+        body += 8;
+        if (g_session_id != 0) {
+            put64(req + 45, g_session_id);
+            body += 8;
+        }
+    }
+    put32(req, type);
+    put32(req + 4, static_cast<uint32_t>(body));
+    return send_all(fd, req, 8 + body);
+}
+
+}  // namespace
+
 extern "C" {
 
 void lz_trace_set(uint64_t trace_id) { g_trace_id = trace_id; }
@@ -211,27 +246,9 @@ void lz_session_set(uint64_t session_id) { g_session_id = session_id; }
 int lz_read_part(int fd, uint64_t chunk_id, uint32_t version,
                  uint32_t part_id, uint32_t offset, uint32_t size,
                  uint8_t* out) {
-    // request (+16 reserved for the optional trailing trace/session ids)
-    uint8_t req[8 + 1 + 4 + 8 + 4 + 4 + 4 + 4 + 8 + 8];
-    size_t body = 1 + 4 + 8 + 4 + 4 + 4 + 4;
-    req[8] = kProtoVersion;
-    put32(req + 9, 1);            // req_id
-    put64(req + 13, chunk_id);
-    put32(req + 21, version);
-    put32(req + 25, part_id);
-    put32(req + 29, offset);
-    put32(req + 33, size);
-    if (g_trace_id != 0) {
-        put64(req + 37, g_trace_id);
-        body += 8;
-        if (g_session_id != 0) {
-            put64(req + 45, g_session_id);
-            body += 8;
-        }
-    }
-    put32(req, kTypeRead);
-    put32(req + 4, static_cast<uint32_t>(body));
-    if (!send_all(fd, req, 8 + body)) return -1;
+    if (!send_read_request(fd, kTypeRead, chunk_id, version, part_id, offset,
+                           size))
+        return -1;
 
     std::vector<uint8_t> payload(kMaxPayload);
     uint64_t received = 0;
@@ -281,28 +298,9 @@ int lz_read_part(int fd, uint64_t chunk_id, uint32_t version,
 int lz_read_part_bulk(int fd, uint64_t chunk_id, uint32_t version,
                       uint32_t part_id, uint32_t offset, uint32_t size,
                       uint8_t* out) {
-    constexpr uint32_t kTypeReadBulk = 1206;
-    constexpr uint32_t kTypeReadBulkData = 1207;
-    uint8_t req[8 + 1 + 4 + 8 + 4 + 4 + 4 + 4 + 8 + 8];
-    size_t body = 1 + 4 + 8 + 4 + 4 + 4 + 4;
-    req[8] = kProtoVersion;
-    put32(req + 9, 1);
-    put64(req + 13, chunk_id);
-    put32(req + 21, version);
-    put32(req + 25, part_id);
-    put32(req + 29, offset);
-    put32(req + 33, size);
-    if (g_trace_id != 0) {  // optional trailing trace + session (wire.h)
-        put64(req + 37, g_trace_id);
-        body += 8;
-        if (g_session_id != 0) {
-            put64(req + 45, g_session_id);
-            body += 8;
-        }
-    }
-    put32(req, kTypeReadBulk);
-    put32(req + 4, static_cast<uint32_t>(body));
-    if (!send_all(fd, req, 8 + body)) return -1;
+    if (!send_read_request(fd, kTypeReadBulk, chunk_id, version, part_id,
+                           offset, size))
+        return -1;
 
     uint8_t header[8];
     if (!recv_all(fd, header, 8)) return -1;
@@ -421,6 +419,219 @@ int lz_write_part(int fd, uint64_t chunk_id, const uint8_t* payload,
     return 0;
 }
 
+// --- multi-part bulk reads: one poll loop over n sockets --------------------
+//
+// Two entry points, lz_read_parts_gather (a healthy region, de-interleaved
+// as it lands) and lz_read_parts_wave (one wave of a read plan: any
+// parts, each to its own place), share ONE receive state machine,
+// read_bulk_parts: they differ in where a block lands and in what a
+// failed part means for the others.
+struct lz_part_req {
+    int fd;
+    uint64_t chunk_id;
+    uint32_t version;
+    uint32_t part_id;
+    int32_t rc;
+};
+
+namespace {
+
+// lz_part_req.rc of a part whose exchange is under way
+constexpr int32_t kInFlight = 1 << 30;
+// ... of a part not finished when the call's deadline came
+constexpr int32_t kRcDeadline = -4;
+
+// Where one part's bulk reply lands: block b of the reply goes to
+// base + b * stride (stride == kBlockSize: the reply is contiguous).
+struct BulkLanding {
+    uint32_t offset;   // part-local, 64 KiB aligned
+    uint32_t size;     // bytes asked for; the last block may be short
+    uint8_t* base;
+    uint64_t stride;
+};
+
+// A part's rc is read by the caller's other threads while the call
+// runs (core/read_executor.py harvests finished parts at a wave's
+// timeout): the bytes and their CRC checks are published before it.
+inline void set_rc(lz_part_req& part, int32_t rc) {
+    __atomic_store_n(&part.rc, rc, __ATOMIC_RELEASE);
+}
+
+// The receive state machine of the bulk read exchange (CltocsReadBulk
+// 1206 out, one CstoclReadBulkData 1207 back: CRC table + raw range,
+// verified HERE, the only CRC pass on this path), for n parts over n
+// connected sockets in one poll loop. Entries whose rc is kInFlight on
+// entry are read; the others are left alone.
+//
+// independent: each part runs to its own end whatever the others do,
+// and one not finished at the deadline reads kRcDeadline. Otherwise
+// the round ends at the first failed part (the caller reads the region
+// again another way, so draining the rest would only burn bandwidth)
+// and whatever is unfinished reads -1.
+//
+// parts[i].rc: 0 ok; >0 peer status; -1 socket; -2 protocol; -3 CRC.
+// done_us[i] (may be null): microseconds from the start of the call to
+// part i's end, on the steady clock. Returns 0 iff every part read is
+// OK. A socket whose part did not end with rc 0 may hold an unread
+// remainder: discard it, never pool it.
+int read_bulk_parts(lz_part_req* parts, uint32_t n, const BulkLanding* land,
+                    int64_t deadline_ms, bool independent,
+                    uint64_t* done_us) {
+    struct St {
+        enum Phase { kHdr, kFixed, kCrcs, kDlen, kData } phase = kHdr;
+        uint8_t small[32];
+        uint32_t got = 0;          // bytes received in current phase
+        uint32_t ncrcs = 0;
+        std::vector<uint8_t> crcs;
+        uint64_t received = 0;     // data bytes so far
+    };
+    const int64_t t0 = steady_us();
+    std::vector<St> st(n);
+    uint32_t live = 0;
+    bool failed = false;
+    auto finish = [&](uint32_t i, int32_t rc) {
+        if (done_us) done_us[i] = static_cast<uint64_t>(steady_us() - t0);
+        if (rc != 0) failed = true;
+        set_rc(parts[i], rc);
+        --live;
+    };
+    for (uint32_t i = 0; i < n; ++i) {
+        if (parts[i].rc != kInFlight) continue;
+        ++live;
+        if (land[i].offset % kBlockSize || land[i].size == 0)
+            finish(i, -2);
+        else if (!send_read_request(parts[i].fd, kTypeReadBulk,
+                                    parts[i].chunk_id, parts[i].version,
+                                    parts[i].part_id, land[i].offset,
+                                    land[i].size))
+            finish(i, -1);
+    }
+    // the next destination of part i's data and how much may land there
+    // in one recv: up to the block's end, or the reply's where the
+    // landing is contiguous
+    auto data_span = [&](uint32_t i, uint8_t*& dst) -> uint64_t {
+        const uint64_t pos = st[i].received;
+        const uint64_t in_blk = pos % kBlockSize;
+        dst = land[i].base + (pos / kBlockSize) * land[i].stride + in_blk;
+        const uint64_t left = land[i].size - pos;
+        return land[i].stride == kBlockSize
+                   ? left
+                   : std::min<uint64_t>(kBlockSize - in_blk, left);
+    };
+    std::vector<pollfd> pfds(n);
+    std::vector<uint32_t> part_of(n);
+    while (live && (independent || !failed)) {
+        const int64_t now = steady_ms();
+        if (now >= deadline_ms) break;
+        int nfds = 0;
+        for (uint32_t i = 0; i < n; ++i) {
+            if (parts[i].rc != kInFlight) continue;
+            pfds[nfds].fd = parts[i].fd;
+            pfds[nfds].events = POLLIN;
+            pfds[nfds].revents = 0;
+            part_of[nfds++] = i;
+        }
+        int pr = ::poll(pfds.data(), nfds,
+                        static_cast<int>(std::min<int64_t>(
+                            deadline_ms - now, 30000)));
+        if (pr < 0) {
+            if (errno == EINTR) continue;
+            break;
+        }
+        for (int pi = 0; pi < nfds; ++pi) {
+            if (!(pfds[pi].revents & (POLLIN | POLLERR | POLLHUP))) continue;
+            const uint32_t i = part_of[pi];
+            St& s = st[i];
+            // drain as much as available without blocking
+            while (parts[i].rc == kInFlight) {
+                uint8_t* dst = s.small;
+                uint64_t want = 0;
+                switch (s.phase) {
+                    case St::kHdr: want = 8; break;
+                    case St::kFixed: want = 22; break;
+                    case St::kCrcs:
+                        dst = s.crcs.data();
+                        want = s.crcs.size();
+                        break;
+                    case St::kDlen: want = 4; break;
+                    case St::kData: want = data_span(i, dst); break;
+                }
+                ssize_t r = ::recv(parts[i].fd, dst + s.got,
+                                   static_cast<size_t>(want - s.got),
+                                   MSG_DONTWAIT);
+                if (r == 0) { finish(i, -1); break; }
+                if (r < 0) {
+                    if (errno == EAGAIN || errno == EWOULDBLOCK) break;
+                    if (errno == EINTR) continue;
+                    finish(i, -1);
+                    break;
+                }
+                s.got += static_cast<uint32_t>(r);
+                if (s.got < want) continue;
+                s.got = 0;
+                switch (s.phase) {
+                    case St::kHdr:
+                        if (get32(s.small) != kTypeReadBulkData ||
+                            get32(s.small + 4) < 22 + 4)
+                            finish(i, -2);
+                        else
+                            s.phase = St::kFixed;
+                        break;
+                    case St::kFixed:
+                        s.ncrcs = get32(s.small + 18);
+                        if (s.small[0] != kProtoVersion) {
+                            finish(i, -2);
+                        } else if (s.small[13] != 0) {
+                            // a refusal: what is left of its frame
+                            // stays unread (the socket is discarded)
+                            finish(i, s.small[13]);
+                        } else if (s.ncrcs != (land[i].size + kBlockSize -
+                                               1) / kBlockSize) {
+                            finish(i, -2);
+                        } else {
+                            s.crcs.resize(4 * size_t(s.ncrcs));
+                            s.phase = St::kCrcs;
+                        }
+                        break;
+                    case St::kCrcs:
+                        s.phase = St::kDlen;
+                        break;
+                    case St::kDlen:
+                        if (get32(s.small) != land[i].size)
+                            finish(i, -2);
+                        else
+                            s.phase = St::kData;
+                        break;
+                    case St::kData: {
+                        s.received += want;
+                        if (s.received < land[i].size) break;
+                        // every block's CRC, over where it landed
+                        int32_t rc = 0;
+                        for (uint32_t b = 0; b < s.ncrcs && rc == 0; ++b) {
+                            const uint32_t len = std::min<uint32_t>(
+                                kBlockSize, land[i].size - b * kBlockSize);
+                            if (lz_crc32(0, land[i].base + b * land[i].stride,
+                                         len) != get32(s.crcs.data() + 4 * b))
+                                rc = -3;
+                        }
+                        finish(i, rc);
+                        break;
+                    }
+                }
+            }
+        }
+    }
+    for (uint32_t i = 0; i < n; ++i) {
+        if (parts[i].rc == kInFlight) {
+            const bool late = independent && steady_ms() >= deadline_ms;
+            finish(i, late ? kRcDeadline : -1);
+        }
+    }
+    return failed ? -1 : 0;
+}
+
+}  // namespace
+
 // Whole-stripe fan-in: read the SAME [offset, offset+size) range of d
 // data parts over d already-connected sockets in ONE poll-driven loop,
 // scattering bytes straight into their gathered (de-interleaved) chunk
@@ -435,240 +646,49 @@ int lz_write_part(int fd, uint64_t chunk_id, const uint8_t* payload,
 // offset, identical across parts) must be 64 KiB aligned;
 // region_blocks is the number of 64 KiB chunk blocks to produce, and
 // out must cover region_blocks * 64 KiB bytes.
-struct lz_part_req {
-    int fd;
-    uint64_t chunk_id;
-    uint32_t version;
-    uint32_t part_id;
-    int32_t rc;
-};
-
 int lz_read_parts_gather(lz_part_req* parts, uint32_t d, uint32_t offset,
                          uint32_t region_blocks, uint8_t* out,
                          uint32_t max_ms) {
-    constexpr uint32_t kTypeReadBulk = 1206;
-    constexpr uint32_t kTypeReadBulkData = 1207;
     if (offset % kBlockSize || d == 0 || region_blocks == 0) return -1;
     // part i serves region blocks {j*d+i < region_blocks}: its request
     // size is its own block count (parts differ when d doesn't divide
     // the region)
-    std::vector<uint32_t> part_blocks(d);
-    for (uint32_t i = 0; i < d; ++i)
-        part_blocks[i] = (region_blocks > i)
-                             ? (region_blocks - i + d - 1) / d
-                             : 0;
-    const int64_t deadline = [] {
-        struct timespec ts;
-        clock_gettime(CLOCK_MONOTONIC, &ts);
-        return int64_t(ts.tv_sec) * 1000 + ts.tv_nsec / 1000000;
-    }() + max_ms;
+    std::vector<BulkLanding> land(d);
+    for (uint32_t i = 0; i < d; ++i) {
+        const uint32_t blocks =
+            (region_blocks > i) ? (region_blocks - i + d - 1) / d : 0;
+        land[i] = {offset, blocks * kBlockSize,
+                   out + uint64_t(i) * kBlockSize, uint64_t(d) * kBlockSize};
+        parts[i].rc = blocks ? kInFlight : 0;
+    }
+    return read_bulk_parts(parts, d, land.data(), steady_ms() + max_ms,
+                           false, nullptr);
+}
 
-    struct St {
-        enum Phase { kHdr, kFixed, kCrcs, kDlen, kData, kDone } phase = kHdr;
-        uint8_t small[32];
-        uint32_t got = 0;          // bytes received in current phase
-        uint32_t frame_len = 0;
-        uint32_t ncrcs = 0;
-        std::vector<uint8_t> crcs;
-        uint64_t received = 0;     // data bytes so far
-    };
-    std::vector<St> st(d);
-    // send all requests (blocking sockets, tiny frames)
-    for (uint32_t i = 0; i < d; ++i) {
-        if (part_blocks[i] == 0) {
-            parts[i].rc = 0;
-            continue;
-        }
-        uint8_t req[8 + 1 + 4 + 8 + 4 + 4 + 4 + 4 + 8 + 8];
-        size_t body = 1 + 4 + 8 + 4 + 4 + 4 + 4;
-        req[8] = kProtoVersion;
-        put32(req + 9, 1);
-        put64(req + 13, parts[i].chunk_id);
-        put32(req + 21, parts[i].version);
-        put32(req + 25, parts[i].part_id);
-        put32(req + 29, offset);
-        put32(req + 33, part_blocks[i] * kBlockSize);
-        if (g_trace_id != 0) {  // optional trailing trace + session (wire.h)
-            put64(req + 37, g_trace_id);
-            body += 8;
-            if (g_session_id != 0) {
-                put64(req + 45, g_session_id);
-                body += 8;
-            }
-        }
-        put32(req, kTypeReadBulk);
-        put32(req + 4, static_cast<uint32_t>(body));
-        parts[i].rc = send_all(parts[i].fd, req, 8 + body) ? 1 << 30 : -1;
-    }
-    uint32_t live = 0;
-    bool failed = false;
-    std::vector<pollfd> pfds(d);
-    for (uint32_t i = 0; i < d; ++i) {
-        if (parts[i].rc == (1 << 30)) ++live;
-        else if (parts[i].rc != 0) failed = true;
-    }
-    while (live && !failed) {
-        struct timespec ts;
-        clock_gettime(CLOCK_MONOTONIC, &ts);
-        int64_t now = int64_t(ts.tv_sec) * 1000 + ts.tv_nsec / 1000000;
-        if (now >= deadline) {
-            for (uint32_t i = 0; i < d; ++i)
-                if (parts[i].rc == (1 << 30)) parts[i].rc = -1;
-            break;
-        }
-        int nfds = 0;
-        for (uint32_t i = 0; i < d; ++i) {
-            if (parts[i].rc != (1 << 30)) continue;
-            pfds[nfds].fd = parts[i].fd;
-            pfds[nfds].events = POLLIN;
-            pfds[nfds].revents = 0;
-            ++nfds;
-        }
-        int pr = ::poll(pfds.data(), nfds,
-                        static_cast<int>(std::min<int64_t>(deadline - now,
-                                                           30000)));
-        if (pr < 0) {
-            if (errno == EINTR) continue;
-            break;
-        }
-        for (int pi = 0; pi < nfds; ++pi) {
-            if (!(pfds[pi].revents & (POLLIN | POLLERR | POLLHUP))) continue;
-            // map fd back to part index
-            uint32_t i = 0;
-            while (i < d && parts[i].fd != pfds[pi].fd) ++i;
-            if (i == d) continue;
-            St& s = st[i];
-            // drain as much as available without blocking
-            bool progress = true;
-            while (progress && parts[i].rc == (1 << 30)) {
-                progress = false;
-                uint8_t* dst = nullptr;
-                size_t want = 0;
-                switch (s.phase) {
-                    case St::kHdr: dst = s.small; want = 8; break;
-                    case St::kFixed: dst = s.small; want = 22; break;
-                    case St::kCrcs:
-                        dst = s.crcs.data();
-                        want = s.crcs.size();
-                        break;
-                    case St::kDlen: dst = s.small; want = 4; break;
-                    case St::kData: {
-                        // receive up to the end of the current block,
-                        // directly into the gathered position
-                        const uint64_t psize =
-                            uint64_t(part_blocks[i]) * kBlockSize;
-                        const uint64_t pos = s.received;
-                        const uint64_t blk = pos / kBlockSize;
-                        const uint64_t in_blk = pos % kBlockSize;
-                        dst = out +
-                              ((blk * d + i) * kBlockSize + in_blk);
-                        want = static_cast<size_t>(
-                            std::min<uint64_t>(kBlockSize - in_blk,
-                                               psize - pos));
-                        break;
-                    }
-                    case St::kDone: want = 0; break;
-                }
-                if (want == 0) break;
-                ssize_t n = ::recv(parts[i].fd, dst + s.got, want - s.got,
-                                   MSG_DONTWAIT);
-                if (n == 0) { parts[i].rc = -1; --live; break; }
-                if (n < 0) {
-                    if (errno == EAGAIN || errno == EWOULDBLOCK) break;
-                    if (errno == EINTR) { progress = true; continue; }
-                    parts[i].rc = -1; --live; break;
-                }
-                s.got += static_cast<uint32_t>(n);
-                if (s.got < want) { progress = true; continue; }
-                s.got = 0;
-                progress = true;
-                switch (s.phase) {
-                    case St::kHdr: {
-                        uint32_t type = get32(s.small);
-                        s.frame_len = get32(s.small + 4);
-                        if (type != kTypeReadBulkData ||
-                            s.frame_len < 22 + 4) {
-                            parts[i].rc = -2; --live;
-                            break;
-                        }
-                        s.phase = St::kFixed;
-                        break;
-                    }
-                    case St::kFixed: {
-                        if (s.small[0] != kProtoVersion) {
-                            parts[i].rc = -2; --live; break;
-                        }
-                        uint8_t status = s.small[13];
-                        s.ncrcs = get32(s.small + 18);
-                        if (status != 0) {
-                            parts[i].rc = status; --live; break;
-                        }
-                        if (s.ncrcs != part_blocks[i]) {
-                            parts[i].rc = -2; --live; break;
-                        }
-                        s.crcs.resize(4 * s.ncrcs);
-                        s.phase = St::kCrcs;
-                        break;
-                    }
-                    case St::kCrcs:
-                        s.phase = St::kDlen;
-                        break;
-                    case St::kDlen: {
-                        uint32_t dlen = get32(s.small);
-                        if (dlen != part_blocks[i] * kBlockSize) {
-                            parts[i].rc = -2; --live; break;
-                        }
-                        s.received = 0;
-                        s.phase = St::kData;
-                        break;
-                    }
-                    case St::kData: {
-                        const uint64_t psize =
-                            uint64_t(part_blocks[i]) * kBlockSize;
-                        const uint64_t pos = s.received;
-                        const uint64_t in_blk = pos % kBlockSize;
-                        s.received += std::min<uint64_t>(
-                            kBlockSize - in_blk, psize - pos);
-                        if (s.received >= psize) {
-                            // verify every block CRC over the gathered
-                            // destination regions
-                            int32_t rc = 0;
-                            for (uint32_t b = 0; b < part_blocks[i]; ++b) {
-                                const uint8_t* blkp =
-                                    out + (uint64_t(b) * d + i) * kBlockSize;
-                                if (lz_crc32(0, blkp, kBlockSize) !=
-                                    get32(s.crcs.data() + 4 * b)) {
-                                    rc = -3;
-                                    break;
-                                }
-                            }
-                            parts[i].rc = rc;
-                            s.phase = St::kDone;
-                            --live;
-                        }
-                        break;
-                    }
-                    case St::kDone: break;
-                }
-            }
-        }
-        // abort on the first failed part: the caller retries the whole
-        // region through the wave executor anyway, so draining the
-        // surviving streams would only burn bandwidth (the half-read
-        // sockets are discarded, never pooled)
-        for (uint32_t i = 0; i < d; ++i) {
-            if (parts[i].rc != 0 && parts[i].rc != (1 << 30)) {
-                failed = true;
-                break;
-            }
-        }
-    }
-    int ret = 0;
-    for (uint32_t i = 0; i < d; ++i) {
-        if (parts[i].rc == (1 << 30)) parts[i].rc = -1;
-        if (parts[i].rc != 0) ret = -1;
-    }
-    return ret;
+// One wave of a read plan: n parts over n already-connected sockets in
+// ONE poll loop on one thread, part i's [offsets[i], +sizes[i]) landing
+// contiguous at dsts[i] (its place in the plan's buffer). The parts are
+// independent: one that fails leaves the others running, since any k
+// of them can serve the plan. Entries whose rc the caller set to
+// LZ_WAVE_PENDING (1 << 30) are read, the others left alone (a part
+// the caller's pool had no idle socket for: it reads another way).
+//
+// parts[i].rc: 0 ok (every block's CRC checked); >0 peer status; -1
+// socket; -2 protocol (or a misaligned offset, a size of 0); -3 CRC;
+// -4 not finished at the deadline (max_ms from the call's start). An rc
+// is stored with release order after the part's last byte and check, so
+// another thread may read it while the call runs. done_us[i] receives
+// the microseconds from the call's start to part i's end on the steady
+// clock. Returns 0 iff every pending part ended OK, else -1.
+int lz_read_parts_wave(lz_part_req* parts, uint32_t n,
+                       const uint32_t* offsets, const uint32_t* sizes,
+                       uint8_t* const* dsts, uint32_t max_ms,
+                       uint64_t* done_us) {
+    std::vector<BulkLanding> land(n);
+    for (uint32_t i = 0; i < n; ++i)
+        land[i] = {offsets[i], sizes[i], dsts[i], kBlockSize};
+    return read_bulk_parts(parts, n, land.data(), steady_ms() + max_ms,
+                           true, done_us);
 }
 
 namespace {
@@ -695,7 +715,6 @@ struct RoundSend {
 // round ends on the first failure (the others read -1).
 int status_round(lz_part_req* parts, uint32_t n, const RoundSend* out,
                  bool match_write_id, int64_t deadline) {
-    constexpr int32_t kInFlight = 1 << 30;
     struct St {
         enum Phase { kSendHdr, kSendPay, kAckHdr, kAckPay, kDone };
         Phase phase = kSendHdr;

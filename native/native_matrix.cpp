@@ -89,12 +89,17 @@ int lz_write_parts_exchange(lz_part_req* parts, uint32_t n,
 int lz_read_parts_gather(lz_part_req* parts, uint32_t d, uint32_t offset,
                          uint32_t region_blocks, uint8_t* out,
                          uint32_t max_ms);
+int lz_read_parts_wave(lz_part_req* parts, uint32_t n,
+                       const uint32_t* offsets, const uint32_t* sizes,
+                       uint8_t* const* dsts, uint32_t max_ms,
+                       uint64_t* done_us);
 }
 
 namespace {
 
 constexpr uint32_t kBlock = 64 * 1024;
 constexpr uint32_t kScatterNoAck = 1;
+constexpr int32_t kWavePending = 1 << 30;
 
 std::atomic<int> g_failures{0};
 
@@ -289,6 +294,100 @@ void exchange_leg(lz_part_req* reqs, uint32_t d, uint64_t chunk_id,
     for (uint32_t p = 0; p < d; ++p) reqs[p].version = 1;
 }
 
+// One wave of a read plan in one call (lz_read_parts_wave): every part
+// contiguous at its own place; an entry that is not pending left alone;
+// a part that never answers reading -4 at the deadline while the others
+// end OK; a misaligned part refused before a byte is sent; and, last
+// (a refusal leaves its socket with an unread remainder), one part
+// refused by its server while the others end OK. On connections of its
+// own: a refused exchange leaves statuses unread on the shared ones.
+void wave_leg(int port, uint32_t d, uint64_t chunk_id,
+              const uint8_t* const* payloads, uint64_t part_len) {
+    const uint32_t plen = static_cast<uint32_t>(part_len);
+    std::vector<lz_part_req> reqs;
+    for (uint32_t p = 0; p < d; ++p) {
+        const int fd = lzwire::connect_data("127.0.0.1",
+                                            static_cast<uint16_t>(port));
+        if (fd < 0) fail("serve: wave connect");
+        reqs.push_back(lz_part_req{fd, chunk_id, 1, p, 0});
+    }
+    std::vector<uint8_t> buf((d + 1) * part_len, 0xEE);
+    std::vector<uint32_t> offs(d + 1, 0), sizes(d + 1, plen);
+    std::vector<uint8_t*> dsts(d + 1);
+    std::vector<uint64_t> us(d + 1, 0);
+    for (uint32_t p = 0; p <= d; ++p) dsts[p] = buf.data() + p * part_len;
+    auto pend = [&] { for (uint32_t p = 0; p < d; ++p) reqs[p].rc = kWavePending; };
+    auto whole = [&](uint32_t p) {
+        return std::memcmp(dsts[p], payloads[p], part_len) == 0;
+    };
+    pend();
+    if (lz_read_parts_wave(reqs.data(), d, offs.data(), sizes.data(),
+                           dsts.data(), 10000, us.data()) != 0)
+        fail("serve: read_parts_wave");
+    for (uint32_t p = 0; p < d; ++p) {
+        if (reqs[p].rc != 0 || !us[p] || !whole(p))
+            fail("serve: wave part");
+    }
+    // only part 1 pending, from its second block on, short of its end
+    std::fill(buf.begin(), buf.end(), 0xEE);
+    std::fill(us.begin(), us.end(), 0);
+    offs[1] = kBlock;
+    sizes[1] = plen - kBlock - 100;
+    reqs[1].rc = kWavePending;
+    if (lz_read_parts_wave(reqs.data(), d, offs.data(), sizes.data(),
+                           dsts.data(), 10000, us.data()) != 0 ||
+        std::memcmp(dsts[1], payloads[1] + kBlock, sizes[1]) != 0)
+        fail("serve: wave subset");
+    if (dsts[1][sizes[1]] != 0xEE || dsts[0][0] != 0xEE || us[0] || us[2])
+        fail("serve: wave touched what was not pending");
+    offs[1] = 0;
+    sizes[1] = plen;
+    // a peer that accepts and never answers, as part d
+    int lsn = ::socket(AF_INET, SOCK_STREAM, 0);
+    sockaddr_in sa{};
+    sa.sin_family = AF_INET;
+    sa.sin_addr.s_addr = htonl(INADDR_LOOPBACK);
+    socklen_t slen = sizeof(sa);
+    int mute = ::socket(AF_INET, SOCK_STREAM, 0);
+    if (::bind(lsn, reinterpret_cast<sockaddr*>(&sa), sizeof(sa)) != 0 ||
+        ::listen(lsn, 4) != 0 ||
+        ::getsockname(lsn, reinterpret_cast<sockaddr*>(&sa), &slen) != 0 ||
+        ::connect(mute, reinterpret_cast<sockaddr*>(&sa), sizeof(sa)) != 0) {
+        fail("serve: wave mute peer");
+    } else {
+        std::vector<lz_part_req> more(reqs);
+        more.push_back(lz_part_req{mute, chunk_id, 1, 0, 0});
+        for (auto& r : more) r.rc = kWavePending;
+        if (lz_read_parts_wave(more.data(), d + 1, offs.data(), sizes.data(),
+                               dsts.data(), 150, us.data()) == 0 ||
+            more[d].rc != -4)
+            fail("serve: wave deadline");
+        for (uint32_t p = 0; p < d; ++p) {
+            if (more[p].rc != 0 || !whole(p)) fail("serve: wave beside mute");
+        }
+    }
+    ::close(mute);
+    ::close(lsn);
+    // misaligned: refused here, the socket untouched
+    pend();
+    offs[0] = 17;
+    if (lz_read_parts_wave(reqs.data(), d, offs.data(), sizes.data(),
+                           dsts.data(), 10000, us.data()) == 0 ||
+        reqs[0].rc != -2 || reqs[1].rc != 0)
+        fail("serve: wave misaligned");
+    offs[0] = 0;
+    // a stale version on part 1: its server's status, the others whole
+    pend();
+    reqs[1].version = 99;
+    if (lz_read_parts_wave(reqs.data(), d, offs.data(), sizes.data(),
+                           dsts.data(), 10000, us.data()) == 0 ||
+        reqs[1].rc <= 0 || reqs[0].rc != 0 || reqs[2].rc != 0 || !whole(2))
+        fail("serve: wave refusal");
+    for (auto& r : reqs) {
+        if (r.fd >= 0) ::close(r.fd);
+    }
+}
+
 void serve_roundtrip(int port, uint64_t chunk_id, uint32_t seed) {
     const uint32_t d = 3, bpp = 2;
     const uint64_t part_len = uint64_t{bpp} * kBlock;
@@ -360,6 +459,9 @@ void serve_roundtrip(int port, uint64_t chunk_id, uint32_t seed) {
         if (lz_read_part(socks[0], chunk_id, 1, 0, 64u << 20, kBlock,
                          rd.data()) == 0)
             fail("serve: oob read accepted");
+        // what the servers hold now (exchange_leg rewrote a block)
+        lz_stripe_scatter(data.data(), data.size(), d, bpp, parts.data());
+        wave_leg(port, d, chunk_id, payloads, part_len);
     }
     for (uint32_t p = 0; p < d; ++p) {
         if (socks[p] >= 0) ::close(socks[p]);

@@ -410,7 +410,10 @@ async def test_degraded_read_yields_one_span_tree_that_sums_to_wall(
     from tests.test_write_phases import check_one_tree, encoder_for
 
     cluster = Cluster(tmp_path, n_cs=12)
-    await cluster.start()
+    # no rebuild while the reads run: these chunkservers share the
+    # process, and so the client's socket pool, and a replicator's wave
+    # would take the idle sockets the read's native wave rides on
+    await cluster.start(health_interval=30.0)
     try:
         c = await cluster.client()
         c.encoder = encoder_for(backend)
@@ -444,7 +447,23 @@ async def test_degraded_read_yields_one_span_tree_that_sums_to_wall(
         nets = [s for s in spans if s["name"] == "net"]
         assert len(nets) >= 8 and all(
             s["parent_id"] == waves[0]["span_id"] for s in nets)
-        assert d["net_ms"] > d["waves_ms"], "net sums the parallel parts"
+        # net sums the parallel parts (one row a part on every plane;
+        # on the wave plane a part's net is short beside `waves`, which
+        # also holds the wait for the loop)
+        durs = [(s["t1"] - s["t0"]) * 1e3 for s in nets]
+        assert d["net_ms"] == pytest.approx(sum(durs), abs=0.5)
+        assert min(durs) > 0.0
+        # what `waves` holds that is no part's `net` on the wave plane
+        # (PR 32): the wait for the one worker, a `hop` that ends
+        # before any part's net opens (on the native plane a part's net
+        # held its own hop), and the loop's wake-up after the last
+        # part's end, which is `waves`' self time
+        on_wave = [s for s in nets if s["attrs"]["plane"] == "wave"]
+        hops = [s for s in spans if s["name"] == "hop"
+                and s["parent_id"] == waves[0]["span_id"]]
+        assert len(on_wave) == 8 and len(hops) == 1
+        assert all(s["t0"] >= hops[0]["t1"] - 1e-4 for s in on_wave)
+        assert waves[0]["t1"] >= max(s["t1"] for s in on_wave)
         if backend != "cpu":
             rec = [s for s in spans if s["name"] == "boundary"]
             assert rec[0]["attrs"]["op"] == "recover"
