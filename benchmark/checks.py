@@ -24,7 +24,15 @@ Every number compared is an exact count with the limit 0:
                         the CRC32 of the reference's blocks
   readback_wrong_bytes  bytes of sampled live files read back cold after
                         the close that differ from the model's
-  unchecked             sampled items the comparison could not read
+  unchecked             sampled items the comparison could not read, and
+                        rebuilt chunks the mix asks for that the run
+                        does not have
+
+Where the window killed a server and the master rebuilt what it held,
+the mix's ``check.rebuilt_chunks`` more chunks are drawn from those of
+which a part was rebuilt, so that the rebuilt part files are compared
+in every run, not by the sample's luck; ``parts_wrong`` then asks for
+k + m parts on distinct live servers, the victim's directory left out.
 
 The reference (``benchmark/reference``) imports nothing of the program
 and takes nothing the program made.
@@ -63,10 +71,19 @@ def check_chunk(data: np.ndarray, k: int, m: int, block: int,
                 part_files: dict[int, str]) -> tuple[int, int]:
     """(wrong bytes, wrong CRC words) of one chunk's stored parts
     against the reference; ``part_files`` maps part index -> path."""
+    per_part = check_parts(data, k, m, block, part_files)
+    return (sum(b for b, _c in per_part.values()),
+            sum(c for _b, c in per_part.values()))
+
+
+def check_parts(data: np.ndarray, k: int, m: int, block: int,
+                part_files: dict[int, str]) -> dict[int, tuple[int, int]]:
+    """Part index -> (wrong bytes, wrong CRC words) of that part file."""
     want_parts = layout.expected_parts(data, k, m, block)
     live = layout.part_lengths(k, m, len(data), block)
-    bad_bytes = bad_crcs = 0
+    out = {}
     for p, path in part_files.items():
+        bad_bytes = bad_crcs = 0
         body, table = layout.read_part_file(path, block)
         want = want_parts[p]
         if len(body) < live[p]:
@@ -78,11 +95,45 @@ def check_chunk(data: np.ndarray, k: int, m: int, block: int,
         want_crcs = layout.block_crcs(want[:nblocks * block], block)
         bad_crcs += sum(1 for a, b in zip(table[:nblocks], want_crcs) if a != b)
         bad_crcs += max(nblocks - len(table), 0)
-    return bad_bytes, bad_crcs
+        out[p] = (bad_bytes, bad_crcs)
+    return out
 
 
-async def compare(traffic, client, config: dict, seed: int) -> dict:
-    """Run the whole comparison; returns name -> {"value", "limit"}."""
+async def chunk_table(traffic, client, config: dict) -> dict:
+    """Chunk id -> (k, m, chunk length, block) for every chunk of the
+    model's live files: the master is asked for the id alone, the
+    length and the goal are the harness's own."""
+    block, chunk_bytes = int(config["block_bytes"]), int(config["chunk_bytes"])
+    out = {}
+    for f in traffic.model.live():
+        if not f.length or f.name in traffic.uncertain:
+            continue
+        goal = traffic.dirs[f.dir].goal
+        for ci, (a, b) in enumerate(layout.chunk_spans(f.length, chunk_bytes)):
+            info = await client.chunk_info(f.inode, ci)
+            out[info.chunk_id] = (int(goal["k"]), int(goal["m"]), b - a, block)
+    return out
+
+
+async def rebuilt_picks(traffic, client, chunks: list, taken: list, n: int,
+                        rng) -> list:
+    """A seeded draw of ``n`` of the chunks, beyond those taken, of
+    which the master's records say a part was rebuilt."""
+    rebuilt = {cid for cid, _part in traffic.rebuilt_parts}
+    skip = {(f.name, ci) for f, ci in taken}
+    have = []
+    for f, ci in chunks:
+        if (f.name, ci) not in skip and \
+                (await client.chunk_info(f.inode, ci)).chunk_id in rebuilt:
+            have.append((f, ci))
+    return [have[i] for i in sorted(rng.choice(
+        len(have), size=min(n, len(have)), replace=False))]
+
+
+async def compare(traffic, client, config: dict, seed: int,
+                  notes: dict | None = None) -> dict:
+    """Run the whole comparison; returns name -> {"value", "limit"}.
+    ``notes`` takes what it saw of rebuilt parts, for the log."""
     block, chunk_bytes = int(config["block_bytes"]), int(config["chunk_bytes"])
     model, chk = traffic.model, traffic.mix["check"]
     v = dict.fromkeys(NAMES, 0)
@@ -114,7 +165,13 @@ async def compare(traffic, client, config: dict, seed: int) -> dict:
     chunks = [(f, ci) for f in sorted(live, key=lambda f: -f.length)
               for ci in range(-(-f.length // chunk_bytes))]
     picks = sample_with_first(chunks, int(chk["disk_chunks"]), rng)
+    want = int(chk.get("rebuilt_chunks", 0))
+    if want:
+        more = await rebuilt_picks(traffic, client, chunks, picks, want, rng)
+        v["unchecked"] += want - len(more)
+        picks = picks + more
     cs_dirs = traffic.cluster.live_cs_dirs()
+    rebuilt = {"chunks": 0, "parts": 0, "wrong_bytes": 0, "wrong_crcs": 0}
     for f, ci in picks:
         goal = traffic.dirs[f.dir].goal
         k, m = int(goal["k"]), int(goal["m"])
@@ -140,11 +197,19 @@ async def compare(traffic, client, config: dict, seed: int) -> dict:
                 v["parts_wrong"] += 1
             span = layout.chunk_spans(f.length, chunk_bytes)[ci]
             data = model.bytes_of(f, span[0], span[1] - span[0])
-            bad_b, bad_c = check_chunk(data, k, m, block, files)
-            v["stored_wrong_bytes"] += bad_b
-            v["stored_wrong_crcs"] += bad_c
+            per_part = check_parts(data, k, m, block, files)
+            v["stored_wrong_bytes"] += sum(b for b, _c in per_part.values())
+            v["stored_wrong_crcs"] += sum(c for _b, c in per_part.values())
+            mine = [p for p in per_part if (
+                info.chunk_id, p) in traffic.rebuilt_parts]
+            rebuilt["chunks"] += bool(mine)
+            rebuilt["parts"] += len(mine)
+            rebuilt["wrong_bytes"] += sum(per_part[p][0] for p in mine)
+            rebuilt["wrong_crcs"] += sum(per_part[p][1] for p in mine)
         except (OSError, ValueError, RuntimeError):
             v["unchecked"] += 1
+    if notes is not None:
+        notes["rebuilt"] = rebuilt
 
     for f in sample_with_first(sorted(live, key=lambda f: -f.length),
                                int(chk["readback_files"]), rng):

@@ -149,6 +149,17 @@ class Cluster:
         self.procs[name].wait(timeout=10)
         self.killed.add(name)
 
+    async def kill9_soon(self, name: str) -> float:
+        """SIGKILL from inside a running window: returns the moment of
+        the signal (monotonic) once the process is gone, and never
+        blocks the loop the sessions run on."""
+        self.killed.add(name)
+        at = time.monotonic()
+        self.procs[name].send_signal(signal.SIGKILL)
+        while self.procs[name].poll() is None:
+            await asyncio.sleep(0.005)
+        return at
+
     def live_cs_dirs(self) -> list[str]:
         return [self.cs_dir(i) for i in range(self.n_cs)
                 if f"cs{i}" not in self.killed]

@@ -9,6 +9,7 @@ clients. Everything a mix names is a file found by name:
   faults   set-up actions after the preload, each
            ``benchmark/traffic/faults/<fault>.py`` with
            ``async def apply(t)``
+  events   the same fault files, fired inside the window
 
 so a later PR adds a verb or a fault as a file and edits nothing here.
 A mix is:
@@ -27,6 +28,18 @@ A mix is:
                   set, each session in a seeded order of its own
   transfer_bytes  the size of one sequential read or write call
   preload         {"files", "bytes"}: files written during set-up
+  events          what happens inside the window beside the sessions:
+                  [{"at_share": 0-1, "fault": name}], each fired once,
+                  ``at_share`` of the window's seconds after its open,
+                  as a task of its own: no session's operation holds it
+                  and it holds none. A mix without the key runs as one
+                  that never had it
+  redundancy_cap_s  the one key that selects the wait: a mix that
+                  carries it has the worker poll the master from a kill
+                  inside the window and wait, that many seconds after
+                  the close at the most, for full redundancy before it
+                  compares. A mix without it, whatever its events do,
+                  runs as a plain window
   check           how much the comparison samples (see checks.py);
                   ``make_live`` names the steps that make a file, run
                   once a session after the close where the window left
@@ -200,6 +213,8 @@ class Traffic:
     uncertain: set = field(default_factory=set)         # names an op failed on
     unlinked: dict = field(default_factory=dict)        # name -> File, as last held
     lost_part_chunks: set = field(default_factory=set)  # any part on the victim
+    kill_at: float | None = None   # monotonic: when an event killed the victim
+    rebuilt_parts: set = field(default_factory=set)     # (chunk id, part id) since
     shared: dict = field(default_factory=dict)          # session -> its batch
     recording: bool = False
     stop_at: float = math.inf
@@ -212,17 +227,24 @@ class Traffic:
         self.verbs = {v: load_verb(v) for v in verbs_of(
             self.mix["steps"] + self.mix["check"].get("make_live", []))}
         self.faults = [load_fault(a) for a in self.mix.get("faults", [])]
+        self.events = [(float(e["at_share"]), load_fault(e["fault"]))
+                       for e in self.mix.get("events", [])]
         self.plan = plan(self.mix, self.seed)
         self.model = Model(make_pool(self.seed, self.plan.pool_bytes))
 
     # -- set-up ---------------------------------------------------------
 
-    async def setup(self) -> None:
+    async def setup(self, on_warm=None) -> None:
+        """Preload, set-up faults, then the warm-up run of the mix's own
+        steps; ``on_warm`` is called between the two, so that a caller
+        can tell what the warm-up run drove from what came before."""
         pre = self.mix.get("preload")
         if pre:
             await self._preload(int(pre["files"]), int(pre["bytes"]))
         for fault in self.faults:
             await fault.apply(self)
+        if on_warm is not None:
+            on_warm()
         await self._run(warm=True)
 
     async def _preload(self, n: int, nbytes: int) -> None:
@@ -251,9 +273,21 @@ class Traffic:
         self.recording = True
         t_open = time.monotonic()
         self.stop_at = t_open + seconds
-        await self._run(warm=False)
+        events = [asyncio.create_task(self._event(t_open + share * seconds,
+                                                  fault))
+                  for share, fault in self.events]
+        try:
+            await self._run(warm=False)
+            await asyncio.gather(*events)   # an event that failed fails the run
+        finally:
+            for task in events:
+                task.cancel()
         self.recording = False
         return t_open, self.stop_at
+
+    async def _event(self, at: float, fault) -> None:
+        await asyncio.sleep(max(at - time.monotonic(), 0.0))
+        await fault.apply(self)
 
     async def make_live(self) -> int:
         """After the close: where the window left too few files with

@@ -82,6 +82,17 @@ def find_part_files(cs_dirs: list[str], chunk_id: int,
     return out
 
 
+def find_chunk_files(cs_dirs: list[str], chunk_id: int) -> list[tuple[int, str]]:
+    """(part id, path) of every part file of the chunk, whatever its
+    slice type and version."""
+    out = []
+    for d in cs_dirs:
+        for p in glob.glob(os.path.join(
+                d, f"{chunk_id & 0xFF:02X}", f"chunk_{chunk_id:016X}_P*.liz")):
+            out.append((int(os.path.basename(p).split("_")[2][1:], 16), p))
+    return out
+
+
 def read_part_file(path: str, block: int) -> tuple[np.ndarray, list[int]]:
     """(stored bytes, stored CRC words of the blocks they cover)."""
     raw = np.fromfile(path, dtype=np.uint8)

@@ -22,8 +22,18 @@ import contextlib
 import threading
 import time
 
+from dataclasses import dataclass
+
 CONTROLS = ("parity-short", "recover-approx")
 FAULTS = ("encode-flip", "recover-flip")
+
+
+@dataclass(frozen=True)
+class TapCounts:
+    """The tap's calls as they stood at one moment."""
+
+    encode_calls: tuple   # (k, m, rows, part bytes, seconds)
+    recover_calls: tuple  # (k, m, rows used, wanted, part bytes, seconds)
 
 
 class EncoderTap:
@@ -39,6 +49,11 @@ class EncoderTap:
     def reset(self) -> None:
         self.encode_calls = []      # (k, m, rows, part bytes, seconds)
         self.recover_calls = []     # (k, m, rows used, wanted, part bytes, seconds)
+
+    def snapshot(self) -> TapCounts:
+        with self.lock:
+            return TapCounts(tuple(self.encode_calls),
+                             tuple(self.recover_calls))
 
     def remove(self) -> None:
         del self.enc.encode, self.enc.recover

@@ -12,7 +12,10 @@ the configuration broken in the encoder's place) or a fault planted in
 the timed path: a step that leaves the state unchanged (write-noop),
 half of the work left out (write-half), an answer altered where it is
 produced (read-flip, encode-flip, recover-flip). The cells run on one
-chip, so there is no exchange between chips to leave out.
+chip, so there is no exchange between chips to leave out. The last case
+is the other way round: a chunkserver that stands still inside the
+window (``stall_seeded``) is no fault of the program, and the run has to
+end as a sound one.
 """
 
 import json
@@ -22,15 +25,17 @@ import sys
 
 import pytest
 
+from stalled_manifest import make as stalled
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 RUN = os.path.join(os.path.dirname(HERE), "run.py")
 MARK = "rehearsal line (not a result): "
 
 
-def rehearse(workload: str, *extra: str) -> dict:
+def rehearse(workload: str, *extra: str, seconds: str = "2") -> dict:
     done = subprocess.run(
         [sys.executable, RUN, "--workload", workload, "--seed", "2147483777",
-         "--seconds", "2", "--trace", "0", "--rehearse-cpu", *extra],
+         "--seconds", seconds, "--trace", "0", "--rehearse-cpu", *extra],
         capture_output=True, text=True, timeout=600)
     lines = [ln for ln in done.stdout.splitlines() if MARK in ln]
     assert lines, done.stdout[-2000:] + done.stderr[-2000:]
@@ -76,3 +81,22 @@ def test_planted_fault_comes_out_not_correct(workload, fault, caught_by):
     line = rehearse(workload, "--fault", fault)
     assert line["correct"] is False
     assert caught_by in failing(line)
+
+
+@pytest.mark.parametrize("workload", [
+    "ec32-stream-write", "ec32-small-files", "ec84-degraded-read"])
+def test_a_server_standing_still_fails_no_sound_run(workload, tmp_path):
+    """A part read that waits past the clients' wave timeout is decoded
+    from parity across the boundary: set-up has warmed that program, so
+    nothing compiles inside the window (the worker would exit 4)."""
+    done = subprocess.run(
+        [sys.executable, RUN, "--workload", workload, "--seed", "2147483777",
+         "--seconds", "10", "--trace", "0", "--rehearse-cpu", "--manifest",
+         stalled(str(tmp_path), workload)],
+        capture_output=True, text=True, timeout=600)
+    assert "warmed the decode a slow or lost part would force" in done.stdout
+    assert "compiled or loaded inside the measured window" not in done.stdout
+    assert done.returncode == 0, done.stdout[-2000:]
+    line = json.loads([ln for ln in done.stdout.splitlines()
+                       if MARK in ln][-1].split(MARK, 1)[1])
+    assert line["correct"] is True and line["failed"] == 0

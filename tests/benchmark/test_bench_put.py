@@ -18,7 +18,7 @@ def test_the_cell_and_its_metrics():
         "ec84-13cs-put", "put-whole", 1)
     cell = manifest.Cell(M, CELL)
     assert {m["name"] for m in cell.end_to_end} == {"write_MBps", "setup_s"}
-    assert {m["name"] for m in cell.per_layer} == {
+    assert {m["name"] for m in cell.per_layer} >= {
         "write_encode_busy_pct", "write_send_busy_pct",
         "encode_boundary_MBps", "encode_kernel_roofline",
         "device_idle_pct.write"}
