@@ -49,7 +49,8 @@ from lizardfs_tpu.runtime import qos as qosmod
 from lizardfs_tpu.runtime import retry as retrymod
 from lizardfs_tpu.runtime import tracing
 from lizardfs_tpu.runtime.metrics import (
-    READ_PHASES, WRITE_COUNTS, WRITE_PHASES, PhaseBreakdown,
+    READ_COUNTS, READ_PHASES, WRITE_COUNTS, WRITE_PHASES, CallRows,
+    PhaseBreakdown,
 )
 from lizardfs_tpu.runtime.rpc import RpcConnection
 from lizardfs_tpu.utils import striping
@@ -247,8 +248,11 @@ class Client:
         # plan's parallel part reads: net, socket transfer incl. the
         # native gather call, and dial, pool-miss connects, inside it),
         # decode (plan postprocess / EC recovery), gather (stripe
-        # de-interleave), copy
-        self.read_phases = PhaseBreakdown("client_read", READ_PHASES)
+        # de-interleave), copy. Beside the times it counts which path
+        # served a chunk's range and what the BlockCache did
+        # (runtime.metrics.READ_COUNTS; _count_read).
+        self.read_phases = PhaseBreakdown(
+            "client_read", READ_PHASES, READ_COUNTS)
         # request-scoped span ring (runtime/tracing.py): every
         # tracing.span of an op lands here with its parent; merge with
         # daemon `trace-dump` output via tracing.merge_timeline
@@ -277,6 +281,20 @@ class Client:
         self._read_op = tracing.OpSink(
             self.read_phases, self.trace_ring, "client", self.metrics
         )
+        # the metadata calls that are ops of their own (a gateway's
+        # HEAD and DELETE): a root span each, its rows on the side it
+        # belongs to, closing no rep there (runtime.metrics.CallRows)
+        self._meta_ops = {
+            call: tracing.OpSink(
+                CallRows(rows, count, call + "s"), self.trace_ring,
+                "client", self.metrics,
+            )
+            for call, rows, count in (
+                ("lookup", self.read_phases, self._count_read),
+                ("get_xattr", self.read_phases, self._count_read),
+                ("unlink", self.write_phases, self._count_write),
+            )
+        }
         # adaptive N-deep write window (spends PR 1's phase telemetry):
         # stripe segments ride unacknowledged per striped chunk write
         # under per-chunkserver credits + a shared staging-byte budget,
@@ -423,6 +441,25 @@ class Client:
         ``op_counters``, for the mount's ``.stats``."""
         self.write_phases.count(name, n)
         self._count_op(name, n)
+
+    def _count_read(self, name: str, n: int = 1) -> None:
+        """One of the read path's counts (READ_COUNTS), kept as
+        :meth:`_count_write` keeps the write path's."""
+        self.read_phases.count(name, n)
+        self._count_op(name, n)
+
+    async def _meta_call(self, call: str, via, msg_cls, **fields):
+        """A metadata call that is an op of its own, as a root span
+        named after it with the master's stamped handler time laid
+        under it as ``<call>_srv`` (``_note_srv``): what is left of the
+        span is the wire and the two loops. Inside another op (a path
+        walk under a read) it is a plain span of that op."""
+        sink = (self._meta_ops[call] if tracing.PHASE_SINK.get() is None
+                else None)
+        with tracing.span(call, phase=call, bucket="net", sink=sink) as sp:
+            reply = await via(msg_cls, **fields)
+            self._note_srv(sp, call + "_srv", reply)
+        return reply
 
     async def _retry_transient(self, what: str, attempt_fn) -> None:
         """Run ``attempt_fn`` under the unified RetryPolicy
@@ -886,7 +923,8 @@ class Client:
 
     async def lookup(self, parent: int, name: str, uid: int | None = None,
                      gids: list[int] | None = None) -> m.Attr:
-        r = await self._call_read(
+        r = await self._meta_call(
+            "lookup", self._call_read,
             m.CltomaLookup, parent=parent, name=name, **self._ident(uid, gids)
         )
         return r.attr
@@ -981,7 +1019,8 @@ class Client:
 
     async def unlink(self, parent: int, name: str, uid: int | None = None,
                      gids: list[int] | None = None) -> None:
-        await self._call(
+        await self._meta_call(
+            "unlink", self._call,
             m.CltomaUnlink, parent=parent, name=name, **self._ident(uid, gids)
         )
         self._dentry_drop(parent, name)
@@ -1177,8 +1216,9 @@ class Client:
     async def get_xattr(self, inode: int, name: str,
                         uid: int | None = None,
                         gids: list[int] | None = None) -> bytes:
-        r = await self._call(m.CltomaGetXattr, inode=inode, name=name,
-                             **self._ident(uid, gids))
+        r = await self._meta_call(
+            "get_xattr", self._call, m.CltomaGetXattr, inode=inode,
+            name=name, **self._ident(uid, gids))
         return r.value
 
     async def remove_xattr(self, inode: int, name: str,
@@ -1603,11 +1643,15 @@ class Client:
         """The master's stamped handler time (``srv_us``, 0 from a
         master that predates it) as an attribute of the client's span
         and a phase of its own, laid in the middle of the round trip:
-        what is left of the span is the wire and the two loops."""
-        srv_s = getattr(reply, "srv_us", 0) / 1e6
-        if srv_s <= 0:
+        what is left of the span is the wire and the two loops. A
+        reply that ends in an Attr carries the stamp on the Attr's
+        tail (proto/messages.py)."""
+        srv_us = getattr(reply, "srv_us", 0) or getattr(
+            getattr(reply, "attr", None), "srv_us", 0)
+        if srv_us <= 0:
             return
-        sp.attrs["srv_us"] = reply.srv_us
+        srv_s = srv_us / 1e6
+        sp.attrs["srv_us"] = srv_us
         now = _time.perf_counter()
         mid = sp.p0 + max(now - sp.p0 - srv_s, 0.0) / 2
         tracing.span(phase, layer="master", phase=phase,
@@ -2557,6 +2601,7 @@ class Client:
             with accounting.task_session(self.session_id):
                 data = await self._read_file_inner(inode, offset, size)
             root.attrs["bytes"] = len(data)
+        self._count_read("read_bytes", len(data))
         # ONE logical read == ONE accounting record: replica fallbacks
         # and dead-holder retries below this line never double-count
         self.session_ops.record(
@@ -2636,6 +2681,7 @@ class Client:
             n = root.attrs["bytes"] = end - offset
             with accounting.task_session(self.session_id):
                 await self._read_into(inode, offset, out[:n], length)
+        self._count_read("read_bytes", n)
         self.session_ops.record(
             self.session_id, "read", _time.perf_counter() - tp0, nbytes=n,
             trace_id=root.trace_id,
@@ -2716,7 +2762,10 @@ class Client:
         )
         lo_b = off // MFSBLOCKSIZE
         hi_b = (off + size - 1) // MFSBLOCKSIZE
-        if not bulk:
+        nblocks = hi_b - lo_b + 1
+        if bulk:
+            self._count_read("cache_bypass_blocks", nblocks)
+        else:
             if self.cache.is_suspect(inode, chunk_index, lo_b, hi_b):
                 await self._revalidate_blocks(inode, chunk_index)
             # cache fast path: all covering blocks resident
@@ -2728,7 +2777,9 @@ class Client:
                 joined = b"".join(cached)
                 rel = off - lo_b * MFSBLOCKSIZE
                 if len(joined) >= rel + size:
+                    self._count_read("cache_hit_blocks", nblocks)
                     return np.frombuffer(joined, dtype=np.uint8)[rel : rel + size]
+            self._count_read("cache_miss_blocks", nblocks)
 
         # block-align the request and extend by the readahead window;
         # bulk reads skip the extension — they bypass the cache, so
@@ -3114,6 +3165,7 @@ class Client:
                 )
             except (ReadError, ConnectionError, OSError) as e:
                 raise _tag(e)
+            self._count_read("planned_chunks")
             if in_place:
                 return None  # bytes landed in `into`
             return np.asarray(result[:size])
@@ -3187,6 +3239,7 @@ class Client:
                 # actually taken (a silent precondition miss would
                 # quietly forfeit the 3x read win)
                 self._record("stripe_gather_fast")
+                self._count_read("gather_chunks")
                 return None
             except asyncio.CancelledError:
                 native_io.abort_parts_gather(cell)
@@ -3220,6 +3273,7 @@ class Client:
             on_part_failure=self._part_failure_observer(loc),
             count=self._count_op,
         )
+        self._count_read("planned_chunks")
         # reassemble the stripes we read, then slice the requested bytes.
         # The gather runs off-loop (native stripe_gather releases the
         # GIL) — at 64 MiB chunks an on-loop de-interleave serialized

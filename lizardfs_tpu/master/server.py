@@ -726,11 +726,15 @@ class MasterServer(Daemon):
 
     @staticmethod
     def _stamp_srv(reply, dt: float) -> None:
-        """Stamp the handler's own time on a grant / locate reply
-        (trailing, skew-tolerant ``srv_us``): the client's span round
-        the RPC less this is the wire and the two event loops."""
-        if hasattr(reply, "srv_us"):
-            reply.srv_us = min(max(int(dt * 1e6), 1), 0xFFFFFFFF)
+        """Stamp the handler's own time on a reply that carries the
+        trailing, skew-tolerant ``srv_us`` (grant, locate, status,
+        xattr), or on its nested Attr's tail (as ``_stamp_token``): the
+        client's span round the RPC less this is the wire and the two
+        event loops."""
+        target = reply if hasattr(reply, "srv_us") else getattr(
+            reply, "attr", None)
+        if hasattr(target, "srv_us"):
+            target.srv_us = min(max(int(dt * 1e6), 1), 0xFFFFFFFF)
 
     async def _client_loop(self, reader, writer, first: m.CltomaRegister) -> None:
         if not self.is_active:
@@ -3656,13 +3660,25 @@ class MasterServer(Daemon):
         # released chunks: delete their on-disk parts
         drained = self.meta.registry.pending_deletes[:16]
         del self.meta.registry.pending_deletes[:16]
+        sent = self.metrics.counter(
+            "chunk_deletes_sent",
+            "MatocsDeleteChunk commands sent to the holders of released "
+            "chunks' parts (unlink of a file with trash time 0, trash "
+            "expiry, truncate)",
+        )
         for dead in drained:
             t = geometry.SliceType(dead.slice_type)
             for cs_id, part in dead.parts:
                 link = self.cs_links.get(cs_id)
                 if link is None:
                     continue
+                sent.inc()
                 self.spawn(self._delete_orphan(link, dead, t, part))
+        self.metrics.gauge(
+            "chunk_deletes_pending",
+            help="released chunks whose parts' delete commands wait for "
+                 "a later health tick (16 chunks a tick)",
+        ).set(len(self.meta.registry.pending_deletes))
         if len(self._repl_fail_until) > 256:
             # deleted/abandoned chunks leave expired deadlines behind;
             # prune so the dict tracks only active backoffs

@@ -45,7 +45,12 @@ class Attr(Message):
     NOT a file attribute but the serving master's applied changelog
     position, stamped at reply time. It rides Attr because Attr is the
     skew-variable terminal field of MatoclAttrReply (the codec forbids
-    fields after it); see MatoclReadChunk for the token semantics."""
+    fields after it); see MatoclReadChunk for the token semantics.
+
+    ``srv_us`` (trailing, skew-tolerant): the master's handler time for
+    the RPC this Attr answers, for the same reason on the same tail
+    (see MatoclReadChunk; 0 from a master that predates it, and in an
+    Attr that answers no RPC)."""
 
     SKEW_TOLERANT_FROM = 12
     FIELDS = (
@@ -63,6 +68,7 @@ class Attr(Message):
         ("trash_time", "u32"),
         ("eattr", "u8"),
         ("meta_version", "u64"),
+        ("srv_us", "u32"),
     )
 
 
@@ -236,13 +242,17 @@ class MatoclStatusReply(Message):
     admission controller's backoff hint on BUSY sheds — QoS sheds
     answer ANY request type with this reply (the RPC pump resolves by
     req_id and call_ok raises before typed-field access), so the hint
-    needs exactly one carrier. 0 / absent = no hint."""
+    needs exactly one carrier. 0 / absent = no hint.
+
+    ``srv_us`` (trailing, skew-tolerant): the master's handler time,
+    see MatoclReadChunk: the client lays it inside the root span of a
+    call that is an op of its own (unlink)."""
 
     MSG_TYPE = 1013
     SKEW_TOLERANT_FROM = 2
     FIELDS = (
         ("req_id", "u32"), ("status", "u8"), ("meta_version", "u64"),
-        ("retry_after_ms", "u32"),
+        ("retry_after_ms", "u32"), ("srv_us", "u32"),
     )
 
 
@@ -539,8 +549,11 @@ class CltomaGetXattr(Message):
 
 
 class MatoclXattrReply(Message):
+    # trailing ``srv_us``: the master's handler time, see MatoclReadChunk
     MSG_TYPE = 1041
-    FIELDS = (("req_id", "u32"), ("status", "u8"), ("value", "bytes"))
+    SKEW_TOLERANT_FROM = 3
+    FIELDS = (("req_id", "u32"), ("status", "u8"), ("value", "bytes"),
+              ("srv_us", "u32"))
 
 
 class CltomaListXattr(Message):

@@ -224,7 +224,9 @@ WRITE_COUNTS = ("rmw_reads", "rmw_read_bytes", "rmw_region_bytes",
                 "payload_bytes",
                 "window_chunks", "fallback_chunks", "window_segments",
                 "window_credit_waits", "ring_parts", "socket_parts",
-                "window_depth_sum")
+                "window_depth_sum",
+                # unlink calls (their rows: CallRows, below)
+                "unlinks")
 READ_PHASES = {
     "locate": None, "locate_srv": "locate", "wait": None, "plan": None,
     # the plan's part reads, in parallel: net and dial sum over them
@@ -233,6 +235,17 @@ READ_PHASES = {
     **{k: v or "decode" for k, v in _BOUNDARY_PHASES.items()},
     "gather": None, "copy": None,
 }
+# What the read path counts beside its times, where the decision is
+# made: chunk ranges the one native gather served whole
+# (stripe_gather_fast) and chunk ranges a read plan's waves served (no
+# caller's buffer to land in, a part missing, slow or refused, a
+# standard copy); 64 KiB blocks the BlockCache answered, blocks a read
+# that asked it had to fetch, and blocks of reads that pass it by
+# (bulk, or an inode flagged NOCACHE); the bytes read_file returned;
+# lookup and get_xattr calls (their rows: CallRows, below).
+READ_COUNTS = ("gather_chunks", "planned_chunks", "cache_hit_blocks",
+               "cache_miss_blocks", "cache_bypass_blocks", "read_bytes",
+               "lookups", "get_xattrs")
 
 
 class PhaseBreakdown:
@@ -296,6 +309,26 @@ class PhaseBreakdown:
             out["reps"] = self.reps
             out.update(self.counts)
         return out
+
+
+class CallRows:
+    """The rows of a metadata call that is an op of its own (lookup,
+    get_xattr, unlink), kept on the breakdown of the side it belongs
+    to: the call's root span and the master's handler time laid under
+    it charge there under their own names (``unlink``, ``unlink_srv``:
+    rows the side's tree does not name), and where a read or a write
+    closes a rep the call counts itself (``calls``): reps, wall and
+    self stay the side's own ops'."""
+
+    __slots__ = ("add", "count", "calls")
+
+    def __init__(self, rows: PhaseBreakdown, count, calls: str):
+        self.add = rows.add
+        self.count = count      # the side's own: (name, n=1)
+        self.calls = calls
+
+    def add_wall(self, seconds: float, self_seconds: float | None = None) -> None:
+        self.count(self.calls)
 
 
 def top_level_ms(snapshot: dict, phases: dict) -> dict:

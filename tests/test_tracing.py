@@ -14,7 +14,7 @@ import pytest
 
 from lizardfs_tpu.proto import framing, messages as m
 from lizardfs_tpu.runtime import tracing
-from lizardfs_tpu.runtime.metrics import Metrics
+from lizardfs_tpu.runtime.metrics import Metrics, phase_delta
 
 from tests.test_cluster import Cluster, EC_GOAL
 
@@ -491,6 +491,104 @@ def test_srv_us_version_skew():
         assert cls(**fields).pack_body() == old
         with pytest.raises(Exception):
             cls.parse(body[:20])  # cut inside file_length: no zero-fill
+
+
+@pytest.mark.parametrize("cls,fields,where,width", [
+    (m.MatoclStatusReply, dict(req_id=1, status=0, meta_version=9,
+                               retry_after_ms=0), None, 4),
+    (m.MatoclXattrReply, dict(req_id=1, status=0, value=b"etag"), None, 4),
+    (m.MatoclAttrReply, dict(req_id=1, status=0), "attr", 4),
+], ids=lambda v: getattr(v, "__name__", None))
+def test_srv_us_trails_the_metadata_replies(cls, fields, where, width):
+    """``srv_us`` also trails the status reply (unlink), the xattr
+    reply and, as the consistency token does, the Attr that ends an
+    attr reply (lookup): a master that predates it is decoded as 0, and
+    a reply that carries none is the encoding before the field."""
+    attr = dict(inode=7, ftype=1, mode=0o644, uid=0, gid=0, atime=1, mtime=2,
+                ctime=3, nlink=1, length=10, goal=1, trash_time=0, eattr=0,
+                meta_version=9)
+
+    def make(srv_us):
+        if where:
+            return cls(attr=m.Attr(srv_us=srv_us, **attr), **fields)
+        return cls(srv_us=srv_us, **fields)
+
+    def stamp(msg):
+        return getattr(msg, where).srv_us if where else msg.srv_us
+
+    body = make(1234).pack_body()
+    assert stamp(cls.parse(body)) == 1234
+    old = body[:-width]
+    decoded = cls.parse(old)
+    assert stamp(decoded) == 0 and decoded.req_id == 1
+    if where:
+        assert decoded.attr.length == 10 and decoded.attr.meta_version == 9
+    plain = make(0).pack_body()
+    assert old.startswith(plain) and cls.parse(plain).req_id == 1
+
+
+@pytest.mark.asyncio
+async def test_metadata_calls_are_ops_with_the_handler_inside(tmp_path):
+    """``lookup``, ``get_xattr`` and ``unlink`` called as ops of their
+    own are root spans with the RPC and the master's stamped handler
+    time under them; their rows land on the read side (lookup,
+    get_xattr) and the write side (unlink) under their own names, they
+    count themselves, and close no rep there. Inside another op a call
+    is a plain span of that op. The master counts the delete commands
+    an unlink of a file with trash time 0 leaves to its holders."""
+    cluster = Cluster(tmp_path, n_cs=3)
+    await cluster.start(health_interval=0.2)
+    try:
+        c = await cluster.client()
+        f = await c.create(1, "obj")
+        await c.settrashtime(f.inode, 0)
+        await c.pwrite(f.inode, 0, b"x" * 1000)
+        await c.set_xattr(f.inode, "user.etag", b"abc")
+        w0, r0 = c.write_phases.snapshot(), c.read_phases.snapshot()
+        c.trace_ring.clear()
+        attr = await c.lookup(1, "obj")
+        assert await c.get_xattr(attr.inode, "user.etag") == b"abc"
+        await c.unlink(1, "obj")
+        w = phase_delta(c.write_phases.snapshot(), w0)
+        r = phase_delta(c.read_phases.snapshot(), r0)
+        assert (r["lookups"], r["get_xattrs"], w["unlinks"]) == (1, 1, 1)
+        assert r["reps"] == w["reps"] == 0 and r["wall_ms"] == w["wall_ms"] == 0
+        assert 0 < r["lookup_srv_ms"] <= r["lookup_ms"]
+        assert 0 < r["get_xattr_srv_ms"] <= r["get_xattr_ms"]
+        assert 0 < w["unlink_srv_ms"] <= w["unlink_ms"]
+        assert c.op_counters["unlinks"] == c.op_counters["lookups"] == 1
+        spans = c.trace_ring.dump()
+        by_name = {s["name"]: s for s in spans}
+        for call, rpc in (("lookup", "CltomaLookup"),
+                          ("get_xattr", "CltomaGetXattr"),
+                          ("unlink", "CltomaUnlink")):
+            root = by_name[call]
+            assert root["parent_id"] == 0 and root["attrs"]["srv_us"] >= 1
+            assert by_name[rpc]["parent_id"] == root["span_id"]
+            srv = by_name[call + "_srv"]
+            assert srv["parent_id"] == root["span_id"]
+            assert root["t0"] <= srv["t0"] and srv["t1"] <= root["t1"] + 1e-4
+        assert len({by_name[n]["trace_id"]
+                    for n in ("lookup", "get_xattr", "unlink")}) == 3
+        # inside another op: a span of that op, no root, no call counted
+        c.trace_ring.clear()
+        with tracing.span("read_file", sink=c._read_op):
+            with pytest.raises(Exception):
+                await c.lookup(1, "obj")    # gone: the span still closes
+        inner = {s["name"]: s for s in c.trace_ring.dump()}
+        assert inner["lookup"]["parent_id"] == inner["read_file"]["span_id"]
+        assert c.read_phases.snapshot()["lookups"] == r0["lookups"] + 1
+        # the master's side of the unlink: one command a holder of a part
+        for _ in range(100):
+            sent = cluster.master.metrics.series.get("chunk_deletes_sent")
+            if sent is not None and sent.total:
+                break
+            await asyncio.sleep(0.05)
+        assert sent.total == 1          # goal 1: one copy, one holder
+        assert cluster.master.metrics.series[
+            "chunk_deletes_pending"].value == 0
+    finally:
+        await cluster.stop()
 
 
 def test_content_gen_version_skew():

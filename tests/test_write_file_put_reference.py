@@ -14,6 +14,7 @@ stopped, and what the window counted beside its phase rows against what
 the geometry alone predicts.
 """
 
+import asyncio
 import os
 import sys
 
@@ -25,7 +26,7 @@ from lizardfs_tpu.constants import MFSBLOCKSIZE, MFSCHUNKSIZE
 from lizardfs_tpu.core import native_io
 from lizardfs_tpu.runtime.metrics import WRITE_COUNTS, phase_delta
 
-from tests.test_cluster import STD2_GOAL, WIDE_EC_GOAL, Cluster
+from tests.test_cluster import EC_GOAL, STD2_GOAL, WIDE_EC_GOAL, Cluster
 from tests.test_pwrite_ec32_reference import compare_stored, stop_holder_of
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -46,6 +47,9 @@ OBJECTS = {
     # a whole chunk, and a tail of five blocks that falls back
     "chunk_and_short_tail": MFSCHUNKSIZE + 5 * MFSBLOCKSIZE,
     "two_whole_chunks": 2 * MFSCHUNKSIZE,
+    # warp's default object: 20 blocks a part, no whole number of the
+    # window's segments (six of three blocks and one of two)
+    "ten_mib_seven_segments": 10 * MiB,
 }
 
 pytestmark = pytest.mark.skipif(
@@ -321,5 +325,205 @@ async def test_the_put_sequence_publishes_a_whole_object(tmp_path):
         assert c.op_counters["window_chunks"] == 1
         assert not c.op_counters.get("fallback_chunks")
         assert not t.uncertain
+    finally:
+        await cluster.stop()
+
+
+# -- warp mixed: GET, STAT, PUT and DELETE side by side -------------------
+
+MIXED_GOALS = {
+    "ec32": (EC_GOAL, {"id": EC_GOAL, "name": "ec32", "expr": "$ec(3,2)",
+                       "k": 3, "m": 2}, 6),
+    "ec84": (WIDE_EC_GOAL, None, 13),
+}
+
+
+async def mixed_traffic(cluster, goal_name: str, seed: int, sessions: int):
+    """The cell ``ec84-s3-mixed`` at its rehearsal's size under the one
+    generator, on an in-process cluster at the named goal."""
+    cell = manifest.Cell(manifest.load_manifest(), "ec84-s3-mixed")
+    manifest.rehearsal_of(cell)
+    goal_id, goal, _n = MIXED_GOALS[goal_name]
+    goal = goal or cell.config["goals"][0]
+    clients = [await cluster.client() for _ in range(sessions)]
+    dirs = []
+    for entry in cell.config["directories"]:
+        d = await clients[0].mkdir(1, entry["name"])
+        await clients[0].setgoal(d.inode, goal_id)
+        dirs.append(generator.Directory(entry["name"], d.inode, goal))
+    mix = dict(cell.mix, sessions=sessions, objects=6)
+    t = generator.Traffic(mix, seed, clients, dirs, None,
+                          int(cell.config["chunk_bytes"]))
+    return t, cell, goal
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("goal_name", sorted(MIXED_GOALS))
+async def test_mixed_operations_match_the_reference(tmp_path, goal_name):
+    """A seeded run of 200 mixed operations through the benchmark's
+    verb (``traffic/verbs/warp_mixed_op.py``: the S3 gateway's Client
+    calls) from four sessions side by side, after the set-up action has
+    PUT the pool: every GET's answer byte for byte against the model,
+    the shares exact, the bucket's listing after the DELETEs, the part
+    files of three live objects against the reference, and what the
+    read path counted."""
+    cluster = Cluster(tmp_path, n_cs=MIXED_GOALS[goal_name][2])
+    await cluster.start(health_interval=0.5)
+    try:
+        t, cell, goal = await mixed_traffic(cluster, goal_name, 34, 4)
+        k, m_par = goal["k"], goal["m"]
+        step, = cell.mix["steps"]
+        verb = t.verbs[step["verb"]]
+        for fault in t.faults:
+            await fault.apply(t)
+        assert len(t.model.live()) == len(verb.pool(t).live) == 6
+        t.recording = True
+        compared = []
+
+        def compare_now(st, f, offset, size, data):
+            want = t.model.bytes_of(f, offset, size)
+            assert len(data) == size == f.length
+            assert np.array_equal(np.frombuffer(data, np.uint8), want)
+            compared.append(f.name)
+
+        t.retain = compare_now
+        before = [c.read_phases.snapshot() for c in t.clients]
+
+        async def session(s: int) -> None:
+            for _ in range(50):
+                await verb.do(t, s, t._state(s), step, False)
+
+        await asyncio.gather(*(session(s) for s in range(4)))
+        by_class = {}
+        for op in t.ops:
+            assert op.ok
+            by_class[op.cls] = by_class.get(op.cls, 0) + 1
+        # two and a half blocks of 20 a session: 9 / 6 / 3 / 2 in every
+        # whole block, the half block free
+        assert sum(by_class.values()) == 200
+        for cls, share in (("read", 9), ("stat", 6), ("write", 3),
+                           ("delete", 2)):
+            assert 8 * share <= by_class[cls] <= 8 * share + 40
+        assert len(compared) == by_class["read"]
+        assert not t.uncertain and not t.session_errors()
+        assert all(seen == want for _n, seen, want in t.getattr_seen)
+        assert len(t.getattr_seen) == by_class["read"] + by_class["stat"]
+
+        c = t.clients[0]
+        staging, bucket = t.dirs
+        assert await c.readdir(staging.inode) == []
+        listed = {e.name for e in await c.readdir(bucket.inode)}
+        live = {f.name: f for f in t.model.live()}
+        assert listed == set(live) == {f.name for f in verb.pool(t).live}
+        assert len(t.unlinked) == by_class["delete"]
+        assert not listed & set(t.unlinked)
+        assert len(live) == 6 + by_class["write"] - by_class["delete"]
+        for name in sorted(live)[:3]:
+            f = live[name]
+            info = await c.chunk_info(f.inode, 0)
+            compare_stored(cluster, info.chunk_id, t.model.bytes_of(f), 0,
+                           k, m_par)
+        # every GET read one chunk's range, on one path or the other
+        d = {}
+        for cl, b in zip(t.clients, before):
+            for key, val in phase_delta(cl.read_phases.snapshot(), b).items():
+                d[key] = d.get(key, 0) + val
+        assert d["reps"] == by_class["read"]
+        assert d["gather_chunks"] + d["planned_chunks"] == by_class["read"]
+        assert d["read_bytes"] == by_class["read"] * cell.config["object_bytes"]
+        blocks = cell.config["object_bytes"] // MFSBLOCKSIZE
+        assert d["cache_bypass_blocks"] == by_class["read"] * blocks
+        assert d["cache_hit_blocks"] == d["cache_miss_blocks"] == 0
+        assert d["lookups"] == d["get_xattrs"] == len(t.getattr_seen)
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.asyncio
+async def test_a_delete_never_takes_an_object_in_use(tmp_path):
+    """While a GET holds an object, the pool hands a DELETE another
+    one; with every object held it hands it none, and the verb then
+    makes no call."""
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)
+    try:
+        t, cell, _goal = await mixed_traffic(cluster, "ec84", 35, 2)
+        verb = t.verbs[cell.mix["steps"][0]["verb"]]
+        pool = verb.pool(t)
+        for _ in range(2):
+            await verb.put(t, 0, t._state(0), True)
+        a, b = pool.live
+        rng = np.random.default_rng(1)
+        held = pool.draw(rng)
+        other = b if held is a else a
+        for _ in range(20):
+            taken = pool.take(rng)
+            assert taken is other
+            pool.add(taken)
+        pool.held[other.name] = 1       # a second operation holds the other
+        assert pool.take(rng) is None
+        t.recording = True
+        await verb.delete(t, 1, t._state(1), False)
+        assert not t.ops and len(pool.live) == 2
+        pool.release(other)
+        pool.release(held)
+        assert not pool.held
+        await verb.delete(t, 1, t._state(1), False)
+        op, = t.ops
+        assert (op.cls, op.ok, op.metadata) == ("delete", True, True)
+        gone, = t.unlinked
+        assert [f.name for f in pool.live] == \
+            [f.name for f in t.model.live()] != [gone]
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.asyncio
+async def test_the_read_path_counts_which_plan_served_a_chunk(tmp_path):
+    """``gather_chunks`` + ``planned_chunks`` is the chunk ranges read:
+    a whole-file read lands in its own buffer and takes the one native
+    gather, the gateway's sized read of an object inside one chunk has
+    no buffer to land in and takes a read plan, and with a data part's
+    holder stopped every range takes a plan."""
+    length = MFSCHUNKSIZE + 16 * MFSBLOCKSIZE
+    data = np.random.default_rng(36).integers(0, 256, length, dtype=np.uint8)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        f = await ec84_file(c, "counted.bin")
+        await c.write_file(f.inode, data)
+
+        async def counted_read(*args):
+            c.cache.invalidate(f.inode)
+            before = c.read_phases.snapshot()
+            got = await c.read_file(f.inode, *args)
+            d = phase_delta(c.read_phases.snapshot(), before)
+            assert d["read_bytes"] == len(got)
+            return np.frombuffer(got, np.uint8), d
+
+        got, d = await counted_read()
+        assert np.array_equal(got, data)
+        assert (d["gather_chunks"], d["planned_chunks"]) == (2, 0)
+        got, d = await counted_read(0, 10 * MiB)
+        assert np.array_equal(got, data[:10 * MiB])
+        assert (d["gather_chunks"], d["planned_chunks"]) == (0, 1)
+        assert d["cache_bypass_blocks"] == 160
+        # under 4 MiB a read asks the BlockCache: a miss, then a hit
+        before = c.read_phases.snapshot()
+        for _ in range(2):
+            assert await c.read_file(f.inode, 0, 3 * MFSBLOCKSIZE) == \
+                data[:3 * MFSBLOCKSIZE].tobytes()
+        d = phase_delta(c.read_phases.snapshot(), before)
+        assert (d["cache_miss_blocks"], d["cache_hit_blocks"]) == (3, 3)
+        assert d["gather_chunks"] + d["planned_chunks"] == 1
+
+        info = await c.chunk_info(f.inode, 0)
+        await stop_holder_of(cluster, info.chunk_id, 1, K, M)
+        got, d = await counted_read()
+        assert np.array_equal(got, data)
+        assert d["gather_chunks"] + d["planned_chunks"] == 2
+        assert d["planned_chunks"] >= 1
+        assert c.op_counters["planned_chunks"] >= 3
     finally:
         await cluster.stop()
