@@ -2741,9 +2741,16 @@ class Client:
         ``file_length=None``: length unknown — learn it from the locate
         reply (MatoclReadChunk.file_length, like the reference's
         fs_readchunk) and clamp there, saving sized reads the separate
-        getattr round trip. Only valid with ``into=None``."""
+        getattr round trip. A caller that passes no length passes no
+        ``into`` either: it cannot size one yet.
+
+        Without ``into``, a bulk read whose wire range is the range
+        asked lands in a buffer made here once the length is known, and
+        that buffer is returned: it meets ``_read_slice``'s conditions
+        (the one native gather, in-place standard reads) exactly as
+        ``_read_into``'s reads do."""
         if file_length is None:
-            assert into is None, "length-from-locate needs the copy path"
+            assert into is None, "no length to size the caller's buffer by"
             chunk_len = MFSCHUNKSIZE  # provisional; clamped post-locate
         else:
             chunk_len = min(
@@ -2804,6 +2811,7 @@ class Client:
             await self._throttle(read_size, phase="wait")
         last_error: Exception | None = None
         bad_addrs: set[tuple[str, int]] = set()  # replicas that failed us
+        own: np.ndarray | None = None  # made once a call; retries reuse it
         for attempt in range(self.retries):
             if attempt:
                 with tracing.span("backoff", phase="wait", bucket="queue"):
@@ -2893,17 +2901,22 @@ class Client:
                     into[into_offset : into_offset + size] = 0
                     return None
                 return np.zeros(size, dtype=np.uint8)  # hole
-            # direct scatter into the caller's buffer is possible only
-            # when the network range IS the requested range
-            direct = (
-                into is not None and aligned_off == off and read_size == size
-            )
+            # direct scatter into a buffer is possible only when the
+            # network range IS the requested range
+            direct = aligned_off == off and read_size == size
+            dest, dest_offset = into, into_offset
+            if dest is None and bulk and direct:
+                if own is None or own.size != size:
+                    # the first attempt, or a retry whose fresher
+                    # locate moved the clamp
+                    own = np.empty(size, dtype=np.uint8)
+                dest, dest_offset = own, 0
             try:
                 data = await self._read_located(
                     loc, chunk_index, aligned_off, read_size, file_length,
                     attempt=attempt, avoid=bad_addrs,
-                    into=into if direct else None,
-                    into_offset=into_offset,
+                    into=dest if direct else None,
+                    into_offset=dest_offset,
                 )
             except (ReadError, ConnectionError, OSError) as e:
                 last_error = e
@@ -2937,7 +2950,8 @@ class Client:
                     )
                 )
             if data is None:
-                return None  # landed in `into` already
+                # landed in `into` already, or in the buffer made here
+                return None if into is not None else own
             rel = off - aligned_off
             return data[rel : rel + size]
         raise st.StatusError(st.EIO, f"read failed after retries: {last_error}")

@@ -13,6 +13,7 @@ mirror the mount's read-task cancellation (src/mount/readdata.cc).
 """
 
 import asyncio
+import contextlib
 import socket as socket_mod
 import struct
 import time as _time
@@ -112,7 +113,25 @@ async def _write_aligned_ec_file(cluster, c, nbytes):
     return f, payload
 
 
-async def test_stripe_gather_fast_path_engages(tmp_path):
+async def _read_into(c, inode, nbytes) -> bytes:
+    back = np.zeros(nbytes, dtype=np.uint8)
+    assert await c.read_file_into(inode, 0, back) == nbytes
+    return back.tobytes()
+
+
+async def _read_sized(c, inode, nbytes) -> bytes:
+    return await c.read_file(inode, 0, nbytes)
+
+
+# the two doors to the gather: a caller's buffer (read_file_into, a
+# whole-file read_file) and the buffer _read_chunk_range makes for a
+# sized bulk read inside one chunk (the S3 gateway's GET)
+ENTRIES = pytest.mark.parametrize(
+    "read", [_read_into, _read_sized], ids=["into", "sized"])
+
+
+@ENTRIES
+async def test_stripe_gather_fast_path_engages(tmp_path, read):
     """A slot-aligned bulk EC read must take the one-call native gather
     (counter proves it) and return the right bytes."""
     if not native_io.parts_gather_available():
@@ -123,9 +142,7 @@ async def test_stripe_gather_fast_path_engages(tmp_path):
         c = await cluster.client()
         # 6 MiB: 96 blocks, d=3 -> 32 whole slots, bulk (>= 4 MiB)
         f, payload = await _write_aligned_ec_file(cluster, c, 6 * 2**20)
-        back = np.zeros(len(payload), dtype=np.uint8)
-        n = await c.read_file_into(f.inode, 0, back)
-        assert n == len(payload) and back.tobytes() == payload
+        assert await read(c, f.inode, len(payload)) == payload
         assert c.op_counters.get("stripe_gather_fast", 0) >= 1, \
             "fast-path precondition silently missed"
         assert not c.op_counters.get("stripe_gather_fallback")
@@ -133,7 +150,9 @@ async def test_stripe_gather_fast_path_engages(tmp_path):
         await cluster.stop()
 
 
-async def test_stripe_gather_failure_falls_back_to_waves(tmp_path, monkeypatch):
+@ENTRIES
+async def test_stripe_gather_failure_falls_back_to_waves(
+        tmp_path, monkeypatch, read):
     """A native gather failure must degrade to the wave executor and
     still return correct bytes (counter proves the degrade happened)."""
     if not native_io.parts_gather_available():
@@ -148,15 +167,14 @@ async def test_stripe_gather_failure_falls_back_to_waves(tmp_path, monkeypatch):
             raise native_io.NativeIOError(5, "injected gather failure")
 
         monkeypatch.setattr(native_io, "read_parts_gather_blocking", boom)
-        back = np.zeros(len(payload), dtype=np.uint8)
-        n = await c.read_file_into(f.inode, 0, back)
-        assert n == len(payload) and back.tobytes() == payload
+        assert await read(c, f.inode, len(payload)) == payload
         assert c.op_counters.get("stripe_gather_fallback", 0) >= 1
     finally:
         await cluster.stop()
 
 
-async def test_stripe_gather_cs_death_still_reads(tmp_path):
+@ENTRIES
+async def test_stripe_gather_cs_death_still_reads(tmp_path, read):
     """With a data-part holder dead, the fast-path precondition fails
     (part missing) and the wave executor recovers the bytes."""
     if not native_io.parts_gather_available():
@@ -174,25 +192,19 @@ async def test_stripe_gather_cs_death_still_reads(tmp_path):
         )
         await victim.stop()
         await asyncio.sleep(0.1)
-        back = np.zeros(len(payload), dtype=np.uint8)
-        n = await c.read_file_into(f.inode, 0, back)
-        assert n == len(payload) and back.tobytes() == payload
+        assert await read(c, f.inode, len(payload)) == payload
     finally:
         await cluster.stop()
 
 
 # --- (c) abort path: no buffer writes after the caller resumes --------------
 
-async def test_abort_parts_gather_quiesces_buffer(tmp_path):
-    """abort_parts_gather must unblock the executor thread promptly,
-    and once the caller observes completion NOTHING may touch the
-    destination buffer again (the caller immediately reuses it)."""
-    if not native_io.parts_gather_available():
-        pytest.skip("native parts gather not built")
-
-    # a server that accepts, reads the request, and stalls until teardown
-    # (3.12's Server.wait_closed waits for handlers — an unconditional
-    # sleep here would hang the test's own cleanup)
+@contextlib.asynccontextmanager
+async def _stalled_server():
+    """A server that accepts, reads the request, and stalls until
+    teardown (3.12's Server.wait_closed waits for handlers — an
+    unconditional sleep here would hang the test's own cleanup).
+    Yields its port and the event set once a request has been read."""
     stalled = asyncio.Event()
     teardown = asyncio.Event()
 
@@ -207,8 +219,21 @@ async def test_abort_parts_gather_quiesces_buffer(tmp_path):
             writer.close()
 
     server = await asyncio.start_server(stall_handler, "127.0.0.1", 0)
-    port = server.sockets[0].getsockname()[1]
     try:
+        yield server.sockets[0].getsockname()[1], stalled
+    finally:
+        teardown.set()
+        server.close()
+        await server.wait_closed()
+
+
+async def test_abort_parts_gather_quiesces_buffer(tmp_path):
+    """abort_parts_gather must unblock the executor thread promptly,
+    and once the caller observes completion NOTHING may touch the
+    destination buffer again (the caller immediately reuses it)."""
+    if not native_io.parts_gather_available():
+        pytest.skip("native parts gather not built")
+    async with _stalled_server() as (port, stalled):
         region_blocks = 6
         out = np.zeros(region_blocks * B, dtype=np.uint8)
         cell: dict = {}
@@ -234,10 +259,60 @@ async def test_abort_parts_gather_quiesces_buffer(tmp_path):
         out[:] = sentinel
         await asyncio.sleep(0.3)
         np.testing.assert_array_equal(out, sentinel)
-    finally:
-        teardown.set()
-        server.close()
-        await server.wait_closed()
+
+
+async def test_cancelled_sized_read_joins_its_gather(tmp_path, monkeypatch):
+    """A sized bulk read lands in a buffer ``_read_chunk_range`` made
+    for it: cancelled inside the gather, the native thread has left the
+    call before the caller sees the cancel, nothing writes to that
+    buffer afterwards, and the client reads on."""
+    if not native_io.parts_gather_available():
+        pytest.skip("native parts gather not built")
+    async with _stalled_server() as (port, stalled):
+        cluster = Cluster(tmp_path)
+        await cluster.start()
+        try:
+            c = await cluster.client()
+            f, payload = await _write_aligned_ec_file(cluster, c, 6 * 2**20)
+            gather = native_io.read_parts_gather_blocking
+            seen = {"entered": 0, "left": 0}
+
+            def gather_from_a_stalled_holder(addrs, *args):
+                # runs on the native-io worker thread
+                seen["entered"] += 1
+                seen["out"] = args[-2]
+                try:
+                    gather([("127.0.0.1", port)] * len(addrs), *args)
+                finally:
+                    seen["left"] += 1
+
+            monkeypatch.setattr(native_io, "read_parts_gather_blocking",
+                                gather_from_a_stalled_holder)
+            read = asyncio.ensure_future(c.read_file(f.inode, 0, len(payload)))
+            await asyncio.wait_for(stalled.wait(), 10.0)
+            t0 = _time.monotonic()
+            read.cancel()
+            with pytest.raises(asyncio.CancelledError):
+                await asyncio.wait_for(read, 10.0)
+            assert (seen["entered"], seen["left"]) == (1, 1), \
+                "the caller saw the cancel while the native thread still ran"
+            assert _time.monotonic() - t0 < 5.0, \
+                "abort did not unblock the thread"
+            out = seen["out"]
+            assert out.nbytes == len(payload)
+            sentinel = np.frombuffer(
+                data_generator.generate(98, out.nbytes).tobytes(), np.uint8)
+            out[:] = sentinel
+            await asyncio.sleep(0.3)
+            np.testing.assert_array_equal(out, sentinel)
+            assert not c.op_counters.get("stripe_gather_fallback")
+
+            monkeypatch.setattr(
+                native_io, "read_parts_gather_blocking", gather)
+            assert await c.read_file(f.inode, 0, len(payload)) == payload
+            assert c.op_counters.get("stripe_gather_fast", 0) == 1
+        finally:
+            await cluster.stop()
 
 
 async def test_abort_before_dial_refuses_cleanly():
@@ -374,6 +449,21 @@ async def test_send_parts_stands_aside_for_a_chained_part(
         await cluster.stop()
 
 
+def _drop_idle_sockets() -> None:
+    """Empty the process-wide socket pools. Earlier tests leave idle
+    sockets to chunkservers that are gone; a later cluster that is
+    handed one of their ports would take such a socket for its own, so
+    a test that counts dials or redials starts from empty pools."""
+    idle = []
+    for pool in (native_io.POOL, native_io.RING_POOL):
+        with pool._lock:
+            idle += [s for bucket in pool._idle.values() for s in bucket]
+            pool._idle.clear()
+    for s in idle:
+        native_io.shm_ring_drop(s)
+        s.close()
+
+
 async def test_pwrite_reuses_pooled_sockets_through_a_restart(tmp_path):
     """Two pwrites to one chunk dial each chunkserver once; a data
     plane restarted between two more costs one redial of the exchange
@@ -382,6 +472,7 @@ async def test_pwrite_reuses_pooled_sockets_through_a_restart(tmp_path):
         pytest.skip("native parts scatter not built")
     from lizardfs_tpu.chunkserver import native_serve
 
+    _drop_idle_sockets()
     cluster = Cluster(tmp_path)
     await cluster.start(health_interval=30.0)
     try:
@@ -817,16 +908,7 @@ async def test_shm_ring_segments_released_on_session_teardown(tmp_path):
         # pooled connections keep their segment mapped (that's the
         # point: no per-chunk renegotiation) — drop the pools and the
         # mappings must go with them (ring conns pool in RING_POOL)
-        idle = []
-        for pool in (native_io.POOL, native_io.RING_POOL):
-            with pool._lock:
-                idle += [
-                    s for bucket in pool._idle.values() for s in bucket
-                ]
-                pool._idle.clear()
-        for s in idle:
-            native_io.shm_ring_drop(s)
-            s.close()
+        _drop_idle_sockets()
         deadline = asyncio.get_event_loop().time() + 10.0
         while asyncio.get_event_loop().time() < deadline:
             active = sum(
@@ -1360,6 +1442,7 @@ async def test_exchange_redials_once_after_a_peer_restart(tmp_path):
     _skip_without_exchange()
     from lizardfs_tpu.chunkserver import native_serve
 
+    _drop_idle_sockets()
     cluster = Cluster(tmp_path)
     await cluster.start(health_interval=30.0)
     try:

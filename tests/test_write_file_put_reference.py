@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 from lizardfs_tpu.client.write_window import MAX_DEPTH
-from lizardfs_tpu.constants import MFSBLOCKSIZE, MFSCHUNKSIZE
+from lizardfs_tpu.constants import EATTR_NOCACHE, MFSBLOCKSIZE, MFSCHUNKSIZE
 from lizardfs_tpu.core import native_io
 from lizardfs_tpu.runtime.metrics import WRITE_COUNTS, phase_delta
 
@@ -482,9 +482,10 @@ async def test_a_delete_never_takes_an_object_in_use(tmp_path):
 async def test_the_read_path_counts_which_plan_served_a_chunk(tmp_path):
     """``gather_chunks`` + ``planned_chunks`` is the chunk ranges read:
     a whole-file read lands in its own buffer and takes the one native
-    gather, the gateway's sized read of an object inside one chunk has
-    no buffer to land in and takes a read plan, and with a data part's
-    holder stopped every range takes a plan."""
+    gather, and so does the gateway's sized read of an object inside
+    one chunk, which gets a buffer of its own once the locate has
+    taught its length; with a data part's holder stopped every range
+    takes a plan."""
     length = MFSCHUNKSIZE + 16 * MFSBLOCKSIZE
     data = np.random.default_rng(36).integers(0, 256, length, dtype=np.uint8)
     cluster = Cluster(tmp_path, n_cs=13)
@@ -507,7 +508,7 @@ async def test_the_read_path_counts_which_plan_served_a_chunk(tmp_path):
         assert (d["gather_chunks"], d["planned_chunks"]) == (2, 0)
         got, d = await counted_read(0, 10 * MiB)
         assert np.array_equal(got, data[:10 * MiB])
-        assert (d["gather_chunks"], d["planned_chunks"]) == (0, 1)
+        assert (d["gather_chunks"], d["planned_chunks"]) == (1, 0)
         assert d["cache_bypass_blocks"] == 160
         # under 4 MiB a read asks the BlockCache: a miss, then a hit
         before = c.read_phases.snapshot()
@@ -525,5 +526,124 @@ async def test_the_read_path_counts_which_plan_served_a_chunk(tmp_path):
         assert d["gather_chunks"] + d["planned_chunks"] == 2
         assert d["planned_chunks"] >= 1
         assert c.op_counters["planned_chunks"] >= 3
+    finally:
+        await cluster.stop()
+
+
+def boom(*args, **kwargs):
+    raise native_io.NativeIOError(5, "injected gather failure")
+
+
+# A sized read of 4 MiB or more inside one chunk (the S3 gateway's GET
+# and ranged GET, the tape server's archive read) passes the BlockCache
+# by and lands in a buffer `_read_chunk_range` makes for it, so the same
+# conditions decide its path as decide a whole-file read's. Each case:
+# the goal, the object's length, the (offset, size) read, the
+# (gather_chunks, planned_chunks) it has to count, and what is done to
+# the cluster first.
+SIZED_READS = {
+    # a ranged GET from a slot boundary over whole blocks: the gather
+    "slot_aligned_range": (WIDE_EC_GOAL, 24 * MiB, (8 * MiB, 8 * MiB),
+                           (1, 0), None),
+    # an object that is no multiple of 64 KiB: the plan, de-interleaved
+    # straight into the buffer
+    "not_block_multiple": (WIDE_EC_GOAL, 10 * MiB + 5, (0, 10 * MiB + 5),
+                           (0, 1), None),
+    # block-aligned but three blocks into a slot of eight: the plan
+    "off_a_slot_boundary": (WIDE_EC_GOAL, 24 * MiB,
+                            (3 * MFSBLOCKSIZE, 8 * MiB), (0, 1), None),
+    # off a block boundary the wire range is wider than the range
+    # asked: no buffer is made, the plan's region is sliced
+    "off_a_block_boundary": (WIDE_EC_GOAL, 24 * MiB, (5, 8 * MiB),
+                             (0, 1), None),
+    "clamped_at_eof": (WIDE_EC_GOAL, 10 * MiB, (2 * MiB, 16 * MiB),
+                       (1, 0), None),
+    "past_eof": (WIDE_EC_GOAL, 10 * MiB, (12 * MiB, 8 * MiB), (0, 0), None),
+    "hole": (WIDE_EC_GOAL, 10 * MiB, (0, 10 * MiB), (0, 0), "hole"),
+    # a copy goal's one part lands in place
+    "standard_goal": (STD2_GOAL, 10 * MiB, (0, 10 * MiB), (0, 1), None),
+    "gather_fails": (WIDE_EC_GOAL, 10 * MiB, (0, 10 * MiB), (0, 1),
+                     "gather_fails"),
+    "holder_stopped": (WIDE_EC_GOAL, 10 * MiB, (0, 10 * MiB), (0, 1),
+                       "holder_stopped"),
+    # a NOCACHE inode's reads are bulk at any size: one slot of eight
+    # blocks takes the gather too
+    "nocache_one_slot": (WIDE_EC_GOAL, 10 * MiB,
+                         (16 * MFSBLOCKSIZE, 8 * MFSBLOCKSIZE), (1, 0),
+                         "nocache"),
+}
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("case", sorted(SIZED_READS))
+async def test_a_sized_bulk_read_returns_what_was_written(
+        tmp_path, monkeypatch, case):
+    goal, length, (off, size), want, fault = SIZED_READS[case]
+    data = np.random.default_rng([35, length]).integers(
+        0, 256, length, dtype=np.uint8)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)  # no rebuild under the test
+    try:
+        c = await cluster.client()
+        f = await c.create(1, f"{case}.bin")
+        await c.setgoal(f.inode, goal)
+        if fault == "hole":
+            data[:] = 0
+            await c.truncate(f.inode, length)
+        else:
+            await c.write_file(f.inode, data)
+        if fault == "gather_fails":
+            monkeypatch.setattr(native_io, "read_parts_gather_blocking", boom)
+        elif fault == "holder_stopped":
+            info = await c.chunk_info(f.inode, 0)
+            await stop_holder_of(cluster, info.chunk_id, 1, K, M)
+        elif fault == "nocache":
+            await c.seteattr(f.inode, EATTR_NOCACHE)
+        recovers = []
+        recover = c.encoder.recover
+        monkeypatch.setattr(
+            c.encoder, "recover",
+            lambda *a, **kw: recovers.append(a[:2]) or recover(*a, **kw))
+        c.cache.invalidate(f.inode)
+        before = c.read_phases.snapshot()
+        fallbacks = c.op_counters.get("stripe_gather_fallback", 0)
+        got = await c.read_file(f.inode, off, size)
+        d = phase_delta(c.read_phases.snapshot(), before)
+        assert got == data[off:off + size].tobytes()
+        assert (d["gather_chunks"], d["planned_chunks"]) == want
+        assert d["read_bytes"] == len(got)
+        assert d["cache_bypass_blocks"] == \
+            (off + size - 1) // MFSBLOCKSIZE - off // MFSBLOCKSIZE + 1
+        assert d["cache_miss_blocks"] == d["cache_hit_blocks"] == 0
+        assert c.op_counters.get("stripe_gather_fallback", 0) - fallbacks \
+            == (fault == "gather_fails")
+        # a lost data part is recovered across the encoder boundary
+        assert recovers == ([(K, M)] if fault == "holder_stopped" else [])
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.asyncio
+async def test_a_read_under_the_bypass_size_keeps_the_cache(tmp_path):
+    """2 MiB is under ``CACHE_BYPASS_BYTES``: no buffer is made for it,
+    it asks the BlockCache (a miss, then a hit) and the miss takes a
+    read plan, as before sized bulk reads took the gather."""
+    data = np.random.default_rng(352).integers(
+        0, 256, 10 * MiB, dtype=np.uint8)
+    cluster = Cluster(tmp_path, n_cs=13)
+    await cluster.start(health_interval=30.0)
+    try:
+        c = await cluster.client()
+        f = await ec84_file(c, "small_reads.bin")
+        await c.write_file(f.inode, data)
+        c.cache.invalidate(f.inode)
+        before = c.read_phases.snapshot()
+        for _ in range(2):
+            assert await c.read_file(f.inode, 0, 2 * MiB) == \
+                data[:2 * MiB].tobytes()
+        d = phase_delta(c.read_phases.snapshot(), before)
+        assert (d["cache_miss_blocks"], d["cache_hit_blocks"]) == (32, 32)
+        assert (d["gather_chunks"], d["planned_chunks"]) == (0, 1)
+        assert d["cache_bypass_blocks"] == 0
     finally:
         await cluster.stop()
