@@ -1,0 +1,5 @@
+from _loop import delay_ms
+
+
+def read(ctx):
+    return delay_ms(ctx)

@@ -1,0 +1,5 @@
+from _loop import named_pct
+
+
+def read(ctx):
+    return named_pct(ctx)

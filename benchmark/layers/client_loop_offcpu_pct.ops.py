@@ -1,0 +1,5 @@
+from _loop import offcpu_pct
+
+
+def read(ctx):
+    return offcpu_pct(ctx)

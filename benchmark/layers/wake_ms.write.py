@@ -1,0 +1,5 @@
+from _loop import wake_ms
+
+
+def read(ctx):
+    return wake_ms(ctx)

@@ -253,6 +253,12 @@ class Client:
         # (runtime.metrics.READ_COUNTS; _count_read).
         self.read_phases = PhaseBreakdown(
             "client_read", READ_PHASES, READ_COUNTS)
+        # the meter of the loop this client connected on
+        # (tracing.LoopMeter): the first client of a loop shows the
+        # loop's counts beside its read rows (loop_turns, loop_busy_us,
+        # loop_turn_sq_us2, loop_offcpu_us), the others wait in line, so
+        # that a sum over a loop's sessions counts the loop once
+        self._loop_meter = None
         # request-scoped span ring (runtime/tracing.py): every
         # tracing.span of an op lands here with its parent; merge with
         # daemon `trace-dump` output via tracing.merge_timeline
@@ -496,6 +502,10 @@ class Client:
         """Registration handshake body. Caller MUST hold _conn_lock."""
         self._info = info
         self._password = password
+        if self._loop_meter is None:
+            self._loop_meter = tracing.attach_meter()
+            if self._loop_meter is not None:
+                self._loop_meter.ride(self.read_phases)
         # spawn the native-IO pool threads while the process is quiet:
         # lazy spawn inside submit() blocks the event loop under GIL
         # pressure (measured 150-600 ms during EC write fan-out)
@@ -620,9 +630,13 @@ class Client:
         # locate / commit span it is what the wire and the two loops
         # cost beside the master's own stamped handler time
         with tracing.span(msg_cls.__name__, layer="rpc", bucket="net"):
-            return await self._busy_retry(
+            reply = await self._busy_retry(
                 lambda: self._call_once(msg_cls, **fields), msg_cls.__name__
             )
+            # the last leg of the way back: from where the pump handed
+            # the reply over to this coroutine running again
+            tracing.wake("rpc", getattr(reply, "woke", None))
+            return reply
 
     async def _call_once(self, msg_cls, **fields):
         if msg_cls.FIELDS and msg_cls.FIELDS[-1][0] == "trace_id":
@@ -754,6 +768,7 @@ class Client:
             with tracing.span(msg_cls.__name__, layer="rpc", bucket="net",
                               replica=True):
                 r = await conn.call(msg_cls, timeout=10.0, **fields)
+                tracing.wake("rpc", getattr(r, "woke", None))
         except (OSError, ConnectionError, asyncio.TimeoutError):
             await self._drop_replica()
             self.metrics.counter("shadow_fallbacks").inc()
@@ -904,6 +919,11 @@ class Client:
         if self._limits_probe_task is not None:
             self._limits_probe_task.cancel()
             self._limits_probe_task = None
+        if self._loop_meter is not None:
+            # what it showed of the loop's counts stays in its rows;
+            # the next client of the loop shows the rest
+            self._loop_meter.leave(self.read_phases)
+            self._loop_meter = None
         await self._drop_replica()
         if self.master is not None:
             if self.read_phases.reps or self.write_phases.reps:
@@ -1760,8 +1780,9 @@ class Client:
         into it."""
         self._count_write("rmw_region_bytes", len(region))
         with tracing.span("encode", phase="encode", bucket="compute"):
-            parts = await asyncio.to_thread(
-                striping.split_chunk, region, slice_type, self.encoder
+            parts = await tracing.hop(
+                striping.split_chunk, region, slice_type, self.encoder,
+                phase="hop_compute",
             )
         part_offset = lo_s * MFSBLOCKSIZE
         region_end = lo_s * slice_type.data_parts * MFSBLOCKSIZE + len(region)
@@ -2002,8 +2023,9 @@ class Client:
         part_len = -(-nblocks // d) * MFSBLOCKSIZE
         stage = self._stage_acquire(d, part_len)
         with tracing.span("stage", phase="stage", bucket="compute"):
-            stacked, _ = await asyncio.to_thread(
-                striping.padded_data_parts, chunk_data, d, stage
+            stacked, _ = await tracing.hop(
+                striping.padded_data_parts, chunk_data, d, stage,
+                phase="hop_compute",
             )
         first = 1 if slice_type.is_xor else 0
         full_chunk = len(chunk_data) == MFSCHUNKSIZE
@@ -2011,13 +2033,15 @@ class Client:
         async def parity_parts() -> dict[int, np.ndarray]:
             with tracing.span("encode", phase="encode", bucket="compute"):
                 if slice_type.is_xor:
-                    par = await asyncio.to_thread(
-                        self.encoder.xor_parity, stacked
+                    par = await tracing.hop(
+                        self.encoder.xor_parity, stacked,
+                        phase="hop_compute",
                     )
                     return {0: par}
-                par = await asyncio.to_thread(
+                par = await tracing.hop(
                     self.encoder.encode, d, slice_type.parity_parts,
                     list(stacked),
+                    phase="hop_compute",
                 )
                 return {d + j: p for j, p in enumerate(par)}
 
@@ -2277,7 +2301,8 @@ class Client:
                 try:
                     with tracing.span("encode", phase="encode",
                                       bucket="compute", seg=wid):
-                        await asyncio.to_thread(encode_segment, a, b, views)
+                        await tracing.hop(encode_segment, a, b, views,
+                                          phase="hop_compute")
                 except BaseException:
                     session.ring_unstage(wid)
                     raise
@@ -3227,24 +3252,25 @@ class Client:
                 "net", layer="wire", phase="net", bucket="net",
                 plane="gather",
             ).begin()
-            fut = asyncio.get_running_loop().run_in_executor(
-                native_io.EXECUTOR,
-                # partial_with_trace: run_in_executor drops context, so
-                # the open span and the sink ride the partial instead
-                native_io.partial_with_trace(
-                    native_io.read_parts_gather_blocking,
-                    [by_part[p][0] for p in wanted],
-                    loc.chunk_id, loc.version,
-                    [by_part[p][1] for p in wanted],
-                    lo_slot * MFSBLOCKSIZE, region_blocks,
-                    into[into_offset : into_offset + size],
-                    cell,
-                ),
+            # a bare future (the cancel path below joins it):
+            # run_in_executor drops context, so the open span and the
+            # sink ride the trip instead, and its way back is laid here
+            trip = native_io.partial_with_trace(
+                native_io.read_parts_gather_blocking,
+                [by_part[p][0] for p in wanted],
+                loc.chunk_id, loc.version,
+                [by_part[p][1] for p in wanted],
+                lo_slot * MFSBLOCKSIZE, region_blocks,
+                into[into_offset : into_offset + size],
+                cell,
             )
+            fut = asyncio.get_running_loop().run_in_executor(
+                native_io.EXECUTOR, trip)
             try:
                 try:
                     await asyncio.shield(fut)
                 finally:
+                    trip.wake()
                     net.end()
                     waves.end()
                 for p in wanted:
@@ -3303,14 +3329,16 @@ class Client:
         ):
             # zero-copy: de-interleave straight into the caller's buffer
             with tracing.span("gather", phase="gather", bucket="compute"):
-                await asyncio.to_thread(
+                await tracing.hop(
                     striping.assemble_chunk, data_parts, slice_type, size,
                     into[into_offset : into_offset + size],
+                    phase="hop_compute",
                 )
             return None
         with tracing.span("gather", phase="gather", bucket="compute"):
-            region = await asyncio.to_thread(
+            region = await tracing.hop(
                 striping.assemble_chunk, data_parts, slice_type,
                 d * bps,  # bytes covered by these stripes
+                phase="hop_compute",
             )
         return np.asarray(region[rel : rel + size])

@@ -29,6 +29,7 @@ from lizardfs_tpu.client.client import Client
 from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.proto import messages as m
 from lizardfs_tpu.proto import status as st
+from lizardfs_tpu.runtime import tracing
 
 c_off_t = ctypes.c_int64
 c_mode_t = ctypes.c_uint32
@@ -321,6 +322,11 @@ class LizardFuse:
                 f"{op}: {count}"
                 for op, count in sorted(self.client.op_counters.items())
             ]
+            # the turns of the loop this client runs on, where this
+            # client is the one of its loop that shows them
+            rows = self.client.read_phases.snapshot()
+            lines += [f"{k}: {rows[k]}" for k in tracing.LOOP_COUNTS
+                      if k in rows]
             lines.append(f"cache_hits: {self.client.cache.hits}")
             lines.append(f"cache_misses: {self.client.cache.misses}")
             return ("\n".join(lines) + "\n").encode()

@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import asyncio
 import ctypes
-import functools
 import os
 import socket
 import struct
@@ -28,7 +27,7 @@ from lizardfs_tpu.core import native as _native_lib
 from lizardfs_tpu.proto import framing
 from lizardfs_tpu.proto import messages as m
 from lizardfs_tpu.proto import status as st
-from lizardfs_tpu.runtime import accounting
+from lizardfs_tpu.runtime import accounting, tracing
 
 # exchanges smaller than this stay on the asyncio path
 NATIVE_READ_THRESHOLD = 128 * 1024
@@ -580,35 +579,29 @@ def serve_slot_release() -> None:
 
 
 async def run(fn, *args):
-    """Run a blocking native-IO function on the dedicated executor.
-
-    The caller's open span and op sink (runtime/tracing.py
-    contextvars) are captured HERE — run_in_executor does not carry
-    context into the worker thread — and the trace id installed as the
+    """Run a blocking native-IO function on the dedicated executor and
+    come back (``tracing.hop``): the caller's open span and op sink
+    ride into the worker thread, the wait for the thread is a ``hop``
+    span and the way back a ``wake``; the trace id is installed as the
     C side's thread-local (lz_trace_set) for the duration of the call,
     so the native request builders tag their frames with the trace of
     the request they serve."""
-    loop = asyncio.get_running_loop()
-    return await loop.run_in_executor(EXECUTOR, partial_with_trace(fn, *args))
+    return await tracing.hop(
+        _session_call, accounting.wire_session(), fn, *args,
+        executor=EXECUTOR)
 
 
-def partial_with_trace(fn, *args):
-    """``functools.partial`` carrying the caller's open span, op sink
-    AND wire session into the executor thread — for call sites that
-    need raw run_in_executor (shield/abort-cell patterns) instead of
-    :func:`run`. All are captured HERE, in the calling task, because
-    neither contextvars nor the task's session scope reach an executor
-    thread; the spans the worker opens (and the ``hop`` span of its
-    wait for the thread) then hang under the caller's and charge the
-    caller's op, traced or not."""
-    from lizardfs_tpu.runtime import tracing
-
-    carried = tracing.carry()
-    if carried is not None:
-        return functools.partial(
-            _traced_call, carried, accounting.wire_session(), fn, *args
-        )
-    return functools.partial(fn, *args)
+def partial_with_trace(fn, *args) -> tracing.Hop:
+    """The call as a ``tracing.Hop``, carrying the caller's open span,
+    op sink AND wire session into the executor thread — for call sites
+    that need raw run_in_executor (shield/abort-cell patterns) instead
+    of :func:`run`; they lay the way back themselves (``.wake()``)
+    where they await. All are captured HERE, in the calling task,
+    because neither contextvars nor the task's session scope reach an
+    executor thread; the spans the worker opens (and the ``hop`` span
+    of its wait for the thread) then hang under the caller's and
+    charge the caller's op, traced or not."""
+    return tracing.Hop(_session_call, accounting.wire_session(), fn, *args)
 
 
 # worker-thread trace id: read by the python-framed handshakes
@@ -620,14 +613,11 @@ def _thread_trace_id() -> int:
     return getattr(_TRACE_TL, "trace_id", 0)
 
 
-def _traced_call(carried, session_id, fn, *args):
-    from lizardfs_tpu.runtime import tracing
-
-    with tracing.carried(carried):
-        trace_id = tracing.current_trace_id()
-        if not trace_id:
-            return fn(*args)  # an untraced op: only its phases charge
-        return _call_under_trace(trace_id, session_id, fn, *args)
+def _session_call(session_id, fn, *args):
+    trace_id = tracing.current_trace_id()
+    if not trace_id:
+        return fn(*args)  # an untraced op: only its phases charge
+    return _call_under_trace(trace_id, session_id, fn, *args)
 
 
 def _call_under_trace(trace_id, session_id, fn, *args):
@@ -655,10 +645,7 @@ def _call_under_trace(trace_id, session_id, fn, *args):
 
 async def run_serve(fn, *args):
     """Run a blocking server-side serve function on its own executor."""
-    loop = asyncio.get_running_loop()
-    return await loop.run_in_executor(
-        SERVE_EXECUTOR, functools.partial(fn, *args)
-    )
+    return await tracing.hop(fn, *args, executor=SERVE_EXECUTOR)
 
 
 def _blocking_socket(addr: tuple[str, int], io_timeout: float) -> socket.socket:
@@ -806,8 +793,6 @@ def read_part_blocking(
 def _leg(name: str, bucket: str = "net"):
     """One leg of a part write as a span under the caller's ``part``:
     the names are the same on every plane."""
-    from lizardfs_tpu.runtime import tracing
-
     return tracing.span(name, layer="wire", phase=name, bucket=bucket)
 
 
@@ -1112,8 +1097,6 @@ def read_parts_wave_blocking(wave: PartsWave) -> None:
     saw the part end (``done_us``): the wait for this thread is the
     ``hop`` beside them, not inside. Raises nothing for a part's
     failure: the caller reads ``wave.outcome``."""
-    from lizardfs_tpu.runtime import tracing
-
     reqs, cell = wave.reqs, wave.cell
     try:
         if cell.get("aborted"):
@@ -1131,6 +1114,10 @@ def read_parts_wave_blocking(wave: PartsWave) -> None:
                 plane="wave",
             ).begin(at=t_call).end(
                 at=min(t_call + wave.done_us[i] / 1e6, now))
+        # the call ends with its last part: from there to `now` this
+        # thread waited to get the GIL back (the way back's wake_gil)
+        tracing.native_end(
+            t_call + max(wave.done_us[i] for i in wave.live) / 1e6, now)
     finally:
         cell.pop("socks", None)
         # an abort that found the sockets before this pop has shut them
@@ -1678,6 +1665,10 @@ def _lay_exchange_legs(t0: float, leg_us) -> None:
         t1 = min(t0 + us / 1e6, now)
         _leg(name).begin(at=t0).end(at=t1)
         t0 = t1
+    else:
+        # where the last leg ended C was done: from there to `now` this
+        # thread waited to get the GIL back (the way back's wake_gil)
+        tracing.native_end(t0, now)
 
 
 def _write_parts_scatter(

@@ -106,20 +106,21 @@ async def read_part_range(
             "net", layer="wire", phase="net", bucket="net", part=part_id,
             bytes=size, plane="native",
         ).begin()
-        fut = asyncio.get_running_loop().run_in_executor(
-            native_io.EXECUTOR,
-            # partial_with_trace: carries the open span and the sink
-            # into the worker thread (run_in_executor drops context)
-            native_io.partial_with_trace(
-                native_io.read_part_blocking,
-                addr, chunk_id, version, part_id, offset, size, tmp,
-                cell if scatter_direct else None, fresh,
-            ),
+        # a bare future (the cancel path below joins it): the trip
+        # carries the open span and the sink into the worker thread
+        # (run_in_executor drops context), its way back is laid here
+        trip = native_io.partial_with_trace(
+            native_io.read_part_blocking,
+            addr, chunk_id, version, part_id, offset, size, tmp,
+            cell if scatter_direct else None, fresh,
         )
+        fut = asyncio.get_running_loop().run_in_executor(
+            native_io.EXECUTOR, trip)
         try:
             try:
                 await asyncio.shield(fut)
             finally:
+                trip.wake()
                 net.end()
             GLOBAL_STATS.record_success(addr)
             if not scatter_direct:
@@ -346,6 +347,7 @@ async def execute_plan(
     unreadable: list[int] = []
     # a per-part task -> its part; a native call's future -> its wave
     pending: dict[asyncio.Future, int | _NativeWave] = {}
+    trips: dict[asyncio.Future, tracing.Hop] = {}  # a native call's trip
     native_waves: list[_NativeWave] = []  # all started, for the counts
     max_wave = max((op.wave for op in plan.read_operations), default=0)
     loop = asyncio.get_running_loop()
@@ -386,14 +388,14 @@ async def execute_plan(
             for wave, _ in native.calls:
                 if not wave.live:
                     continue  # the pool has no socket for any of them
-                fut = loop.run_in_executor(
-                    native_io.EXECUTOR,
-                    # partial_with_trace: the open `waves` span and the
-                    # sink ride into the worker, whose wait is one `hop`
-                    native_io.partial_with_trace(
-                        native_io.read_parts_wave_blocking, wave),
-                )
+                # the open `waves` span and the sink ride into the
+                # worker, whose wait is one `hop`; the way back is laid
+                # where the plan sees the call done
+                trip = native_io.partial_with_trace(
+                    native_io.read_parts_wave_blocking, wave)
+                fut = loop.run_in_executor(native_io.EXECUTOR, trip)
                 pending[fut] = native
+                trips[fut] = trip
             harvest(native)  # the parts the pool had no socket for
         for op in ops:
             start_part(op)
@@ -488,6 +490,7 @@ async def execute_plan(
             for task in done:
                 what = pending.pop(task)
                 if isinstance(what, _NativeWave):
+                    trips.pop(task).wake()
                     task.result()  # the worker raises for no part's sake
                 else:
                     settle(what, task.exception())

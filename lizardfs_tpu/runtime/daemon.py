@@ -87,14 +87,18 @@ class Daemon:
         # event-loop stall watchdog (loop_watchdog.h analog): a blocked
         # loop is THE latency failure mode of an asyncio daemon — the
         # reference aborts on a stuck poll loop; here a stall is logged
-        # with its duration and charted so operators see it. A sampler
-        # THREAD grabs the loop thread's stack while the stall is in
-        # progress (the loop itself can only notice after the fact), so
-        # the warning names a file:line instead of guessing.
+        # with its duration and charted so operators see it. The loop's
+        # meter (tracing.LoopMeter, attached in start()) times every
+        # turn of the loop: the watchdog reads the longest one from it
+        # and the share of the time the loop was away from its poll. A
+        # sampler THREAD grabs the loop thread's stack while the stall
+        # is in progress (the loop itself can only notice after the
+        # fact), so the warning names a file:line instead of guessing.
         self.watchdog_warn_s = 0.25
-        self._wd_last = 0.0
+        self._meter: tracing.LoopMeter | None = None
+        self.longest_us = 0  # raised by the meter, zeroed by the tick
         self._wd_max_lag = 0.0  # worst lag since the last metrics sample
-        self._wd_beat = 0.0  # written by the loop tick, read by sampler
+        self._wd_busy = (0.0, 0)  # (when, the meter's busy_us) last sample
         self._wd_loop_ident = 0
         self._wd_sampler_stop: object | None = None
         self._wd_sampler_thread: object | None = None
@@ -110,47 +114,67 @@ class Daemon:
         import time as _time
         import traceback as _tb
 
+        # the beat is the meter's: when the loop last came back from
+        # its poll (the 0.1 s tick keeps an idle loop's polls short)
+        meter = self._meter
         captured_for = -1.0
         while not self._wd_sampler_stop.wait(0.05):
-            beat = self._wd_beat
-            if not beat or beat == captured_for:
+            beat = meter.opened
+            if beat == captured_for:
                 continue
-            if _time.monotonic() - beat > self.watchdog_warn_s + 0.1:
+            if _time.perf_counter() - beat > self.watchdog_warn_s + 0.1:
                 frame = sys._current_frames().get(self._wd_loop_ident)
                 # validate AFTER capturing: a beat that moved means the
                 # stall ended mid-capture and the frame is an innocent
                 # post-stall callback — blaming it would send the
                 # operator to the wrong code (GIL-starved stalls end
                 # exactly when this thread gets to run again)
-                if frame is not None and self._wd_beat == beat:
+                if frame is not None and meter.opened == beat:
                     self._wd_stall_stack = "".join(_tb.format_stack(frame))
                     captured_for = beat
 
     async def _watchdog_tick(self) -> None:
         import time as _time
 
-        now = _time.monotonic()
-        # refresh the heartbeat FIRST: the sampler must not attribute
-        # this tick's own logging to the stall it is reporting
-        last, self._wd_last = self._wd_last, now
-        self._wd_beat = now
-        if last:
-            lag = max(now - last - 0.1, 0.0)
-            if lag > self.watchdog_warn_s:
-                stack, self._wd_stall_stack = self._wd_stall_stack, None
-                self.log.warning(
-                    "event loop stalled for %.0f ms%s", lag * 1000,
-                    "; loop thread was at:\n" + stack if stack
-                    else " (stack not captured)",
-                )
-                self.metrics.counter("loop_stalls").inc()
-            # hold the WORST lag until the 1 Hz sampler reads it —
-            # a transient stall must not be erased by the next tick
-            self._wd_max_lag = max(self._wd_max_lag, lag)
+        meter = self._meter
+        if meter is None:
+            return  # a loop with no place to stand at its poll
+        # the longest turn since the last tick, the one this tick runs
+        # in (still open) among them
+        lag = max(self.longest_us / 1e6,
+                  _time.perf_counter() - meter.opened)
+        self.longest_us = 0
+        if lag > self.watchdog_warn_s:
+            stack, self._wd_stall_stack = self._wd_stall_stack, None
+            self.log.warning(
+                "event loop stalled for %.0f ms%s", lag * 1000,
+                "; loop thread was at:\n" + stack if stack
+                else " (stack not captured)",
+            )
+            self.metrics.counter("loop_stalls").inc()
+        # hold the WORST lag until the 1 Hz sampler reads it —
+        # a transient stall must not be erased by the next tick
+        self._wd_max_lag = max(self._wd_max_lag, lag)
 
     async def _sample_metrics(self) -> None:
-        self.metrics.gauge("loop_lag_ms").set(self._wd_max_lag * 1000)
+        import time as _time
+
+        self.metrics.gauge(
+            "loop_lag_ms",
+            help="the longest stretch the event loop was away from its "
+                 "poll (its longest turn) in the last sample",
+        ).set(self._wd_max_lag * 1000)
         self._wd_max_lag = 0.0
+        if self._meter is not None:
+            t0, busy0 = self._wd_busy
+            now, busy = _time.perf_counter(), self._meter.busy_us
+            self._wd_busy = (now, busy)
+            if t0:
+                self.metrics.gauge(
+                    "loop_busy_pct",
+                    help="share of the last sample the event loop was "
+                         "away from its poll, running callbacks",
+                ).set(100.0 * (busy - busy0) / ((now - t0) * 1e6))
         # burn gauges must decay with the windows, not freeze at the
         # last observed value when traffic stops
         self.slo.refresh_gauges()
@@ -553,12 +577,16 @@ class Daemon:
             self.spawn(self._run_timer(interval, coro_fn))
         import threading
 
-        self._wd_loop_ident = threading.get_ident()
-        self._wd_sampler_stop = threading.Event()
-        self._wd_sampler_thread = threading.Thread(
-            target=self._wd_sampler, name=self.name + "-watchdog", daemon=True
-        )
-        self._wd_sampler_thread.start()
+        self._meter = tracing.attach_meter()
+        if self._meter is not None:
+            self._meter.watchers.append(self)
+            self._wd_loop_ident = threading.get_ident()
+            self._wd_sampler_stop = threading.Event()
+            self._wd_sampler_thread = threading.Thread(
+                target=self._wd_sampler, name=self.name + "-watchdog",
+                daemon=True,
+            )
+            self._wd_sampler_thread.start()
         # no-op under LZ_PROF=0 (the switch is the start gate)
         self.profiler.start()
         self.log.info("%s listening on %s:%d", self.name, self.host, self.port)
@@ -569,6 +597,8 @@ class Daemon:
         if self._wd_sampler_stop is not None:
             self._wd_sampler_stop.set()
             self._wd_sampler_thread.join(timeout=1.0)
+        if self._meter is not None and self in self._meter.watchers:
+            self._meter.watchers.remove(self)
         if self._server is not None:
             self._server.close()
             # drop live connections: python 3.12's wait_closed() blocks

@@ -266,10 +266,13 @@ class PhaseBreakdown:
     until they are charged. ``snapshot`` returns cumulative totals,
     times as ``<phase>_ms`` and counts under their own names; subtract
     two snapshots (:func:`phase_delta`) to scope the breakdown to a
-    measured interval (bench reps)."""
+    measured interval (bench reps). :meth:`carry` lets the rows show
+    counts somebody else keeps (the loop meter's, runtime/tracing.py):
+    what the source has counted since, read where a snapshot is made,
+    and kept as plain counts once :meth:`settle` ends it."""
 
     __slots__ = ("name", "phases", "totals_s", "counts", "wall_s", "self_s",
-                 "reps", "_lock")
+                 "reps", "_lock", "_carried")
 
     def __init__(self, name: str, phases, counts=()):
         self.name = name
@@ -281,6 +284,7 @@ class PhaseBreakdown:
         self.self_s = 0.0
         self.reps = 0
         self._lock = threading.Lock()
+        self._carried = None    # (source, what it read when carry began)
 
     @property
     def top_level(self) -> tuple[str, ...]:
@@ -300,6 +304,24 @@ class PhaseBreakdown:
             self.self_s += self_seconds or 0.0
             self.reps += 1
 
+    def carry(self, source) -> None:
+        """Show, from now on, what ``source()`` (name -> count, only
+        ever rising) counts beyond what it reads now."""
+        self.settle()
+        self._carried = (source, source())
+
+    def _carried_counts(self) -> dict:
+        if self._carried is None:
+            return {}
+        source, base = self._carried
+        return {name: n - base[name] for name, n in source().items()}
+
+    def settle(self) -> None:
+        """End :meth:`carry`: what was shown stays, as counts."""
+        shown, self._carried = self._carried_counts(), None
+        for name, n in shown.items():
+            self.count(name, n)
+
     def snapshot(self) -> dict:
         with self._lock:
             out = {f"{p}_ms": round(v * 1e3, 2)
@@ -308,6 +330,8 @@ class PhaseBreakdown:
             out["wall_ms"] = round(self.wall_s * 1e3, 2)
             out["reps"] = self.reps
             out.update(self.counts)
+        for name, n in self._carried_counts().items():
+            out[name] = out.get(name, 0) + n
         return out
 
 

@@ -16,6 +16,7 @@ from lizardfs_tpu.proto.codec import Message
 from lizardfs_tpu.proto.status import StatusError
 from lizardfs_tpu.runtime import faults as _faults
 from lizardfs_tpu.runtime import retry as _retry
+from lizardfs_tpu.runtime import tracing
 
 
 class RpcConnection:
@@ -73,6 +74,11 @@ class RpcConnection:
                 req_id = getattr(msg, "req_id", None)
                 fut = self._pending.pop(req_id, None) if req_id is not None else None
                 if fut is not None and not fut.done():
+                    # where the reply was ready for its caller: the
+                    # stamp travels with the reply (pipelined calls
+                    # each read their own), and the caller lays its
+                    # way back from it (tracing.wake)
+                    msg.woke = tracing.stamp()
                     fut.set_result(msg)
                 # unsolicited + unhandled messages are dropped
         except (asyncio.IncompleteReadError, ConnectionError, asyncio.CancelledError):

@@ -34,8 +34,11 @@ spans in the same ``.xplane.pb`` as the device's operations.
 Cost contract: with ``LZ_TRACE=0`` no ids are issued,
 ``current_trace_id()`` is 0 everywhere, and :func:`span` charges its
 phase row and does nothing else (the phase rows are the always-on
-counters). The measured cost of the default (on) is in
-``doc/operations.md`` ("Request tracing").
+counters). The loop meter (:class:`LoopMeter`) is such a counter: its
+four counts run with ``LZ_TRACE=0`` too, at two clock readings a side
+of every poll; the ``<name>_hold`` rows and the ``wake`` spans need the
+span tree and are laid only while tracing is enabled. The measured cost
+of the default (on) is in ``doc/operations.md`` ("Request tracing").
 
 Clocks: ring spans carry CLOCK_REALTIME epoch seconds (C side:
 microseconds via clock_gettime) so same-host cross-process merges line
@@ -46,11 +49,15 @@ clock, so the profile's clock can be laid on the rings' axis.
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
+import functools
 import itertools
 import os
 import time
 from collections import deque
+from threading import get_ident
+from time import perf_counter, thread_time
 
 # process-wide kill switch: LZ_TRACE=0 disables issuing trace ids, which
 # short-circuits every record path (spans are only recorded for nonzero
@@ -68,7 +75,7 @@ CURRENT: contextvars.ContextVar = contextvars.ContextVar(
 # the logical op in flight: where its spans charge phases and record
 # (an OpSink). A contextvar (not a global) keeps concurrent clients in
 # one process (in-process test clusters, gateways) from cross-charging;
-# tasks and to_thread copy it, run_in_executor does not (carry()).
+# tasks and to_thread copy it, run_in_executor does not (Hop).
 PHASE_SINK: contextvars.ContextVar = contextvars.ContextVar(
     "lz_phase_sink", default=None
 )
@@ -277,6 +284,152 @@ class OpSink:
         self.metrics = metrics
 
 
+# --- the loop meter -------------------------------------------------------------
+
+# the loop that runs on a thread, by the thread's ident: what a span
+# that opens there, or a stamp made there, reads the turn from
+_METERS: dict = {}
+
+LOOP_COUNTS = ("loop_turns", "loop_busy_us", "loop_turn_sq_us2",
+               "loop_offcpu_us")
+
+
+class LoopMeter:
+    """Every turn of one asyncio loop, stamped where the loop polls.
+
+    It stands in for the loop's selector (``loop._selector``): its
+    :meth:`select` reads the clocks on entry and on return and hands
+    everything else to the selector it wraps. A *turn* is from
+    ``select()``'s return to the next ``select()``'s entry: the time the
+    loop was away from its poll, during which nothing that became ready
+    can be served. Exact, not sampled; a turn costs two ``perf_counter``
+    and two ``thread_time`` readings and a few additions.
+
+    The counts are additive integers of µs, so that a window is the
+    difference of two readings: ``turns`` closed, ``busy_us`` (Σ turn
+    lengths), ``sq_us2`` (Σ of squared turn lengths: over twice a
+    window it is what an event that becomes ready at a random instant
+    waits for the next poll), ``offcpu_us`` (Σ over turns of wall less
+    the loop thread's CPU time: the thread waiting for the GIL or
+    blocked in a call that is not the poll). ``turn`` is the open
+    turn's ordinal and ``opened`` when it opened (``perf_counter``):
+    what a span reads to tell whether it gave the loop back, and the
+    beat a daemon's stall sampler reads. Whoever is in ``watchers``
+    (a daemon, for its ``loop_lag_ms``) has its ``longest_us`` raised
+    to the longest turn since it set it to 0.
+
+    What it cannot see: the loop thread's wait to get the GIL back as
+    it leaves the poll is inside ``select()`` and counts as polling.
+
+    One a loop (:func:`attach_meter`), gone when the loop closes. The
+    counts reach a reader through the phase rows of ONE rider, the
+    first that asked (:meth:`ride`), so that a sum over the sessions of
+    a loop counts the loop once; when the rider leaves, what it showed
+    stays in its rows and the next in line shows the rest."""
+
+    __slots__ = ("loop", "ident", "turn", "opened", "turns", "busy_us",
+                 "sq_us2", "offcpu_us", "watchers", "_cpu", "_selector",
+                 "_select", "_riders", "register", "unregister", "modify",
+                 "get_key", "get_map")
+
+    def __init__(self, loop, selector):
+        self.loop = loop
+        self.ident = get_ident()
+        self._selector = selector
+        self._select = selector.select
+        # what the loop asks of its selector beside select and close
+        for name in ("register", "unregister", "modify", "get_key",
+                     "get_map"):
+            setattr(self, name, getattr(selector, name))
+        self.turn = self.turns = 0
+        self.busy_us = self.sq_us2 = self.offcpu_us = 0
+        self.watchers: list = []
+        self._riders: list = []
+        self._cpu = thread_time()
+        self.opened = perf_counter()
+
+    def __getattr__(self, name):
+        return getattr(self._selector, name)
+
+    def select(self, timeout=None):
+        wall = perf_counter() - self.opened
+        off = int((wall - (thread_time() - self._cpu)) * 1e6)
+        us = int(wall * 1e6)
+        self.turns += 1
+        self.busy_us += us
+        self.sq_us2 += us * us
+        if off > 0:
+            self.offcpu_us += off
+        for watcher in self.watchers:
+            if us > watcher.longest_us:
+                watcher.longest_us = us
+        try:
+            return self._select(timeout)
+        finally:
+            self._cpu = thread_time()
+            self.opened = perf_counter()
+            self.turn += 1
+
+    def close(self) -> None:
+        """The loop closes its selector as the last thing it does."""
+        self.detach()
+        self._selector.close()
+
+    def detach(self) -> None:
+        if _METERS.get(self.ident) is self:
+            del _METERS[self.ident]
+        if getattr(self.loop, "_selector", None) is self:
+            self.loop._selector = self._selector
+        for rows in self._riders[:1]:
+            rows.settle()
+        self._riders.clear()
+
+    def counts(self) -> dict:
+        return dict(zip(LOOP_COUNTS, (self.turns, self.busy_us, self.sq_us2,
+                                      self.offcpu_us)))
+
+    def ride(self, rows) -> None:
+        """``rows`` (a ``PhaseBreakdown``) shows the loop's counts from
+        now on, or waits in line behind the rows that do."""
+        if rows not in self._riders:
+            self._riders.append(rows)
+            if len(self._riders) == 1:
+                rows.carry(self.counts)
+
+    def leave(self, rows) -> None:
+        if rows not in self._riders:
+            return
+        first = self._riders[0] is rows
+        self._riders.remove(rows)
+        if first:
+            rows.settle()
+            if self._riders:
+                self._riders[0].carry(self.counts)
+
+
+def attach_meter() -> LoopMeter | None:
+    """The meter of the loop that runs here, attached now if it has
+    none; None on a loop that offers no place to stand at its poll
+    (the counts are then absent and their readers give None)."""
+    loop = asyncio.get_running_loop()
+    meter = _METERS.get(get_ident())
+    if meter is not None:
+        if meter.loop is loop:
+            return meter
+        meter.detach()  # a loop that ended here and was never closed
+    selector = getattr(loop, "_selector", None)
+    if not callable(getattr(selector, "select", None)):
+        return None
+    meter = _METERS[get_ident()] = LoopMeter(loop, selector)
+    loop._selector = meter
+    return meter
+
+
+def loop_meter() -> LoopMeter | None:
+    """The meter of the loop that runs on this thread, if one does."""
+    return _METERS.get(get_ident())
+
+
 class Span:
     """The one span primitive: a context manager (or a ``begin()`` /
     ``end()`` pair) round one layer's share of an op; ``span`` is its
@@ -287,7 +440,7 @@ class Span:
     self time (duration less the union of what its children cover, so
     parallel children count once), (c) adds its interval to its
     parent's covered union; while open it is the parent of what runs
-    inside it (tasks and ``to_thread`` copy the context; :func:`carry`
+    inside it (tasks and ``to_thread`` copy the context; :class:`Hop`
     takes it into executor threads) and, while a profiler session is
     live in a process that registered an annotator, a profiler
     annotation ``lz.<layer>.<name>``.
@@ -298,13 +451,21 @@ class Span:
     a span charges its phase row (and a root its wall) and does
     nothing else.
 
+    A span that opens and closes on a metered loop's thread inside one
+    turn (:class:`LoopMeter`) never gave the loop back: it held it for
+    its whole length, and charges its self time (nested synchronous
+    spans count once) to the ambient op under ``<name>_hold``. One that
+    suspends inside charges none: the Python it runs on the loop
+    between its awaits stays in the loop's unnamed remainder.
+
     It runs some fifty times in a small write on a loop that twelve
     sessions share, so it allocates little: the ring keeps the closed
     span itself and makes the record when somebody dumps it."""
 
     __slots__ = ("name", "layer", "phase", "bucket", "sink", "attrs",
                  "p0", "w0", "dur", "self_s", "role", "trace_id", "span_id",
-                 "parent", "covered", "_prev", "_prev_sink", "_ann")
+                 "parent", "covered", "_prev", "_prev_sink", "_ann",
+                 "_meter", "_turn")
 
     def __init__(self, name: str, *, layer: str = "client",
                  phase: str | None = None, bucket: str | None = None,
@@ -343,6 +504,11 @@ class Span:
         self.covered = []
         self.w0 = time.time() - (now - self.p0)
         CURRENT.set(self)
+        # the turn it opens in, on a metered loop's own thread (a span
+        # laid after the fact held nothing)
+        meter = self._meter = _METERS.get(get_ident()) if at is None else None
+        if meter is not None:
+            self._turn = meter.turn
         if _ANNOTATE is not None and at is None and _ANNOTATING():
             meta = self.attrs
             if self.sink is not None:
@@ -373,6 +539,11 @@ class Span:
                 self_s = max(dur - _union_seconds(
                     [(max(a, w0), min(b, w1)) for a, b in self.covered
                      if b > w0 and a < w1]), 0.0)
+            meter = self._meter
+            if (meter is not None and meter.turn == self._turn
+                    and at is None and sink is not None
+                    and get_ident() == meter.ident):
+                sink.phases.add(self.name + "_hold", self_s)
             if sink is not None and sink.ring is not None:
                 self.dur, self.self_s, self.role = dur, self_s, sink.role
                 sink.ring.push(self)
@@ -406,38 +577,118 @@ class Span:
 span = Span
 
 
-def carry():
-    """What an executor hop has to carry by hand (``run_in_executor``
-    copies no context): the open span, the ambient sink and when the
-    hop was submitted. None where there is nothing to carry."""
-    cur, sink = CURRENT.get(), PHASE_SINK.get()
-    if cur is None and sink is None:
+# the trip this worker thread is serving (Hop.__call__): where the
+# native call it makes says when C saw its end (native_end)
+_HOP: contextvars.ContextVar = contextvars.ContextVar("lz_hop", default=None)
+
+
+class Hop:
+    """One trip to a worker thread and back, for the op in flight.
+
+    Made in the task that hands the work over: it takes what no
+    executor carries (``run_in_executor`` copies no context), the open
+    span, the ambient sink and when the trip was submitted. Called in
+    the worker: what it took becomes ambient, the wait for the thread
+    is a span of its own (``hop``; ``hop_compute`` where the work is
+    the encoder's or a copy and not the wire's), and the last thing it
+    does is stamp the clock. :meth:`wake`, in the coroutine that
+    waited, as it runs again: the way back as a ``wake`` span under the
+    span that waited, from that stamp, or, where the native call the
+    worker made reported its own end (:func:`native_end`), from there,
+    with the worker's wait to get the GIL back as ``wake_gil`` under
+    it. ``func`` and ``args`` are a ``functools.partial``'s, for
+    whoever names the work by them (runtime/detsched.py)."""
+
+    __slots__ = ("func", "args", "phase", "cur", "sink", "submitted",
+                 "meter", "done", "turn", "c_end", "gil_at")
+
+    def __init__(self, func, *args, phase: str = "hop"):
+        self.func = func
+        self.args = args
+        self.phase = phase
+        self.cur, self.sink = CURRENT.get(), PHASE_SINK.get()
+        self.meter = _METERS.get(get_ident())
+        self.done = self.c_end = self.gil_at = None
+        self.submitted = perf_counter()
+
+    def __call__(self):
+        if self.cur is None and self.sink is None:
+            return self.func(*self.args)  # no op in flight to charge
+        CURRENT.set(self.cur)
+        PHASE_SINK.set(self.sink)
+        _HOP.set(self)
+        span(self.phase, layer="wire", phase=self.phase,
+             bucket="queue").begin(at=self.submitted).end()
+        try:
+            return self.func(*self.args)
+        finally:
+            # pooled threads serve many requests: leak nothing into the next
+            CURRENT.set(None)
+            PHASE_SINK.set(None)
+            _HOP.set(None)
+            meter = self.meter
+            self.turn = meter.turn if meter is not None else -1
+            self.done = perf_counter()
+
+    def wake(self) -> None:
+        if self.done is None:
+            return  # the worker has not ended: nobody was woken
+        at = self.done if self.c_end is None else self.c_end
+        wake("thread", (at, self.turn), self.gil_at)
+
+
+async def hop(func, *args, executor=None, phase: str = "hop"):
+    """``func(*args)`` on a thread of ``executor`` (None: the loop's
+    own, as ``asyncio.to_thread``), under a copy of this task's
+    context: the one way the client path goes to a thread and comes
+    back (:class:`Hop`)."""
+    trip = Hop(func, *args, phase=phase)
+    try:
+        return await asyncio.get_running_loop().run_in_executor(
+            executor, functools.partial(contextvars.copy_context().run, trip))
+    finally:
+        trip.wake()
+
+
+def native_end(at: float, now: float) -> None:
+    """In a worker, after a native call that reports its own end on the
+    steady clock: C saw the work end at ``at``; ``now`` is the worker's
+    first reading after the call, once it had the GIL back."""
+    trip = _HOP.get()
+    if trip is not None:
+        trip.c_end, trip.gil_at = min(at, now), now
+
+
+def stamp():
+    """Now, and the open turn of the loop that runs here, for a
+    :func:`wake` laid later; None while tracing is disabled."""
+    if not _ENABLED:
         return None
-    return (cur, sink, time.perf_counter())
+    meter = _METERS.get(get_ident())
+    return (perf_counter(), meter.turn if meter is not None else -1)
 
 
-class carried:
-    """In the worker thread: what :func:`carry` took becomes ambient,
-    and the wait for the thread is a ``hop`` span of its own."""
-
-    __slots__ = ("token",)
-
-    def __init__(self, token):
-        self.token = token
-
-    def __enter__(self):
-        cur, sink, submitted = self.token
-        CURRENT.set(cur)
-        PHASE_SINK.set(sink)
-        span("hop", layer="wire", phase="hop", bucket="queue").begin(
-            at=submitted).end()
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        # pooled threads serve many requests: leak nothing into the next
-        CURRENT.set(None)
-        PHASE_SINK.set(None)
-        return False
+def wake(after: str, stamped, gil_at: float | None = None) -> None:
+    """The way back to the coroutine that runs this, as a span laid
+    after the fact under the open one (bucket ``queue``): from
+    ``stamped`` (a :func:`stamp`: where the work it waited for was
+    done) to now. ``after`` says what it waited for (``"thread"``,
+    ``"rpc"``), ``turns`` how many turns of its loop opened meanwhile;
+    ``gil_at`` closes a ``wake_gil`` under it. Needs the span tree:
+    nothing is laid while tracing is disabled."""
+    if stamped is None or not _ENABLED:
+        return
+    at, turn = stamped
+    meter = _METERS.get(get_ident())
+    attrs = {"after": after}
+    if meter is not None and turn >= 0:
+        attrs["turns"] = meter.turn - turn
+    way = span("wake", layer="loop", phase="wake", bucket="queue",
+               **attrs).begin(at=at)
+    if gil_at is not None:
+        span("wake_gil", layer="loop", phase="wake_gil",
+             bucket="queue").begin(at=at).end(at=gil_at)
+    way.end()
 
 
 def _union_seconds(intervals: list[tuple[float, float]]) -> float:

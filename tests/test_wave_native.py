@@ -613,7 +613,13 @@ async def test_wave_plane_span_tree_and_rows(tmp_path):
         waves = [s for s in spans if s["name"] == "waves"]
         assert len(waves) == 1
         under = [s for s in spans if s["parent_id"] == waves[0]["span_id"]]
-        assert sorted(s["name"] for s in under) == ["hop"] + ["net"] * 8
+        # one worker: one hop out, eight parts, one way back (PR 36)
+        assert sorted(s["name"] for s in under) == (
+            ["hop"] + ["net"] * 8 + ["wake"])
+        wake = [s for s in under if s["name"] == "wake"][0]
+        assert wake["attrs"]["after"] == "thread"
+        last = max(s["t1"] for s in under if s["name"] == "net")
+        assert abs(wake["t0"] - last) < 1e-3   # from where C saw it end
         for s in under:
             if s["name"] != "net":
                 continue

@@ -356,12 +356,23 @@ async def test_pwrite_yields_one_span_tree_that_sums_to_wall(
         assert exchange[0]["attrs"]["parts"] == 12
         assert exchange[0]["attrs"]["bytes"] == 12 * 262144
         if plane == "scatter":
-            # ONE exchange: one worker, one hop, every leg once
+            # ONE exchange: one worker, one hop, every leg once, and
+            # the way back from where C saw the last leg end (PR 36),
+            # the worker's wait to get the GIL back under it
             assert len(parts) == 1
-            legs = [s["name"] for s in spans
+            legs = [s for s in spans
                     if s["parent_id"] == parts[0]["span_id"]]
-            assert sorted(legs) == ["hop", "part_data", "part_dial",
-                                    "part_end", "part_init"], legs
+            assert sorted(s["name"] for s in legs) == [
+                "hop", "part_data", "part_dial", "part_end", "part_init",
+                "wake"], legs
+            wake = [s for s in legs if s["name"] == "wake"][0]
+            assert wake["attrs"]["after"] == "thread"
+            assert wake["attrs"]["turns"] >= 1
+            gil = [s for s in spans if s["name"] == "wake_gil"
+                   and s["parent_id"] == wake["span_id"]]
+            assert len(gil) == 1 and gil[0]["t1"] <= wake["t1"] + 1e-4
+            end = [s for s in legs if s["name"] == "part_end"][0]
+            assert abs(wake["t0"] - end["t1"]) < 1e-3
             for leg in ("part", "hop", "part_init", "part_data",
                         "part_end"):
                 assert d[f"{leg}_ms"] > 0.0, leg
