@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import functools
 import logging
 import time as _time
 
@@ -2171,10 +2172,10 @@ class Client:
         per-part lengths, the pooled parity send buffer, the scatter
         session + abort cell, slot-aligned segment bounds (as many
         segments as the window's ceiling, so that it can fill), and the
-        per-segment encode/payload/length closures — a stripe-geometry
-        or encoder-boundary change lands in exactly one place.
-        Returns ``(par_buf, cell, session, bounds, encode_segment,
-        seg_payloads, seg_lengths)``."""
+        per-segment encode (which returns the segment's payloads) and
+        length closures — a stripe-geometry or encoder-boundary change
+        lands in exactly one place. Returns ``(par_buf, cell, session,
+        bounds, encode_segment, seg_lengths)``."""
         from lizardfs_tpu.core import native_io
 
         d = slice_type.data_parts
@@ -2206,42 +2207,31 @@ class Client:
             for a in range(0, blocks_per_part, seg_blocks)
         ]
 
-        def encode_segment(a: int, b: int, views=None) -> None:
+        def encode_segment(a: int, b: int, views=None) -> list:
             data_seg = [stacked[i][a:b] for i in range(d)]
+            par_out = [par_buf[j][a:b] for j in range(m_par)]
             if views is not None:
                 # shm-ring staging: parity is encoded STRAIGHT into the
-                # chunkserver-mapped arena (zero copies end to end);
-                # data rows stay in the stage buffer — their single
-                # GIL-free memcpy into the arena happens inside the
-                # native descriptor send (native/shm_ring.h). The
-                # later "send" phase moves descriptors, not megabytes.
-                par_out = [views[d + j] for j in range(m_par)]
-                if par_out[0] is None:
-                    return  # segment past every part's live length
-                if slice_type.is_xor:
-                    self.encoder.xor_parity_into(data_seg, par_out[0])
-                else:
-                    self.encoder.encode_into(d, m_par, data_seg, par_out)
-                return
+                # chunkserver-mapped arena (zero copies end to end) and
+                # that view is its payload, so the native send moves
+                # zero parity bytes; data rows stay in the stage buffer
+                # — their single GIL-free memcpy into the arena happens
+                # inside the native descriptor send (native/shm_ring.h).
+                # The "send" phase moves descriptors, not megabytes.
+                if views[d] is None:
+                    # segment past every part's live length
+                    return data_seg + par_out
+                par_out = views[d:d + m_par]
             if slice_type.is_xor:
-                self.encoder.xor_parity_into(data_seg, par_buf[0][a:b])
+                self.encoder.xor_parity_into(data_seg, par_out[0])
             else:
-                self.encoder.encode_into(
-                    d, m_par, data_seg,
-                    [par_buf[j][a:b] for j in range(m_par)],
-                )
-
-        def seg_payloads(a: int, b: int) -> list:
-            return (
-                [stacked[i][a:b] for i in range(d)]
-                + [par_buf[j][a:b] for j in range(m_par)]
-            )
+                self.encoder.encode_into(d, m_par, data_seg, par_out)
+            return data_seg + par_out
 
         def seg_lengths(a: int, b: int) -> list[int]:
             return [max(min(b, plens[p]) - a, 0) for p in order]
 
-        return (par_buf, cell, session, bounds, encode_segment,
-                seg_payloads, seg_lengths)
+        return (par_buf, cell, session, bounds, encode_segment, seg_lengths)
 
     async def _push_striped_windowed(
         self, grant, chunk_data, slice_type, by_part, stacked,
@@ -2262,12 +2252,24 @@ class Client:
         aligned, so the chunkservers land the same per-block pieces
         and store the same CRCs. Raises on any failure; the caller's
         whole-part fallback heals torn segments. The caller has
-        already charged the QoS throttle."""
+        already charged the QoS throttle.
+
+        A segment is ONE trip to a worker thread
+        (``PartsScatterSession.window_trip``): its encode, its send and
+        the reap of the oldest segments the depth no longer allows run
+        there in a row, the chunk's open before the first and its
+        finish after the last. The loop stages the ring views, takes
+        the segment's credits before the trip, and after it returns the
+        credits of what the worker reaped and feeds the depth
+        controller the worker's own times: ``WriteWindow`` is touched
+        on the loop alone. A full ring or a shut credit gate still
+        reaps on the loop, a trip of its own (:meth:`_window_collect`).
+        ``window_trips`` counts every trip."""
         from lizardfs_tpu.core import native_io
 
         win = self.write_window
         d = slice_type.data_parts  # ring widths: data rows vs parity
-        (par_buf, cell, session, bounds, encode_segment, seg_payloads,
+        (par_buf, cell, session, bounds, encode_segment,
          seg_lengths) = self._stripe_send_plan(
             grant, chunk_data, slice_type, by_part, stacked, part_len,
             send_cells,
@@ -2275,49 +2277,31 @@ class Client:
 
         from collections import deque
 
-        # (write_id, credited bytes, encode seconds, send seconds so far)
+        # (write_id, credited bytes, encode seconds, send seconds): the
+        # segments whose credits are held, oldest first
         outstanding: deque[list] = deque()
         try:
-            with tracing.span("send", phase="send", bucket="net", seg=0):
-                await native_io.run(session.open)
             for wid, (a, b) in enumerate(bounds, start=1):
                 lengths = seg_lengths(a, b)
                 # shm-ring staging: reserve this segment's arena regions
                 # BEFORE encoding so parity lands straight in mapped
-                # memory. A full ring reaps the oldest segment's acks
-                # (freeing its regions) and retries; with nothing left
-                # to reap, this segment takes the socket-copy send.
+                # memory. Parity regions are allocated at the full
+                # padded segment width (the encoder writes the whole
+                # column range); only the live bytes go on the wire. A
+                # full ring reaps the oldest segment's acks (freeing its
+                # regions) and retries; with nothing left to reap, this
+                # segment takes the socket-copy send. No ring is up
+                # before the session opens: the first trip opens it and
+                # stages there.
+                widths = lengths[:d] + [b - a] * (len(lengths) - d)
                 views = None
                 if session.ring_ready():
-                    # parity regions are allocated at the full padded
-                    # segment width (the encoder writes the whole
-                    # column range); only the live bytes go on the wire
-                    widths = lengths[:d] + [b - a] * (len(lengths) - d)
                     views = session.ring_stage(wid, lengths, widths)
                     while views is None and outstanding:
                         await self._window_collect(session, win, outstanding)
                         views = session.ring_stage(wid, lengths, widths)
-                t0 = _time.perf_counter()
-                try:
-                    with tracing.span("encode", phase="encode",
-                                      bucket="compute", seg=wid):
-                        await tracing.hop(encode_segment, a, b, views,
-                                          phase="hop_compute")
-                except BaseException:
-                    session.ring_unstage(wid)
-                    raise
-                enc_dt = _time.perf_counter() - t0
-                payloads = seg_payloads(a, b)
-                if views is not None:
-                    # parity already lives in its staged arena view —
-                    # hand THAT as the payload so the native send sees
-                    # src == dst and moves zero parity bytes; data rows
-                    # keep their stage-buffer source for the C memcpy
-                    for idx in range(d, len(views)):
-                        if views[idx] is not None:
-                            payloads[idx] = views[idx]
                 seg_bytes = sum(lengths)
-                # credits BEFORE the send: per-chunkserver in-flight
+                # credits BEFORE the trip: per-chunkserver in-flight
                 # frames + the client-wide staging budget (returned as
                 # each segment's commit acks come back). NEVER block on
                 # credits while holding outstanding segments — reap the
@@ -2351,28 +2335,23 @@ class Client:
                 win.note_segment(waited)
                 self._count_write("window_segments")
                 self._count_write("window_depth_sum", win.depth)
-                try:
-                    t0 = _time.perf_counter()
-                    with tracing.span("send", phase="send", bucket="net",
-                                      seg=wid):
-                        await native_io.run(
-                            session.send_segment_window, payloads, lengths,
-                            a, wid,
-                        )
-                    send_dt = _time.perf_counter() - t0
-                except BaseException:
-                    win.release(session.unique_addrs, seg_bytes)
-                    raise
-                outstanding.append([wid, seg_bytes, enc_dt, send_dt])
-                # window full: reap the oldest segment's acks (depth is
-                # LIVE — adaptation may have moved it since the last
-                # segment, so reap down to the current depth)
-                while len(outstanding) >= max(win.depth, 1):
-                    await self._window_collect(session, win, outstanding)
-            while outstanding:
-                await self._window_collect(session, win, outstanding)
-            with tracing.span("send", phase="send", bucket="net", seg=-1):
-                await native_io.run(session.finish)
+                outstanding.append([wid, seg_bytes, 0.0, 0.0])
+                self._count_write("window_trips")
+                # the worker reaps the oldest segments, this one among
+                # them, down to the depth (LIVE: read once a trip), and
+                # all of them on the chunk's last segment
+                last = wid == len(bounds)
+                keep = 0 if last else max(win.depth, 1) - 1
+                due = [seg[0] for seg in outstanding]
+                due = due[:len(due) - keep]
+                enc_dt, send_dt, reaped = await native_io.run(
+                    session.window_trip, wid,
+                    functools.partial(encode_segment, a, b), lengths,
+                    widths, a, views, due, last,
+                )
+                outstanding[-1][2:] = enc_dt, send_dt
+                for _wid, ack_dt in reaped:
+                    self._window_settle(win, session, outstanding, ack_dt)
         except BaseException:
             # the session's executor thread may still be streaming from
             # stacked/par_buf — kill the exchange before those buffers
@@ -2381,7 +2360,8 @@ class Client:
             raise
         finally:
             self._fold_ring_stats(session)
-            # failure path: return credits the reap loop never got to
+            # failure path: return the credits of every segment no
+            # settled reap returned, whatever the worker reaped
             for wid, seg_bytes, *_rest in outstanding:
                 win.release(session.unique_addrs, seg_bytes)
             self._stage_release(
@@ -2423,24 +2403,29 @@ class Client:
         session.ring_stats = {k: 0 for k in stats}
 
     async def _window_collect(self, session, win, outstanding) -> None:
-        """Reap the oldest outstanding segment: collect its acks,
-        return its credits, and feed the adaptive depth controller."""
+        """Reap the oldest outstanding segment on a trip of its own (a
+        full ring, a shut credit gate) and settle it. A reap that
+        raises leaves it outstanding: the caller's cleanup returns its
+        credits, once."""
         from lizardfs_tpu.core import native_io
 
-        wid, seg_bytes, enc_dt, send_dt = outstanding.popleft()
-        try:
-            t0 = _time.perf_counter()
-            # ack-reaping is backpressure (downstream disk/CPU), not
-            # push cost — its own phase, so send_ms keeps measuring the
-            # copy the shm ring exists to eliminate; the depth
-            # controller still sees the combined time (ack wait is
-            # exactly the send-bound signal that should deepen it)
-            with tracing.span("ack", phase="ack", bucket="net", seg=wid):
-                await native_io.run(session.collect_acks, wid)
-            send_dt += _time.perf_counter() - t0
-        finally:
-            win.release(session.unique_addrs, seg_bytes)
-        win.observe(enc_dt, send_dt)
+        self._count_write("window_trips")
+        (_wid, ack_dt), = await native_io.run(
+            session.reap, [outstanding[0][0]])
+        self._window_settle(win, session, outstanding, ack_dt)
+
+    @staticmethod
+    def _window_settle(win, session, outstanding, ack_dt: float) -> None:
+        """The oldest outstanding segment's acks are in: return its
+        credits and feed the adaptive depth controller. Ack-reaping is
+        backpressure (downstream disk/CPU), not push cost — its own
+        phase, so send_ms keeps measuring the copy the shm ring exists
+        to eliminate; the controller still sees send + ack combined
+        (ack wait is exactly the send-bound signal that should deepen
+        it)."""
+        _wid, seg_bytes, enc_dt, send_dt = outstanding.popleft()
+        win.release(session.unique_addrs, seg_bytes)
+        win.observe(enc_dt, send_dt + ack_dt)
 
     async def _write_part(
         self,

@@ -41,6 +41,11 @@ _EWMA_ALPHA = 0.3
 # depth ceiling: unacknowledged segments a chunk write may have in
 # flight, and the number of segments a chunk is cut into
 MAX_DEPTH = 8
+# depth floor the controller shrinks to (where the ceiling allows): at
+# depth 1 a segment is reaped right after its own send, so the
+# chunkservers' work on it never overlaps the next encode; double
+# buffering is the least window that pipelines at all
+MIN_DEPTH = 2
 # client-wide staging budget across all in-flight windowed segments
 BUDGET_BYTES = 128 * 2**20
 
@@ -167,8 +172,9 @@ class WriteWindow:
 
     def observe(self, encode_s: float, send_s: float) -> None:
         """Feed one collected segment's busy split; adapt depth with
-        hysteresis. encode-bound -> shrink (buffering cannot beat a
-        compute bottleneck), send-bound -> grow (keep the wire busy)."""
+        hysteresis. encode-bound -> shrink to ``MIN_DEPTH`` (buffering
+        cannot beat a compute bottleneck, but it still hides the acks),
+        send-bound -> grow (keep the wire busy)."""
         self._enc_ewma += _EWMA_ALPHA * (encode_s - self._enc_ewma)
         self._send_ewma += _EWMA_ALPHA * (send_s - self._send_ewma)
         self._since_adapt += 1
@@ -179,7 +185,7 @@ class WriteWindow:
                 and self.depth < self.max_depth):
             self.depth += 1
         elif (self._enc_ewma > self._send_ewma * _ADAPT_RATIO
-                and self.depth > 1):
+                and self.depth > min(MIN_DEPTH, self.max_depth)):
             self.depth -= 1
         if self._m_depth is not None:
             self._m_depth.set(float(self.depth))

@@ -1570,6 +1570,67 @@ class PartsScatterSession:
             self.close()
             raise
 
+    def reap(self, write_ids: list[int]) -> list[tuple[int, float]]:
+        """Collect the acks of ``write_ids`` (oldest first), each under
+        an ``ack`` span; returns each one's ``(write_id, seconds)``."""
+        reaped = []
+        for wid in write_ids:
+            t0 = time.perf_counter()
+            with tracing.span("ack", phase="ack", bucket="net", seg=wid):
+                self.collect_acks(wid)
+            reaped.append((wid, time.perf_counter() - t0))
+        return reaped
+
+    def _check_aborted(self) -> None:
+        if self.cell.get("aborted"):
+            self.close()
+            raise NativeIOError(-1, "scatter session (aborted)")
+
+    def window_trip(
+        self,
+        write_id: int,
+        encode,
+        lengths: list[int],
+        widths: list[int],
+        part_offset: int,
+        views,
+        due: list[int],
+        closing: bool,
+    ) -> tuple[float, float, list[tuple[int, float]]]:
+        """One segment of the windowed write in ONE trip to a worker:
+        ``encode(views)`` (returns the segment's payloads), the send,
+        then the reap of ``due`` (:meth:`reap`), and ``finish()`` where
+        ``closing``. A session not yet open opens first and stages the
+        segment's ring views itself (``lengths`` / ``widths``: no ring
+        is up before the open, and with nothing outstanding a full ring
+        takes the socket copy as the loop's staging would). Each step
+        is the span it is on its own, and the abort cell is checked
+        between steps. Returns ``(encode_s, send_s, reaped)``."""
+        if "submitted" not in self.cell:
+            with tracing.span("send", phase="send", bucket="net", seg=0):
+                self.open()
+            views = self.ring_stage(write_id, lengths, widths)
+        self._check_aborted()
+        t0 = time.perf_counter()
+        try:
+            with tracing.span("encode", phase="encode", bucket="compute",
+                              seg=write_id):
+                payloads = encode(views)
+            self._check_aborted()
+        except BaseException:
+            self.ring_unstage(write_id)
+            raise
+        t1 = time.perf_counter()
+        with tracing.span("send", phase="send", bucket="net", seg=write_id):
+            self.send_segment_window(payloads, lengths, part_offset, write_id)
+        t2 = time.perf_counter()
+        reaped = self.reap(due)
+        if closing:
+            self._check_aborted()
+            with tracing.span("send", phase="send", bucket="net", seg=-1):
+                self.finish()
+        return t1 - t0, t2 - t1, reaped
+
     def finish(self) -> None:
         try:
             # the windowed caller collects every segment before

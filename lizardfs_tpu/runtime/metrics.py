@@ -218,13 +218,15 @@ WRITE_PHASES = {
 # the end and chunks that took the whole-part sends (not eligible, or
 # the window raised), segments sent and those that waited at the credit
 # gate, part-segments handed over as shm-ring descriptors and sent by
-# socket copy, and the window's live depth summed at each segment
-# (its mean is window_depth_sum / window_segments).
+# socket copy, the window's live depth summed at each segment (its
+# mean is window_depth_sum / window_segments), and the window's trips
+# to a worker thread (one a segment, one more for each reap a full
+# ring or a shut credit gate makes on its own).
 WRITE_COUNTS = ("rmw_reads", "rmw_read_bytes", "rmw_region_bytes",
                 "payload_bytes",
                 "window_chunks", "fallback_chunks", "window_segments",
                 "window_credit_waits", "ring_parts", "socket_parts",
-                "window_depth_sum",
+                "window_depth_sum", "window_trips",
                 # unlink calls (their rows: CallRows, below)
                 "unlinks")
 READ_PHASES = {
