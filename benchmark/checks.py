@@ -13,13 +13,16 @@ Every number compared is an exact count with the limit 0:
                         that the model does not hold, and the reverse
   length_wrong          live files whose length at the master differs
   parts_wrong           sampled chunks of live files whose parts are not
-                        k + m on distinct chunkservers (less the killed
+                        what the goal keeps on distinct chunkservers
+                        (k + m for $ec(k,m), N + 1 for xorN, N files of
+                        the one id for N copies; less the killed
                         server's, where the mix killed one), or whose
                         part files are not where the master says
   stored_wrong_bytes    bytes of those chunks' part files on the
                         chunkservers' disks, parity parts included, that
                         differ from the reference's striping and
-                        Reed-Solomon parity of the model's contents
+                        Reed-Solomon or XOR parity of the model's
+                        contents, or, for each copy, from the contents
   stored_wrong_crcs     CRC words of those part files that differ from
                         the CRC32 of the reference's blocks
   readback_wrong_bytes  bytes of sampled live files read back cold after
@@ -32,13 +35,15 @@ Where the window killed a server and the master rebuilt what it held,
 the mix's ``check.rebuilt_chunks`` more chunks are drawn from those of
 which a part was rebuilt, so that the rebuilt part files are compared
 in every run, not by the sample's luck; ``parts_wrong`` then asks for
-k + m parts on distinct live servers, the victim's directory left out.
+every part on distinct live servers, the victim's directory left out.
 
 The reference (``benchmark/reference``) imports nothing of the program
 and takes nothing the program made.
 """
 
 from __future__ import annotations
+
+from collections import Counter
 
 import numpy as np
 
@@ -67,22 +72,23 @@ def sample_with_first(items: list, n: int, rng) -> list:
     return items[:1] + [items[i] for i in rest]
 
 
-def check_chunk(data: np.ndarray, k: int, m: int, block: int,
-                part_files: dict[int, str]) -> tuple[int, int]:
+def check_chunk(data: np.ndarray, goal: dict, block: int,
+                part_files) -> tuple[int, int]:
     """(wrong bytes, wrong CRC words) of one chunk's stored parts
-    against the reference; ``part_files`` maps part index -> path."""
-    per_part = check_parts(data, k, m, block, part_files)
-    return (sum(b for b, _c in per_part.values()),
-            sum(c for _b, c in per_part.values()))
+    against the reference; ``part_files``: (part index, path) pairs."""
+    per_part = check_parts(data, goal, block, part_files)
+    return (sum(b for _p, b, _c in per_part), sum(c for _p, _b, c in per_part))
 
 
-def check_parts(data: np.ndarray, k: int, m: int, block: int,
-                part_files: dict[int, str]) -> dict[int, tuple[int, int]]:
-    """Part index -> (wrong bytes, wrong CRC words) of that part file."""
-    want_parts = layout.expected_parts(data, k, m, block)
-    live = layout.part_lengths(k, m, len(data), block)
-    out = {}
-    for p, path in part_files.items():
+def check_parts(data: np.ndarray, goal: dict, block: int,
+                part_files) -> list[tuple[int, int, int]]:
+    """(part index, wrong bytes, wrong CRC words) of each part file of
+    the (part index, path) pairs, against the goal's stream of that
+    index: every copy of a copy goal against the chunk's bytes whole."""
+    want_parts = layout.goal_parts(goal, data, block)
+    live = layout.goal_part_lengths(goal, len(data), block)
+    out = []
+    for p, path in part_files:
         bad_bytes = bad_crcs = 0
         body, table = layout.read_part_file(path, block)
         want = want_parts[p]
@@ -95,12 +101,12 @@ def check_parts(data: np.ndarray, k: int, m: int, block: int,
         want_crcs = layout.block_crcs(want[:nblocks * block], block)
         bad_crcs += sum(1 for a, b in zip(table[:nblocks], want_crcs) if a != b)
         bad_crcs += max(nblocks - len(table), 0)
-        out[p] = (bad_bytes, bad_crcs)
+        out.append((p, bad_bytes, bad_crcs))
     return out
 
 
 async def chunk_table(traffic, client, config: dict) -> dict:
-    """Chunk id -> (k, m, chunk length, block) for every chunk of the
+    """Chunk id -> (goal, chunk length, block) for every chunk of the
     model's live files: the master is asked for the id alone, the
     length and the goal are the harness's own."""
     block, chunk_bytes = int(config["block_bytes"]), int(config["chunk_bytes"])
@@ -111,8 +117,31 @@ async def chunk_table(traffic, client, config: dict) -> dict:
         goal = traffic.dirs[f.dir].goal
         for ci, (a, b) in enumerate(layout.chunk_spans(f.length, chunk_bytes)):
             info = await client.chunk_info(f.inode, ci)
-            out[info.chunk_id] = (int(goal["k"]), int(goal["m"]), b - a, block)
+            out[info.chunk_id] = (goal, b - a, block)
     return out
+
+
+def stored_parts(info, goal: dict, lost: int, cs_dirs: list[str]):
+    """(parts as the goal keeps them, (part index, path) of each part
+    file the master names): the master's places are as many as the
+    goal keeps (less ``lost``), on distinct servers, of the goal's ids,
+    and each part file lies on exactly as many servers as places name
+    its id."""
+    want_ids = layout.part_ids(goal)
+    got_ids = [loc.part_id for loc in info.locations]
+    n = len(want_ids) - lost
+    ports = {loc.addr.port for loc in info.locations}
+    ok = (len(got_ids) == n and len(ports) == n
+          and not Counter(got_ids) - Counter(want_ids))
+    files, homes = [], set()
+    for pid in sorted(set(got_ids) & set(want_ids)):
+        found = layout.find_part_files(cs_dirs, info.chunk_id, pid)
+        if len(found) != got_ids.count(pid):
+            ok = False
+            continue
+        homes.update(home for home, _path in found)
+        files += [(pid % 64, path) for _home, path in found]
+    return ok and len(homes) == len(files) == n, files
 
 
 async def rebuilt_picks(traffic, client, chunks: list, taken: list, n: int,
@@ -174,38 +203,23 @@ async def compare(traffic, client, config: dict, seed: int,
     rebuilt = {"chunks": 0, "parts": 0, "wrong_bytes": 0, "wrong_crcs": 0}
     for f, ci in picks:
         goal = traffic.dirs[f.dir].goal
-        k, m = int(goal["k"]), int(goal["m"])
         try:
             info = await client.chunk_info(f.inode, ci)
             lost = 1 if (f.name, ci) in traffic.lost_part_chunks else 0
-            ports = {loc.addr.port for loc in info.locations}
-            parts = {loc.part_id for loc in info.locations}
-            want_ids = {layout.ec_part_id(k, m, p) for p in range(k + m)}
-            ok = (len(info.locations) == k + m - lost
-                  and len(ports) == len(info.locations)
-                  and parts <= want_ids and len(parts) == len(info.locations))
-            files, homes = {}, set()
-            for pid in sorted(parts & want_ids):
-                found = layout.find_part_files(cs_dirs, info.chunk_id, pid)
-                if len(found) != 1:
-                    ok = False
-                    continue
-                homes.add(found[0][0])
-                files[pid % 64] = found[0][1]
-            ok = ok and len(homes) == len(files) == k + m - lost
+            ok, files = stored_parts(info, goal, lost, cs_dirs)
             if not ok:
                 v["parts_wrong"] += 1
             span = layout.chunk_spans(f.length, chunk_bytes)[ci]
             data = model.bytes_of(f, span[0], span[1] - span[0])
-            per_part = check_parts(data, k, m, block, files)
-            v["stored_wrong_bytes"] += sum(b for b, _c in per_part.values())
-            v["stored_wrong_crcs"] += sum(c for _b, c in per_part.values())
-            mine = [p for p in per_part if (
-                info.chunk_id, p) in traffic.rebuilt_parts]
+            per_part = check_parts(data, goal, block, files)
+            v["stored_wrong_bytes"] += sum(b for _p, b, _c in per_part)
+            v["stored_wrong_crcs"] += sum(c for _p, _b, c in per_part)
+            mine = [(b, c) for p, b, c in per_part
+                    if (info.chunk_id, p) in traffic.rebuilt_parts]
             rebuilt["chunks"] += bool(mine)
             rebuilt["parts"] += len(mine)
-            rebuilt["wrong_bytes"] += sum(per_part[p][0] for p in mine)
-            rebuilt["wrong_crcs"] += sum(per_part[p][1] for p in mine)
+            rebuilt["wrong_bytes"] += sum(b for b, _c in mine)
+            rebuilt["wrong_crcs"] += sum(c for _b, c in mine)
         except (OSError, ValueError, RuntimeError):
             v["unchecked"] += 1
     if notes is not None:

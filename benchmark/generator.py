@@ -28,12 +28,17 @@ A mix is:
                   set, each session in a seeded order of its own
   transfer_bytes  the size of one sequential read or write call
   preload         {"files", "bytes"}: files written during set-up
-  events          what happens inside the window beside the sessions:
-                  [{"at_share": 0-1, "fault": name}], each fired once,
-                  ``at_share`` of the window's seconds after its open,
-                  as a task of its own: no session's operation holds it
-                  and it holds none. A mix without the key runs as one
-                  that never had it
+  events          what happens inside the window beside the sessions,
+                  each fired once, as a task of its own: no session's
+                  operation holds it and it holds none. Either
+                  {"at_share": 0-1, "fault": name}: ``at_share`` of the
+                  window's seconds after its open; or {"at_bytes": B,
+                  "by_share": 0-1, "fault": name}: once the bytes the
+                  sessions' writes acknowledged since the open reach B,
+                  so that what the fault finds does not hang on the
+                  writers' rate, or at ``by_share`` of the window where
+                  that has not come, noted as "at_bytes not reached". A
+                  mix without the key runs as one that never had it
   redundancy_cap_s  the one key that selects the wait: a mix that
                   carries it has the worker poll the master from a kill
                   inside the window and wait, that many seconds after
@@ -158,7 +163,7 @@ class Retained:
 class Directory:
     name: str
     inode: int
-    goal: dict                     # {"id", "name", "expr", "k", "m"}
+    goal: dict      # {"id", "name", "expr"} and {"k", "m"}, {"xor"} or {"copies"}
 
 
 class Barrier:
@@ -218,6 +223,9 @@ class Traffic:
     shared: dict = field(default_factory=dict)          # session -> its batch
     recording: bool = False
     stop_at: float = math.inf
+    written: int = 0               # bytes of writes acknowledged inside the window
+    byte_waits: list = field(default_factory=list)      # (bytes, asyncio.Event)
+    notes: list = field(default_factory=list)           # for the worker's log
     retained_bytes: int = 0
     longest_retained: int = 0
     states: dict = field(default_factory=dict)          # per session
@@ -227,8 +235,15 @@ class Traffic:
         self.verbs = {v: load_verb(v) for v in verbs_of(
             self.mix["steps"] + self.mix["check"].get("make_live", []))}
         self.faults = [load_fault(a) for a in self.mix.get("faults", [])]
-        self.events = [(float(e["at_share"]), load_fault(e["fault"]))
-                       for e in self.mix.get("events", [])]
+        self.events, self.byte_events = [], []
+        for e in self.mix.get("events", []):
+            if "at_bytes" in e:
+                self.byte_events.append((int(e["at_bytes"]),
+                                         float(e["by_share"]), e["fault"],
+                                         load_fault(e["fault"])))
+            else:
+                self.events.append((float(e["at_share"]),
+                                    load_fault(e["fault"])))
         self.plan = plan(self.mix, self.seed)
         self.model = Model(make_pool(self.seed, self.plan.pool_bytes))
 
@@ -276,6 +291,9 @@ class Traffic:
         events = [asyncio.create_task(self._event(t_open + share * seconds,
                                                   fault))
                   for share, fault in self.events]
+        events += [asyncio.create_task(self._byte_event(
+            t_open, nbytes, t_open + share * seconds, name, fault))
+            for nbytes, share, name, fault in self.byte_events]
         try:
             await self._run(warm=False)
             await asyncio.gather(*events)   # an event that failed fails the run
@@ -288,6 +306,32 @@ class Traffic:
     async def _event(self, at: float, fault) -> None:
         await asyncio.sleep(max(at - time.monotonic(), 0.0))
         await fault.apply(self)
+
+    async def _byte_event(self, t_open: float, nbytes: int, by: float,
+                          name: str, fault) -> None:
+        """Fire once ``nbytes`` of writes have been acknowledged since
+        the open, or at ``by`` where they have not."""
+        reached = asyncio.Event()
+        self.byte_waits.append((nbytes, reached))
+        self._wake_byte_waits()
+        with contextlib.suppress(asyncio.TimeoutError):
+            await asyncio.wait_for(reached.wait(),
+                                   max(by - time.monotonic(), 0.0))
+        at, written = time.monotonic() - t_open, self.written
+        if reached.is_set():
+            self.notes.append(f"event {name}: at_bytes {nbytes} reached, "
+                              f"{written} B acknowledged {at:.3f}s into "
+                              "the window")
+        else:
+            self.notes.append(f"event {name}: at_bytes not reached: "
+                              f"{written} of {nbytes} B acknowledged; fired "
+                              f"on by_share, {at:.3f}s into the window")
+        await fault.apply(self)
+
+    def _wake_byte_waits(self) -> None:
+        for nbytes, reached in self.byte_waits:
+            if self.written >= nbytes:
+                reached.set()
 
     async def make_live(self) -> int:
         """After the close: where the window left too few files with
@@ -328,6 +372,9 @@ class Traffic:
             if self.recording:
                 self.ops.append(Op(cls, t0, time.monotonic(), nbytes, ok,
                                    metadata))
+                if ok and cls == "write":
+                    self.written += nbytes
+                    self._wake_byte_waits()
 
     async def _session(self, s: int, warm: bool) -> None:
         st = self._state(s)

@@ -51,21 +51,23 @@ def encode_call_ms(ctx):
 
 def span_device_seconds(ctx, span: str) -> float:
     """Device time of the traced window that lies under the benchmark's
-    span of that name (``bench.encode`` / ``bench.recover``, which the
-    tap opens round every call across the boundary): by where the time
+    span of that name (``bench.encode`` / ``bench.recover`` /
+    ``bench.xor``, which the tap opens round every call across the
+    boundary): by where the time
     lies, not by what the program that spent it is called, so a kernel
     that replaces today's is read the same way."""
     tr = ctx.get("trace")
     return tr["span_device_s"].get(span, 0.0) if tr else 0.0
 
 
-def _roofline(ctx, products, span):
-    """Share of the roofline of the window's GF products, each
-    (in_rows, out_rows, length), over the device time under ``span``."""
+def _roofline(ctx, calls, span, cost=rooflines.gf_product_cost):
+    """Share of the roofline of the window's calls (GF products, each
+    (in_rows, out_rows, length), by default) over the device time
+    under ``span``."""
     if not ctx.get("peaks"):
         return None
     got = rooflines.roofline_share_pct(
-        products, span_device_seconds(ctx, span), ctx["peaks"])
+        calls, span_device_seconds(ctx, span), ctx["peaks"], cost)
     return got[0] if got else None
 
 
@@ -78,6 +80,14 @@ def recover_roofline(ctx):
     return _roofline(ctx, [(rows, wanted, length) for _k, _m, rows, wanted,
                            length, _s in ctx["tap"].recover_calls],
                      "bench.recover")
+
+
+def xor_roofline(ctx):
+    """The XOR parity of xor goals: bytes-bound, N parts read and one
+    written a call, over the device time under ``bench.xor``."""
+    return _roofline(ctx, [(n, length) for n, length, _s
+                           in ctx["tap"].xor_calls], "bench.xor",
+                     rooflines.xor_cost)
 
 
 def master_rpc_ms_per_op(ctx):

@@ -130,15 +130,15 @@ def rebuilt_live_bytes(parts: set, chunks: dict) -> tuple[int, int]:
     the harness itself: for each (chunk id, part index) of the sound
     records, the live bytes the reference's layout gives that part for
     the length the harness's own model has of the chunk (``chunks``:
-    chunk id -> (k, m, chunk length, block)). Not the master's count,
+    chunk id -> (goal, chunk length, block)). Not the master's count,
     which takes a part at its nominal size, and not the part files'
     sizes, which the chunkserver writes out to that size whatever the
     chunk holds. A record of a chunk no live file holds counts nothing."""
     total = found = 0
     for chunk_id, part in parts:
         if chunk_id in chunks:
-            k, m, length, block = chunks[chunk_id]
-            total += layout.part_lengths(k, m, length, block)[part]
+            goal, length, block = chunks[chunk_id]
+            total += layout.goal_part_lengths(goal, length, block)[part]
             found += 1
     return total, found
 

@@ -3,12 +3,17 @@ it (upstream LizardFS: src/common/slice_traits.h, chunk_part_type.h,
 src/chunkserver/chunk.h), written out for the benchmark's comparison.
 
   * a file is cut into chunks of ``chunk_bytes`` (64 MiB);
-  * a chunk's 64 KiB blocks go round-robin over the k data parts: block
-    i lies in data part i % k at slot i // k; the m parity parts are the
-    Reed-Solomon parity of the k part streams, each padded with zeros
-    to whole blocks;
+  * $ec(k,m): a chunk's 64 KiB blocks go round-robin over the k data
+    parts: block i lies in data part i % k at slot i // k; the m parity
+    parts k..k+m-1 are the Reed-Solomon parity of the k part streams,
+    each padded with zeros to whole blocks;
+  * xorN: the same round-robin over N data parts, which are parts
+    1..N (block i in part 1 + i % N); part 0 is their XOR, block by
+    block over the zero-padded streams;
+  * N copies: N part files of the one id, each the chunk's bytes whole;
   * part p of slice type t has the id t * 64 + p, with
-    t = 10 + 32 * (k - 2) + (m - 1) for ec(k, m);
+    t = 10 + 32 * (k - 2) + (m - 1) for ec(k, m), t = N for xorN and
+    t = 0 (standard) for a copy, whose one part is part 0;
   * a part is one file ``chunk_<id:016X>_P<part:08X>_<version:08X>.liz``:
     a 1 KiB signature, a 4 KiB table of big-endian CRC32 words, one per
     64 KiB block, then the blocks.
@@ -27,10 +32,28 @@ import numpy as np
 from . import gf256
 
 HEADER_BYTES = 1024 + 4096
+COPY_PART_ID = 0
 
 
 def ec_part_id(k: int, m: int, part: int) -> int:
     return (10 + 32 * (k - 2) + (m - 1)) * 64 + part
+
+
+def xor_part_id(n: int, part: int) -> int:
+    return n * 64 + part
+
+
+def part_ids(goal: dict) -> list[int]:
+    """The id of every part file a chunk keeps under a configuration's
+    goal (``{"k", "m"}``, ``{"xor": N}`` or ``{"copies": N}``), one a
+    file: N copies are N files of the one id."""
+    if "copies" in goal:
+        return [COPY_PART_ID] * int(goal["copies"])
+    if "xor" in goal:
+        return [xor_part_id(int(goal["xor"]), p)
+                for p in range(int(goal["xor"]) + 1)]
+    k, m = int(goal["k"]), int(goal["m"])
+    return [ec_part_id(k, m, p) for p in range(k + m)]
 
 
 def chunk_spans(length: int, chunk_bytes: int) -> list[tuple[int, int]]:
@@ -53,16 +76,48 @@ def part_lengths(k: int, m: int, chunk_len: int, block: int) -> list[int]:
     return out + [max(out)] * m
 
 
+def goal_part_lengths(goal: dict, chunk_len: int, block: int) -> list[int]:
+    """Live bytes of each part of one chunk under the goal, by part
+    index: a xor parity part is as long as the longest data part, a
+    copy holds the chunk whole."""
+    if "copies" in goal:
+        return [chunk_len]
+    if "xor" in goal:
+        data = part_lengths(int(goal["xor"]), 1, chunk_len, block)[:-1]
+        return [max(data)] + data
+    return part_lengths(int(goal["k"]), int(goal["m"]), chunk_len, block)
+
+
+def data_streams(data: np.ndarray, d: int, block: int) -> list[np.ndarray]:
+    """The d data part streams of one chunk, padded to whole blocks."""
+    nblocks = -(-len(data) // block)
+    slots = -(-nblocks // d)
+    grid = np.zeros(slots * d * block, dtype=np.uint8)
+    grid[:len(data)] = data
+    grid = grid.reshape(slots, d, block)
+    return [np.ascontiguousarray(grid[:, p, :]).reshape(-1) for p in range(d)]
+
+
 def expected_parts(data: np.ndarray, k: int, m: int,
                    block: int) -> list[np.ndarray]:
     """The k + m part streams of one chunk, padded to whole blocks."""
-    nblocks = -(-len(data) // block)
-    slots = -(-nblocks // k)
-    grid = np.zeros(slots * k * block, dtype=np.uint8)
-    grid[:len(data)] = data
-    grid = grid.reshape(slots, k, block)
-    parts = [np.ascontiguousarray(grid[:, p, :]).reshape(-1) for p in range(k)]
+    parts = data_streams(data, k, block)
     return parts + gf256.encode(k, m, parts)
+
+
+def xor_parts(data: np.ndarray, n: int, block: int) -> list[np.ndarray]:
+    """The N + 1 part streams of one xorN chunk: the parity first."""
+    parts = data_streams(data, n, block)
+    return [np.bitwise_xor.reduce(np.stack(parts))] + parts
+
+
+def goal_parts(goal: dict, data: np.ndarray, block: int) -> list[np.ndarray]:
+    """Every part stream of one chunk under the goal, by part index."""
+    if "copies" in goal:
+        return data_streams(data, 1, block)
+    if "xor" in goal:
+        return xor_parts(data, int(goal["xor"]), block)
+    return expected_parts(data, int(goal["k"]), int(goal["m"]), block)
 
 
 def block_crcs(stream: np.ndarray, block: int) -> list[int]:

@@ -16,7 +16,8 @@ Arguments of the benchmark's own, never passed by the driver:
                    device encoder in interpret mode; prints
                    "REHEARSAL (cpu) — not a chip result", never a result
   --control NAME   puts a broken guarantee in the encoder's place, to
-                   show that ``correct`` comes out false (tap.py)
+                   show that ``correct`` comes out false (tap.py), or
+                   in the client's (copy-flip: worker.py)
   --fault NAME     tests only: breaks the timed path underneath
   --keep-trace DIR keeps a traced run's extracted events as JSON
   --manifest PATH  another BENCHMARK.json (the throw-away cell of the

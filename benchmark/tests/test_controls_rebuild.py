@@ -1,7 +1,8 @@
 """``ec84-rebuild-under-write``'s comparison and its wait have to fail
 what they should: the same whole toy runs as ``test_controls.py`` (by
 hand, each starts daemons), under the cell's rehearsal: 2 writers,
-files of 5 MiB, a chunkserver SIGKILLed a third into a 3 s window.
+files of 5 MiB, a chunkserver SIGKILLed once 64 MiB are written (the
+mix's rehearsal `at_bytes`), inside a 3 s window.
 
     python -m pytest benchmark/tests/test_controls_rebuild.py -q
 """

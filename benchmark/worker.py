@@ -40,6 +40,8 @@ from tap import EncoderTap  # noqa: E402
 
 FAULTS_CLIENT = ("write-noop", "write-half", "read-flip")
 FAULT_REBUILT = "rebuilt-flip"
+# the control of a copy goal, in the client (tap.py's are the encoder's)
+CONTROL_CLIENT = "copy-flip"
 
 
 T_START = time.time()
@@ -75,8 +77,13 @@ class CompileCounter:
 
 
 def break_client(client, fault: str) -> None:
-    """Tests only: the timed path broken underneath the harness."""
+    """The timed path broken underneath the harness: a fault (tests
+    only), or the control ``copy-flip``, which breaks a copy goal's
+    guarantee of identical copies: where a write goes to two or more
+    copies (a relay chain), the last copy is sent apart with one byte
+    altered."""
     pwrite, read_file = client.pwrite, client.read_file
+    write_part = client._write_part
     if fault == "write-noop":
         async def noop(inode, offset, data):
             return None
@@ -92,6 +99,19 @@ def break_client(client, fault: str) -> None:
                 got[len(got) // 2] ^= 1
             return bytes(got)
         client.read_file = flip
+    elif fault == CONTROL_CLIENT:
+        async def one_copy_off(chunk_id, version, locs, payload, length,
+                               *a, **kw):
+            if len(locs) < 2 or length <= 0:
+                return await write_part(chunk_id, version, locs, payload,
+                                        length, *a, **kw)
+            await write_part(chunk_id, version, locs[:-1], payload, length,
+                             *a, **kw)
+            off = np.array(payload[:length], dtype=np.uint8)
+            off[length // 2] ^= 1
+            return await write_part(chunk_id, version, locs[-1:], off,
+                                    length, *a, **kw)
+        client._write_part = one_copy_off
 
 
 def flip_rebuilt(cluster, parts: set) -> int:
@@ -127,7 +147,8 @@ def untraced_spans(counts, span_device_s: dict) -> list[str]:
     boundary later (the comparison's read-back decoding a slow part)
     is in no trace and is no fault of the run."""
     return [span for span, calls in (("bench.encode", counts.encode_calls),
-                                     ("bench.recover", counts.recover_calls))
+                                     ("bench.recover", counts.recover_calls),
+                                     ("bench.xor", counts.xor_calls))
             if calls and not span_device_s.get(span)]
 
 
@@ -264,9 +285,10 @@ async def run_cell(args, cell, enc, tap, counter, annotate, t0_epoch) -> int:
                 raise SystemExit("a client did not take the device encoder")
             clients.append(c)
         checker = clients.pop()
-        if args.fault in FAULTS_CLIENT:
+        for broken in {args.fault, args.control} & {*FAULTS_CLIENT,
+                                                     CONTROL_CLIENT}:
             for c in clients:
-                break_client(c, args.fault)
+                break_client(c, broken)
         goals = {g["name"]: g for g in cfg["goals"]}
         dirs = []
         for entry in cfg["directories"]:
@@ -350,12 +372,15 @@ async def run_cell(args, cell, enc, tap, counter, annotate, t0_epoch) -> int:
             by_class[o.cls] = by_class.get(o.cls, 0) + 1
         say(f"window {t_close - t_open:.3f}s: ops by class {by_class}; last "
             f"op under way ended {max(t_done - t_close, 0):.3f}s after the "
-            f"close; {len(counts.encode_calls)} encode and "
-            f"{len(counts.recover_calls)} recover calls on {dev}; bytes on "
+            f"close; {len(counts.encode_calls)} encode, "
+            f"{len(counts.recover_calls)} recover and "
+            f"{len(counts.xor_calls)} xor calls on {dev}; bytes on "
             f"disk at the close {disk_peak}; rebuilds completed inside the "
             f"window {rebuilds1 - rebuilds0}; retained for the comparison "
             f"{len(traffic.retained)} answers ({traffic.retained_bytes} B, "
             f"{sum(1 for r in traffic.retained if r.degraded)} degraded)")
+        for note in traffic.notes:
+            say(note)
         for cls in sorted(by_class):
             lat = sorted((o.end - o.start) * 1e3 for o in ops
                          if o.cls == cls and o.ok)

@@ -2,6 +2,7 @@
 pure function of the seed."""
 
 import asyncio
+import contextlib
 import glob
 import json
 import os
@@ -16,11 +17,13 @@ import checks
 import generator
 import manifest
 import redundancy
+import rooflines
 import worker
 from reference.fsmodel import Model, make_pool
-from tap import EncoderTap
+from tap import EncoderTap, TapCounts
 
 M = manifest.load_manifest()
+_lib = manifest.load_module("layers", "_lib.py")
 MIXES = sorted({w["traffic"] for w in M["workloads"]})
 SEEDS = (0, 7, 2147483659, 3000000001)
 
@@ -202,7 +205,15 @@ def test_the_mix_is_stream_write_with_one_event():
     plain = manifest.Cell(M, "ec84-stream-write").mix
     for key in ("loop", "sessions", "steps", "sizes", "transfer_bytes"):
         assert kill[key] == plain[key], key
-    assert kill["events"] == [{"at_share": 0.45, "fault": "kill_seeded"}]
+    # the kill fires on the bytes written, not on the clock: the parts
+    # lost then do not hang on the writers' rate (half way through a
+    # chunk of each of the four writers), with a fallback inside the window
+    event, = kill["events"]
+    assert event["fault"] == "kill_seeded" and "at_share" not in event
+    assert event["at_bytes"] == 42 * 64 * 2**20
+    assert event["at_bytes"] // int(kill["sessions"]) % (64 * 2**20) == \
+        32 * 2**20
+    assert 0.5 <= event["by_share"] <= 0.6
     assert "faults" not in kill, "no throttle: the master's defaults"
     assert kill["redundancy_cap_s"] == 120
     assert kill["check"] == dict(plain["check"], rebuilt_chunks=4)
@@ -292,6 +303,80 @@ def test_an_event_that_fails_fails_the_run():
         asyncio.run(traffic_of([(0.1, Fault())]).run(0.3))
 
 
+class Write(Nap):
+    """A verb that writes 1 MiB, acknowledged after 10 ms, timed."""
+
+    async def do(self, t, s, st, arg, warm):
+        await t.timed("write", 1 << 20, asyncio.sleep(0.01))
+
+
+def byte_traffic(at_bytes, by_share, fired, verb=None):
+    class Fault:
+        async def apply(self, t):
+            fired.append((time.monotonic(), t.written))
+
+    t = traffic_of([])
+    t.verbs["nap"] = verb or Write()
+    t.byte_events = [(at_bytes, by_share, "spy", Fault())]
+    return t
+
+
+def test_an_event_at_bytes_fires_once_the_writes_reach_them():
+    fired = []
+    t = byte_traffic(12 << 20, 0.9, fired)
+    t_open, _ = asyncio.run(t.run(0.5))
+    (at, written), = fired
+    # two sessions acknowledge 1 MiB every 10 ms each: 12 MiB by some
+    # 60 ms, the event on the write that reaches them, not on a clock
+    assert 12 << 20 <= written < 13 << 20
+    assert at - t_open < 0.3
+    assert t.written > written, "the sessions ran on beside the event"
+    note, = t.notes
+    assert note.startswith("event spy: at_bytes 12582912 reached, ")
+
+
+def test_an_event_at_bytes_falls_back_on_its_share_and_says_so():
+    fired = []
+    t = byte_traffic(1 << 40, 0.5, fired)
+    t_open, _ = asyncio.run(t.run(0.4))
+    (at, written), = fired
+    assert at - t_open == pytest.approx(0.2, abs=0.08)
+    assert 0 < written < 1 << 40
+    note, = t.notes
+    assert "at_bytes not reached" in note and "fired on by_share" in note
+
+
+def test_only_acknowledged_writes_of_the_window_count_towards_at_bytes():
+    class Mixed(Nap):
+        async def do(self, t, s, st, arg, warm):
+            await t.timed("read", 1 << 20, asyncio.sleep(0.01))
+            with contextlib.suppress(OSError):
+                await t.timed("write", 1 << 20, failing())
+
+    async def failing():
+        await asyncio.sleep(0.005)
+        raise OSError("lost")
+
+    fired = []
+    t = byte_traffic(1 << 20, 0.5, fired, Mixed())
+    asyncio.run(t.setup())               # the warm-up run is no window
+    t_open, _ = asyncio.run(t.run(0.2))
+    (at, written), = fired
+    assert written == t.written == 0 and at - t_open >= 0.09
+    assert "at_bytes not reached" in t.notes[0]
+
+
+def test_a_mix_names_an_event_by_its_share_or_by_its_bytes():
+    mix = {"sessions": 1, "steps": [], "check": {}, "events": [
+        {"at_share": 0.25, "fault": "kill_seeded"},
+        {"at_bytes": 4096, "by_share": 0.5, "fault": "kill_seeded"}]}
+    t = generator.Traffic(mix, 1, [object()], [], None, 1 << 26)
+    (share, fault), = t.events
+    (nbytes, by, name, fault2), = t.byte_events
+    assert (share, nbytes, by, name) == (0.25, 4096, 0.5, "kill_seeded")
+    assert fault.__file__ == fault2.__file__ and callable(fault2.apply)
+
+
 # -- the wait for full redundancy ----------------------------------------
 
 def status(completed=0, failed=0, nbytes=0, active=(), recent=(),
@@ -378,9 +463,10 @@ def test_the_numerator_is_the_harness_own_reckoning():
     harness's model: not the master's count, which takes a part at its
     nominal 8 MiB whatever the chunk held."""
     full, block = 64 << 20, 65536
-    chunks = {0x101: (8, 4, full, block),
-              0x202: (8, 4, 5 << 20, block),      # a chunk cut short
-              0x404: (3, 2, 3901, block)}
+    ec84, ec32 = {"k": 8, "m": 4}, {"k": 3, "m": 2}
+    chunks = {0x101: (ec84, full, block),
+              0x202: (ec84, 5 << 20, block),      # a chunk cut short
+              0x404: (ec32, 3901, block)}
     parts = {(0x101, 4), (0x101, 11), (0x202, 0), (0x202, 9), (0x404, 1),
              (0x303, 0)}                          # of no live file: nothing
     got = redundancy.rebuilt_live_bytes(parts, chunks)
@@ -404,8 +490,9 @@ def test_chunk_table_asks_the_master_for_the_id_alone():
         model=types.SimpleNamespace(live=lambda: files), uncertain={"c"},
         dirs=[types.SimpleNamespace(goal={"k": 8, "m": 4})])
     cfg = {"block_bytes": 65536, "chunk_bytes": 64 << 20}
+    goal = {"k": 8, "m": 4}
     assert asyncio.run(checks.chunk_table(t, Client(), cfg)) == {
-        11 * 16: (8, 4, 64 << 20, 65536), 11 * 16 + 1: (8, 4, 5, 65536)}
+        11 * 16: (goal, 64 << 20, 65536), 11 * 16 + 1: (goal, 5, 65536)}
 
 
 def test_the_wait_is_selected_by_the_mixs_cap_and_by_nothing_else():
@@ -604,6 +691,12 @@ class FakeEncoder:
         length = len(next(iter(parts.values())))
         return {w: np.zeros(length, np.uint8) for w in wanted}
 
+    def xor_parity(self, parts):
+        return np.bitwise_xor.reduce(np.stack(parts))
+
+    def xor_parity_into(self, parts, out):
+        out[...] = self.xor_parity(parts)
+
 
 def test_a_recover_after_the_stop_is_seen_by_no_verdict_and_no_roofline():
     tap = EncoderTap(FakeEncoder())
@@ -633,6 +726,69 @@ def test_a_recover_after_the_stop_is_seen_by_no_verdict_and_no_roofline():
     assert manifest.load_reader("recover_boundary_MBps")(ctx) is None
     assert manifest.load_reader("recover_boundary_MBps")(late) is not None
     tap.remove()
+
+
+def test_the_tap_counts_xor_calls_once_under_their_span():
+    class Nested(FakeEncoder):
+        def xor_parity_into(self, parts, out):
+            out[...] = self.xor_parity(parts)   # the tap's, on this thread
+
+    seen = []
+
+    @contextlib.contextmanager
+    def annotate(name):
+        seen.append(name)
+        yield
+
+    for enc in (FakeEncoder(), Nested()):
+        seen.clear()
+        tap = EncoderTap(enc, annotate)
+        parts = [np.full(65536, i, np.uint8) for i in (1, 2, 4)]
+        assert (tap.enc.xor_parity(parts) == 7).all()
+        out = np.zeros(65536, np.uint8)
+        tap.enc.xor_parity_into(parts, out)
+        assert (out == 7).all()
+        counts = tap.snapshot()
+        assert [c[:2] for c in counts.xor_calls] == [(3, 65536)] * 2
+        assert seen == ["bench.xor"] * 2
+        assert counts.encode_calls == counts.recover_calls == ()
+        tap.remove()
+        assert "xor_parity" not in vars(enc)
+
+
+def test_parity_short_stores_a_xor_goals_parity_as_zeros():
+    tap = EncoderTap(FakeEncoder(), control="parity-short")
+    parts = [np.full(4096, i, np.uint8) for i in (1, 2)]
+    assert not tap.enc.xor_parity(parts).any()
+    out = np.ones(4096, np.uint8)
+    tap.enc.xor_parity_into(parts, out)
+    assert not out.any() and len(tap.xor_calls) == 2
+    tap.remove()
+
+
+def test_xor_calls_with_no_device_time_are_refused_and_read_bytes_bound():
+    tap = EncoderTap(FakeEncoder())
+    parts = [np.zeros(1 << 20, np.uint8)] * 3
+    for _ in range(4):
+        tap.enc.xor_parity(parts)
+    counts = tap.snapshot()
+    tap.remove()
+    # the windowed write's xor_parity_into stays on the host today: its
+    # calls under bench.xor with no device time refuse a traced run
+    assert worker.untraced_spans(counts, {"bench.encode": 1e-3}) == [
+        "bench.xor"]
+    assert worker.untraced_spans(counts, {"bench.xor": 1e-4}) == []
+    peaks = manifest.peaks_for("TPU v5 lite")
+    ctx = {"tap": counts, "trace": {"span_device_s": {"bench.xor": 1e-4}},
+           "peaks": peaks}
+    # 3 parts read and one written, 1 MiB each, four calls, at 819 GB/s
+    least = 4 * 4 * (1 << 20) / peaks["hbm_bytes_per_s"]
+    assert _lib.xor_roofline(ctx) == pytest.approx(100 * least / 1e-4)
+    assert rooflines.least_seconds(*rooflines.xor_cost(3, 1 << 20),
+                                   peaks)[1] == "bytes"
+    assert _lib.xor_roofline(dict(ctx, trace=None)) is None
+    assert _lib.xor_roofline(dict(ctx, peaks=None)) is None
+    assert _lib.xor_roofline(dict(ctx, tap=TapCounts((), ()))) is None
 
 
 # -- the decode a slow part would force is warmed in set-up ---------------

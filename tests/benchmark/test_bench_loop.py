@@ -110,11 +110,31 @@ def test_an_entry_names_cells_that_report_what_it_moves(name):
         assert name in {m["name"] for m in cell.per_layer}
 
 
+def in_order(names: list, entries: list) -> bool:
+    """Every name among the entries, in that order, anywhere: later
+    entries go at the end of ``per_layer``, after these."""
+    it = iter(m["name"] for m in entries)
+    return all(name in it for name in names)
+
+
 def test_the_new_entries_are_the_last_fifteen_and_the_rebuild_cell_has_none():
-    assert [m["name"] for m in M["per_layer"][-15:]] == NAMES
+    # in their order, and no longer last: later entries follow them
+    assert in_order(NAMES, M["per_layer"])
     rebuild = manifest.Cell(M, "ec84-rebuild-under-write")
     assert not [m["name"] for m in rebuild.per_layer
                 if m["layer"] == "client loop and GIL"]
+
+
+@pytest.mark.parametrize("appended", [1, 2])
+def test_an_entry_appended_after_the_fifteen_keeps_them_in_order(appended):
+    later = [{"name": f"later_{i}.ops", "layer": "client write path"}
+             for i in range(appended)]
+    assert in_order(NAMES, M["per_layer"] + later)
+    assert in_order(NAMES, later + M["per_layer"])
+    # the order is held: the fifteen turned round are not found in it
+    assert not in_order(NAMES[1:] + NAMES[:1], M["per_layer"] + later)
+    assert not in_order(NAMES, [m for m in M["per_layer"] + later
+                                if m["name"] != NAMES[7]])
 
 
 @pytest.mark.asyncio

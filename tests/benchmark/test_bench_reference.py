@@ -85,3 +85,50 @@ def test_striping_round_trips(length):
 def test_chunk_spans():
     assert layout.chunk_spans(10, 4) == [(0, 4), (4, 8), (8, 10)]
     assert layout.chunk_spans(0, 4) == []
+
+
+# -- xor and copy goals, worked by hand at a block of 4 bytes -------------
+
+def test_xor_and_copy_part_ids_as_upstream_packs_them():
+    # goal.h: xor2..xor9 are slice types 2..9, a standard copy type 0;
+    # chunk_part_type.h: type * 64 + part; xor parity is part 0
+    assert layout.xor_part_id(3, 0) == 192 and layout.xor_part_id(2, 2) == 130
+    assert layout.part_ids({"xor": 3}) == [192, 193, 194, 195]
+    assert layout.part_ids({"copies": 2}) == [0, 0]
+    assert layout.part_ids({"k": 3, "m": 2}) == [
+        layout.ec_part_id(3, 2, p) for p in range(5)]
+
+
+def test_xor_parity_and_part_lengths_by_hand():
+    data = np.arange(1, 15, dtype=np.uint8)          # 14 bytes, 4 blocks
+    b = [data[0:4], data[4:8], data[8:12], np.array([13, 14, 0, 0], np.uint8)]
+    parity, one, two = layout.goal_parts({"xor": 2}, data, 4)
+    # block i of the chunk in data part 1 + i % 2
+    assert one.tolist() == b[0].tolist() + b[2].tolist()
+    assert two.tolist() == b[1].tolist() + b[3].tolist()
+    assert parity.tolist() == (b[0] ^ b[1]).tolist() + (b[2] ^ b[3]).tolist()
+    assert parity.tolist() == [4, 4, 4, 12, 4, 4, 11, 12]
+    # a parity part is as long as the longest data part
+    assert layout.goal_part_lengths({"xor": 2}, 14, 4) == [8, 8, 6]
+    assert layout.goal_part_lengths({"xor": 3}, 10, 4) == [4, 4, 4, 2]
+    assert layout.goal_part_lengths({"xor": 3}, 3, 4) == [3, 3, 0, 0]
+
+
+def test_a_copy_is_the_chunk_whole_with_its_crcs():
+    data = np.arange(10, dtype=np.uint8)
+    copy, = layout.goal_parts({"copies": 3}, data, 4)
+    assert copy.tolist() == list(range(10)) + [0, 0]
+    assert layout.goal_part_lengths({"copies": 3}, 10, 4) == [10]
+    assert layout.block_crcs(copy, 4) == [
+        zlib.crc32(bytes([0, 1, 2, 3])), zlib.crc32(bytes([4, 5, 6, 7])),
+        zlib.crc32(bytes([8, 9, 0, 0]))]
+
+
+@pytest.mark.parametrize("n", [2, 3, 9])
+def test_xor_parity_recovers_any_one_part(n):
+    data = np.random.default_rng(n).integers(0, 256, 7 * 65536 + 5, np.uint8)
+    parts = layout.goal_parts({"xor": n}, data, 65536)
+    for lost in range(n + 1):
+        rest = [p for i, p in enumerate(parts) if i != lost]
+        assert np.array_equal(np.bitwise_xor.reduce(np.stack(rest)),
+                              parts[lost])
