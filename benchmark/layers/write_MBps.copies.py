@@ -1,0 +1,5 @@
+from _goals import family_mbps
+
+
+def read(ctx):
+    return family_mbps(ctx, "copies")

@@ -120,8 +120,8 @@ class ChunkServer(Daemon):
         self.session_ops = accounting.SessionOps(
             self.metrics, "chunkserver", max_sessions=16
         )
-        # per-chunk heat accumulator between heartbeats: chunk_id ->
-        # [ops, bytes]. The top slice folds into heartbeat heat_json
+        # per-chunk read heat accumulator between heartbeats: chunk_id
+        # -> [ops, bytes] (_heat_charge). The top slice folds into heartbeat heat_json
         # (master/heat.py heavy-hitter sketch); bounded so a scan over
         # millions of chunks can't balloon the daemon — once full, new
         # (cold) chunks are dropped and the hot set keeps charging
@@ -632,10 +632,11 @@ class ChunkServer(Daemon):
                 max(op["t1"] - op["t0"], 0.0),
                 nbytes=op["bytes"], trace_id=op["trace_id"],
             )
-            # native-plane ops heat the same per-chunk accumulator the
+            # native-plane reads heat the same per-chunk accumulator the
             # asyncio handlers charge — the master's heat map must not
             # go blind when the C++ data plane serves the bytes
-            self._heat_charge(op["chunk_id"], op["bytes"])
+            if op_class == "read":
+                self._heat_charge(op["chunk_id"], op["bytes"])
 
     def trace_spans(self, trace_id: int | None = None) -> list[dict]:
         # pull whatever the native plane recorded since the last
@@ -675,9 +676,12 @@ class ChunkServer(Daemon):
     # --- per-chunk heat fold (master/heat.py input) -------------------------
 
     def _heat_charge(self, chunk_id: int, nbytes: int) -> None:
-        """Charge one data-plane op against the chunk's heat row. Cheap
-        enough for every read/write; gated so LZ_HEAT=off costs one
-        env read and nothing else."""
+        """Charge one data-plane read against the chunk's heat row.
+        Reads alone: the heat buys a hot chunk more copies to read
+        from, and a chunk being written gains nothing from them (each
+        write then goes to every copy, and the copies are made while
+        its bytes still change). Cheap enough for every read; gated so
+        LZ_HEAT=off costs one env read and nothing else."""
         if not constants_mod.heat_enabled():
             return
         cell = self._heat.get(chunk_id)
@@ -1318,7 +1322,6 @@ class ChunkServer(Daemon):
             session.session_id or "unattributed", "write", dt,
             nbytes=msg.length, trace_id=session.trace_id,
         )
-        self._heat_charge(msg.chunk_id, msg.length)
         await ack(code)
 
     @staticmethod
@@ -1871,7 +1874,6 @@ class ChunkServer(Daemon):
             session.session_id or "unattributed", "write", dt,
             nbytes=len(msg.data), trace_id=session.trace_id,
         )
-        self._heat_charge(msg.chunk_id, len(msg.data))
         await ack(code)
 
     def _local_write(self, session: _WriteSession, msg: m.CltocsWriteData) -> None:

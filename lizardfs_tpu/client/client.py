@@ -449,6 +449,25 @@ class Client:
         self.write_phases.count(name, n)
         self._count_op(name, n)
 
+    def _count_acked(self, locations, nbytes: int) -> None:
+        """A chunk's share of a ``pwrite`` or ``write_file`` that its
+        chunkservers acknowledged: its bytes under the goal's family
+        (``copies_payload_bytes``, ``xor_payload_bytes``,
+        ``ec_payload_bytes``) and, in ``chain_parts``, its parts that
+        went through a relay chain (two holders or more: the head
+        forwards to the rest)."""
+        parts = [geometry.ChunkPartType.from_id(loc.part_id)
+                 for loc in locations]
+        kind = parts[0].type
+        family = "xor" if kind.is_xor else "ec" if kind.is_ec else "copies"
+        self._count_write(family + "_payload_bytes", nbytes)
+        holders: dict[int, int] = {}
+        for p in parts:
+            holders[p.part] = holders.get(p.part, 0) + 1
+        chained = sum(1 for n in holders.values() if n > 1)
+        if chained:
+            self._count_write("chain_parts", chained)
+
     def _count_read(self, name: str, n: int = 1) -> None:
         """One of the read path's counts (READ_COUNTS), kept as
         :meth:`_count_write` keeps the write path's."""
@@ -1637,6 +1656,7 @@ class Client:
                 await self._rmw_striped(grant, slice_type, copies, ci, coff,
                                         piece, grant.file_length, rmw_cache)
             status_code = st.OK
+            self._count_acked(grant.locations, len(piece))
         finally:
             with tracing.span("commit", phase="commit", bucket="net"):
                 await self._call(
@@ -1892,6 +1912,7 @@ class Client:
         try:
             await self._push_chunk_parts(grant, chunk_data)
             status_code = st.OK
+            self._count_acked(grant.locations, len(chunk_data))
         finally:
             if status_code == st.OK:
                 # commit coalescing: queue the end record; the window's

@@ -133,6 +133,16 @@ class CpuChunkEncoder(ChunkEncoder):
         return parity_arr, data_crcs, parity_crcs
 
 
+# The phase rows of a call across the device boundary, the boundary's
+# own and its four legs' (runtime.metrics: _BOUNDARY_PHASES): a GF(2^8)
+# product's (encode, recover) and xor parity's, apart, so that a xor
+# call moves no reader of the products' rows. The spans keep their
+# names either way (``_LEG_SPANS``; the boundary's says ``op``).
+_LEG_SPANS = ("dev_stage", "dev_put", "dev_run", "dev_fetch")
+_RS_ROWS = ("boundary",) + _LEG_SPANS
+_XOR_ROWS = tuple("xor_" + row for row in _RS_ROWS)
+
+
 def _tpu_allow_cpu() -> bool:
     """LZ_TPU_ALLOW_CPU escape hatch (default OFF). Routed through the
     one spelling-parity accessor: the old bare-truthiness read meant
@@ -205,40 +215,60 @@ class TpuChunkEncoder(ChunkEncoder):
     def _put(self, arr: np.ndarray):
         return self._jax.device_put(np.ascontiguousarray(arr), self._device)
 
+    def _across(self, phases: tuple, stage, run, out=None,
+                **attrs) -> np.ndarray:
+        """One call across the boundary on the device: a ``boundary``
+        span holding four, ``dev_stage`` (``stage()`` on the host: the
+        operands as host arrays, the parts stacked last), ``dev_put``
+        (each ``device_put``, until it returns), ``dev_run`` (``run``
+        on the device operands, until it returns) and ``dev_fetch``
+        (``np.asarray``, which blocks until upload, kernel and download
+        are done; into ``out`` where the caller gives a buffer). No
+        synchronisation is added: the device trace under ``dev_fetch``
+        gives the kernel, the rest of it is transfer and wake-up.
+        ``phases`` names the rows the five are charged to (``_RS_ROWS``
+        or ``_XOR_ROWS``), so that one kind of call never moves the
+        other's."""
+        boundary, *legs = phases
+
+        def leg(i: int):
+            return tracing.span(_LEG_SPANS[i], layer="encoder",
+                                phase=legs[i], bucket="compute")
+
+        with tracing.span("boundary", layer="encoder", phase=boundary,
+                          bucket="compute", **attrs) as sp:
+            with leg(0):
+                operands = stage()
+                sp.attrs["rows"], sp.attrs["bytes"] = operands[-1].shape
+            with leg(1):
+                operands = [self._put(a) for a in operands]
+            with leg(2):
+                res = run(*operands)
+            with leg(3):
+                if out is None:
+                    return np.asarray(res)
+                np.copyto(out, np.asarray(res))
+                return out
+
     def _apply_gf(self, op: str, k: int, m: int, matrix, rows) -> np.ndarray:
         """One GF(2^8) product across the boundary: ``matrix()`` times
         ``rows`` (the input parts in the matrix's column order, None =
-        all zeros, whose columns are dropped), as a ``boundary`` span
-        holding four: ``dev_stage`` (the bit matrix and ``np.stack`` on
-        the host), ``dev_put`` (the two ``device_put`` calls, until
-        they return), ``dev_run`` (``apply_gf``, until it returns) and
-        ``dev_fetch`` (``np.asarray``, which blocks until upload,
-        kernel and download are done). No synchronisation is added:
-        the device trace under ``dev_fetch`` gives the kernel, the rest
-        of it is transfer and wake-up."""
+        all zeros, whose columns are dropped); ``dev_stage`` builds the
+        bit matrix and stacks the parts, ``dev_put`` puts both,
+        ``dev_run`` is ``apply_gf`` (:meth:`_across`)."""
         live = [j for j, r in enumerate(rows) if r is not None]
         if not live:
             raise ValueError("at least one input part must be non-None")
 
-        def leg(name: str):
-            return tracing.span(name, layer="encoder", phase=name,
-                                bucket="compute")
+        def stage():
+            bigm = matrix()
+            if len(live) < len(rows):
+                bigm = bigm[:, np.concatenate(
+                    [np.arange(8 * j, 8 * j + 8) for j in live])]
+            return bigm, np.stack([np.asarray(rows[j]) for j in live])
 
-        with tracing.span("boundary", layer="encoder", phase="boundary",
-                          bucket="compute", op=op, k=k, m=m) as sp:
-            with leg("dev_stage"):
-                bigm = matrix()
-                if len(live) < len(rows):
-                    bigm = bigm[:, np.concatenate(
-                        [np.arange(8 * j, 8 * j + 8) for j in live])]
-                stacked = np.stack([np.asarray(rows[j]) for j in live])
-                sp.attrs["rows"], sp.attrs["bytes"] = stacked.shape
-            with leg("dev_put"):
-                operands = self._put(bigm), self._put(stacked)
-            with leg("dev_run"):
-                out = self._ops.apply_gf(*operands)
-            with leg("dev_fetch"):
-                return np.asarray(out)
+        return self._across(_RS_ROWS, stage, self._ops.apply_gf,
+                            op=op, k=k, m=m)
 
     def encode(self, k, m, data_parts):
         if len(data_parts) != k:
@@ -271,9 +301,20 @@ class TpuChunkEncoder(ChunkEncoder):
             )
         ).astype(np.uint32)
 
+    def _xor(self, parts, out=None) -> np.ndarray:
+        """The XOR of xorN's parts across the boundary, through the
+        staging ``_apply_gf`` takes: the parts stacked on the host,
+        put, ``xor_reduce`` (a plain XOR needs no bit planes) and the
+        fetch, charged to the ``xor_`` rows."""
+        return self._across(
+            _XOR_ROWS, lambda: (np.stack([np.asarray(p) for p in parts]),),
+            self._ops.xor_reduce, out, op="xor", k=len(parts), m=1)
+
     def xor_parity(self, parts):
-        stacked = np.stack([np.asarray(p) for p in parts])
-        return np.asarray(self._ops.xor_reduce(self._put(stacked)))
+        return self._xor(parts)
+
+    def xor_parity_into(self, parts, out):
+        self._xor(parts, out)
 
     def encode_with_checksums(self, k, m, data, block_size=MFSBLOCKSIZE):
         from lizardfs_tpu.ops import pallas_ec

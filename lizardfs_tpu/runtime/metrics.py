@@ -184,6 +184,11 @@ _BOUNDARY_PHASES = {
     "dev_put": "boundary",      # the two device_put calls, until they return
     "dev_run": "boundary",      # apply_gf, until it returns
     "dev_fetch": "boundary",    # np.asarray: upload, kernel, download, wake-up
+    # xor parity's call (xor2..xor9 goals) and its four legs, as above,
+    # under rows of their own
+    "xor_boundary": None, "xor_dev_stage": "xor_boundary",
+    "xor_dev_put": "xor_boundary", "xor_dev_run": "xor_boundary",
+    "xor_dev_fetch": "xor_boundary",
 }
 WRITE_PHASES = {
     # write_file's copy of its argument, before the first RPC
@@ -221,12 +226,16 @@ WRITE_PHASES = {
 # socket copy, the window's live depth summed at each segment (its
 # mean is window_depth_sum / window_segments), and the window's trips
 # to a worker thread (one a segment, one more for each reap a full
-# ring or a shut credit gate makes on its own).
+# ring or a shut credit gate makes on its own). Where a pwrite's or a
+# write_file's chunk is acknowledged (Client._count_acked): its bytes
+# by the goal's family, and its parts written through a relay chain.
 WRITE_COUNTS = ("rmw_reads", "rmw_read_bytes", "rmw_region_bytes",
                 "payload_bytes",
                 "window_chunks", "fallback_chunks", "window_segments",
                 "window_credit_waits", "ring_parts", "socket_parts",
                 "window_depth_sum", "window_trips",
+                "copies_payload_bytes", "xor_payload_bytes",
+                "ec_payload_bytes", "chain_parts",
                 # unlink calls (their rows: CallRows, below)
                 "unlinks")
 READ_PHASES = {

@@ -180,6 +180,48 @@ async def test_hot_chunk_boost_and_demote_live(tmp_path):
         await cluster.stop()
 
 
+@pytest.mark.parametrize("native", [True, False])
+async def test_a_chunk_being_written_earns_no_heat_and_no_boost(tmp_path,
+                                                                native):
+    """Heat buys a hot chunk copies to read from; a chunk that is only
+    written gains nothing from them (every write then goes to each copy,
+    and the copies are made while its bytes still change). Writes far
+    past the boost threshold, on either data plane, leave the chunk
+    cold and its copies as the goal says; reads of it heat it."""
+    cluster = Cluster(tmp_path, n_cs=3, native_data_plane=native)
+    await cluster.start(health_interval=0.1)
+    try:
+        master = cluster.master
+        assert master.tweaks.set("heat_boost_bytes", str(256 * 1024))
+        c = await cluster.client()
+        f = await c.create(1, "written.bin")
+        await c.setgoal(f.inode, 2)
+        payload = data_generator.generate(12, 2 * 1024 * 1024).tobytes()
+        for off in range(0, 8 * len(payload), len(payload)):
+            await c.pwrite(f.inode, off, payload)
+        for cs in cluster.chunkservers:
+            cs._fold_native_trace()
+            assert cs._heat == {}
+            await cs._heartbeat()
+        await asyncio.sleep(0.5)  # health ticks: nothing to boost
+        loc = await c.chunk_info(f.inode, 0)
+        chunk = master.meta.registry.chunk(loc.chunk_id)
+        assert master.heat.heat_of("chunk", loc.chunk_id) == 0.0
+        assert chunk.boost == 0 and master.meta.registry.boosted == set()
+        assert len({cs for cs, _ in chunk.parts}) == 2
+        for _ in range(2):
+            c.cache.invalidate(f.inode)
+            await c.read_file(f.inode, 0, len(payload))
+        for cs in cluster.chunkservers:
+            cs._fold_native_trace()
+            await cs._heartbeat()
+        await _until(
+            lambda: master.heat.heat_of("chunk", loc.chunk_id) > 0,
+            what="read heat")
+    finally:
+        await cluster.stop()
+
+
 async def test_slo_qos_auto_arm_and_expiry(tmp_path):
     """The second auto-arm action: an SLO breach squeezes the top
     offender's fair-share weight (counted, named), and the health tick

@@ -1,13 +1,36 @@
 """TPU (JAX) kernels vs numpy golden path: byte-identical parity and CRCs.
 
 Runs on the virtual CPU mesh in tests; same code path runs on real TPU.
+xor2..xor9 parity takes the staging path the GF(2^8) products take
+(stack, put, kernel, fetch) and charges rows of its own.
 """
+
+import os
+import sys
 
 import numpy as np
 import pytest
 
+from lizardfs_tpu.constants import MFSBLOCKSIZE
 from lizardfs_tpu.core.encoder import CpuChunkEncoder, TpuChunkEncoder, get_encoder
 from lizardfs_tpu.ops import crc32, rs
+from lizardfs_tpu.runtime import tracing
+from lizardfs_tpu.runtime.metrics import (
+    WRITE_COUNTS, WRITE_PHASES, PhaseBreakdown,
+)
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import layout  # noqa: E402
+
+LEGS = ("dev_stage", "dev_put", "dev_run", "dev_fetch")
+RS_ROWS = ("boundary",) + LEGS
+XOR_ROWS = tuple("xor_" + r for r in RS_ROWS)
+# a whole number of 64 KiB blocks, and a length that is not
+LENGTHS = (2 * MFSBLOCKSIZE, 3 * MFSBLOCKSIZE + 77)
 
 
 @pytest.fixture(scope="module")
@@ -96,3 +119,86 @@ def test_registry():
     # SIMD backend (or numpy if the .so is absent), never XLA-on-CPU
     e = get_encoder(None)
     assert e.name in ("cpp", "cpu")
+
+
+def parts_of(n: int, length: int, seed: int) -> list[np.ndarray]:
+    rng = np.random.default_rng([seed, n, length])
+    return [rng.integers(0, 256, length, dtype=np.uint8) for _ in range(n)]
+
+
+@pytest.mark.parametrize("length", LENGTHS)
+@pytest.mark.parametrize("n", range(2, 10))
+def test_device_xor_equals_the_golden_codec(tpu_enc, n, length):
+    parts = parts_of(n, length, 42)
+    want = rs.xor_parity(parts)
+    got = tpu_enc.xor_parity(parts)
+    assert got.dtype == np.uint8 and np.array_equal(got, want)
+    # into a buffer the caller holds: a row of a send buffer, and a
+    # strided view (every other byte of a larger one)
+    rows = np.full((2, length), 0xA5, dtype=np.uint8)
+    tpu_enc.xor_parity_into(parts, rows[1])
+    assert np.array_equal(rows[1], want) and (rows[0] == 0xA5).all()
+    wide = np.full(2 * length, 0x5A, dtype=np.uint8)
+    tpu_enc.xor_parity_into(parts, wide[::2])
+    assert np.array_equal(wide[::2], want) and (wide[1::2] == 0x5A).all()
+
+
+@pytest.mark.parametrize("chunk_len", [9 * MFSBLOCKSIZE,
+                                       7 * MFSBLOCKSIZE + 1234])
+@pytest.mark.parametrize("n", range(2, 10))
+def test_device_xor_equals_the_reference_layout(tpu_enc, n, chunk_len):
+    """A chunk of xorN as the benchmark's reference lays it out (parity
+    part 0, data parts 1..N, zero-padded to whole blocks): the device's
+    parity of the data parts is the reference's part 0."""
+    data = np.random.default_rng([7, n, chunk_len]).integers(
+        0, 256, chunk_len, dtype=np.uint8)
+    streams = layout.goal_parts({"xor": n}, data, MFSBLOCKSIZE)
+    assert np.array_equal(tpu_enc.xor_parity(streams[1:]), streams[0])
+    out = np.empty_like(streams[0])
+    tpu_enc.xor_parity_into(streams[1:], out)
+    assert np.array_equal(out, streams[0])
+
+
+def test_xor_rows_are_its_own_and_leave_the_products_rows(tpu_enc):
+    """One xor call charges ``xor_boundary`` and its four legs, under a
+    ``boundary`` span that says ``op="xor"`` and holds the four legs'
+    spans; the products' rows stay where they were, and a product moves
+    no xor row."""
+    rows = PhaseBreakdown("client_write", WRITE_PHASES, WRITE_COUNTS)
+    ring = tracing.SpanRing()
+    sink = tracing.OpSink(rows, ring, "client")
+    parts = parts_of(3, 4 * MFSBLOCKSIZE, 1)
+    with tracing.span("pwrite", sink=sink):
+        with tracing.span("encode", phase="encode", bucket="compute"):
+            tpu_enc.xor_parity(parts)
+    after_xor = rows.snapshot()
+    assert all(after_xor[r + "_ms"] > 0 for r in XOR_ROWS)
+    assert all(after_xor[r + "_ms"] == 0 for r in RS_ROWS)
+    spans = ring.dump()
+    boundary, = [s for s in spans if s["name"] == "boundary"]
+    assert boundary["attrs"]["op"] == "xor"
+    assert (boundary["attrs"]["rows"], boundary["attrs"]["bytes"]) == (
+        3, 4 * MFSBLOCKSIZE)
+    legs = sorted((s for s in spans
+                   if s["parent_id"] == boundary["span_id"]),
+                  key=lambda s: s["t0"])
+    assert [s["name"] for s in legs] == list(LEGS)
+
+    with tracing.span("pwrite", sink=sink):
+        with tracing.span("encode", phase="encode", bucket="compute"):
+            tpu_enc.encode(3, 2, parts)
+    after_rs = rows.snapshot()
+    assert all(after_rs[r + "_ms"] > 0 for r in RS_ROWS)
+    assert {r: after_rs[r + "_ms"] for r in XOR_ROWS} == {
+        r: after_xor[r + "_ms"] for r in XOR_ROWS}
+    # the xor rows nest under encode, as the products' boundary does
+    assert rows.phases["xor_boundary"] == "encode"
+    assert all(rows.phases["xor_" + leg] == "xor_boundary" for leg in LEGS)
+    assert "xor_boundary" not in rows.top_level
+
+
+def test_the_host_encoders_write_xor_into_the_callers_buffer():
+    parts = parts_of(5, 3 * MFSBLOCKSIZE + 9, 3)
+    out = np.zeros(3 * MFSBLOCKSIZE + 9, dtype=np.uint8)
+    CpuChunkEncoder().xor_parity_into(parts, out)
+    assert np.array_equal(out, rs.xor_parity(parts))

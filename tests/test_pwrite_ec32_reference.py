@@ -61,6 +61,7 @@ def expected_counts(first: int, calls: int) -> dict:
         region_end = min(-(-(coff + TRANSFER) // STRIPE) * STRIPE,
                          MFSCHUNKSIZE)
         want["payload_bytes"] += TRANSFER
+        want["ec_payload_bytes"] += TRANSFER  # acknowledged, at $ec
         want["rmw_region_bytes"] += region_end - region_start
         if head:
             want["rmw_reads"] += 1

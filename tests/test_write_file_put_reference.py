@@ -275,8 +275,12 @@ async def test_the_gates_are_spans_of_the_write_file_tree(tmp_path):
         gate, = [s for s in spans if s["name"] == "chunk_gate"]
         assert gate["parent_id"] == root["span_id"]
         assert gate["attrs"]["chunk"] == 2 and gate["bucket"] == "queue"
-        # plain copies go through no window and no whole-part fallback
-        assert not any(d[n] for n in WRITE_COUNTS)
+        # plain copies go through no window and no whole-part fallback;
+        # they count their bytes, and each chunk's one part through the
+        # relay chain of its two holders
+        assert {n: d[n] for n in WRITE_COUNTS if d[n]} == {
+            "copies_payload_bytes": 2 * MFSCHUNKSIZE + MFSBLOCKSIZE,
+            "chain_parts": 3}
     finally:
         await cluster.stop()
 

@@ -459,7 +459,8 @@ async def test_partial_stripe_pwrite_reads_back_then_patches(
         assert {n: d[n] for n in client.write_phases.counts} == dict(
             dict.fromkeys(WRITE_COUNTS, 0),
             rmw_reads=1, rmw_read_bytes=live_blocks * MFSBLOCKSIZE,
-            rmw_region_bytes=region, payload_bytes=len(payload))
+            rmw_region_bytes=region, payload_bytes=len(payload),
+            ec_payload_bytes=len(payload))
         client.cache.invalidate(f.inode)
         assert await client.read_file(
             f.inode, 0, (call + 1) * len(payload)) == payload * (call + 1)
